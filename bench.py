@@ -7,12 +7,17 @@ own trick) with the TPU batch backend, and prints ONE JSON line:
     {"metric": ..., "value": N, "unit": "pods/s", "vs_baseline": N/ref}
 
 Baseline: the reference's default-scheduler sustains ~100–300 pods/s on
-scheduler_perf (BASELINE.md); vs_baseline uses 300 — the top of the
-published envelope — so the ratio is conservative.
+scheduler_perf (README "Reference envelope"); vs_baseline uses 300 —
+the top of the published envelope — so the ratio is conservative.
 
 Presets: --preset smoke (100 nodes/1k pods, quick), --preset 1k,
---preset 5k (default; the BASELINE headline config).
-Options: --backend host|tpu (default tpu), --batch-size (default 8192).
+--preset 5k (default; upstream SchedulingBasic/5000Nodes_10000Pods).
+Options: --backend host|tpu (default tpu), --batch-size (default 16384).
+
+`--backend tpu` means "the device JAX has": the metric name and the
+`backend` field carry the platform that actually ran (`..._nodes_cpu...`
+on a JAX_PLATFORMS=cpu pre-flight), and the run exits non-zero if the
+device was lost on the way (scheduler_perf.device_run_failures).
 """
 
 from __future__ import annotations
@@ -26,16 +31,39 @@ import sys
 REFERENCE_PODS_PER_SEC = 300.0
 
 
-def _provenance(backend: str) -> dict:
+def _provenance(args, details: list[dict]) -> dict:
     """Solve-backend provenance stamped into every headline/detail JSON:
-    the jax platform and device count the run actually used, and whether
-    the solve routed through the fused Pallas kernel, the lax.scan
-    reference, and the donated carry — a relay-battery number is only
-    comparable to a CPU one when both rows carry these fields."""
-    if backend != "tpu":
+    the jax platform, device kind and count and library versions the
+    run actually used, and whether the solve routed through the fused
+    Pallas kernel, the lax.scan reference, and the donated carry. With
+    --processes >= 2 it is the leader replica's (read off its status
+    row into the detail): the parent never touches JAX, because the
+    chip belongs to the one process that schedules."""
+    if args.backend != "tpu":
         return {"solve_kernel": "host"}
+    if args.processes > 1:
+        return next((d["solve_provenance"] for d in details
+                     if d.get("solve_provenance")), {})
     from kubernetes_tpu.ops.backend import solve_provenance
     return solve_provenance()
+
+
+def _ran_on(args, prov: dict) -> str:
+    """The backend name a result is printed under: the platform that
+    ran, so no CPU number is ever printed under `tpu`."""
+    if args.backend != "tpu":
+        return args.backend
+    return prov.get("jax_platform") or "unknown"
+
+
+def _device_exit(args, details: list[dict]) -> int:
+    """Exit code of a finished run: non-zero when it was asked for the
+    device and lost it (any detail row — see device_run_failures)."""
+    if args.backend != "tpu":
+        return 0
+    from kubernetes_tpu.perf.scheduler_perf import device_exit
+    return device_exit(details, args.processes)
+
 
 #: default --churn rate sweeps (pods/s arrival): bracket the knee from
 #: a comfortable trickle to past the drain headline for the preset.
@@ -77,8 +105,7 @@ PRESETS = {
     # stores (store/sharded.py) — flagless; --shards/KTPU_SHARDS override.
     "200k": (200000, 500, 5000),
     # r22 stretch preset: 1M nodes. Intended for --processes >= 2 (the
-    # multi-process control plane); the finding — positive or negative,
-    # with the bounding resource named — is recorded in BASELINE.md.
+    # multi-process control plane).
     "1m": (1_000_000, 500, 5000),
 }
 
@@ -86,7 +113,58 @@ PRESETS = {
 def _proc_tag(args) -> str:
     """Metric-name suffix for multi-process rows: an N-process headline
     must never be mistaken for (or averaged with) the in-process one."""
-    return f"_procs{args.processes}" if (args.processes or 0) > 1 else ""
+    return f"_procs{args.processes}" if args.processes > 1 else ""
+
+
+DRAIN_TEMPLATE = [
+    # Warmup phase triggers jit compilation before the measured phase.
+    {"opcode": "createNodes", "countParam": "$nodes"},
+    {"opcode": "createPods", "countParam": "$warmup"},
+    {"opcode": "barrier"},
+    {"opcode": "createPods", "countParam": "$measured",
+     "collectMetrics": True},
+    {"opcode": "barrier"},
+]
+SERVE_TEMPLATE = [
+    {"opcode": "createNodes", "countParam": "$nodes"},
+    {"opcode": "createPods", "countParam": "$warmup"},
+    {"opcode": "barrier"},
+    {"opcode": "churnOpenLoop", "collectMetrics": True,
+     "arrival": {"model": "poisson", "rate": "$rate"},
+     "duration": "$duration", "seed": 17},
+]
+
+
+def make_runner(args, shards, boundary, batch: int, profile_dir=None):
+    """The one PerfRunner construction — every mode here and
+    chip_smoke.py build their runs through it. A fresh runner (and
+    backend) per run, so one run's warmed programs never subsidize the
+    next one's numbers. With --processes >= 2 the device backend is a
+    SPEC the leader replica builds from: this process must not touch
+    JAX, or it would hold the chip the leader needs."""
+    from kubernetes_tpu.perf.scheduler_perf import PerfRunner, device_backend
+
+    backend = spec = None
+    if args.backend == "tpu":  # as resolved by prepare()
+        backend, spec = device_backend(args.chunk, args.processes)
+    return PerfRunner(backend=backend, backend_spec=spec, batch_size=batch,
+                      through_apiserver=boundary, shards=shards,
+                      profile_dir=profile_dir,
+                      policy_count=args.policy_set,
+                      policy_tenants=args.policy_tenants,
+                      audit_rules=[{"level": args.audit_level}]
+                      if args.audit_level else None,
+                      processes=args.processes,
+                      data_dir=args.data_dir or None)
+
+
+def run_template(args, template: list, params: dict, shards, boundary,
+                 batch: int, profile_dir=None, timeout: float = 1800.0):
+    """One harness run of a workload template; returns the runner (its
+    backend outlives the run, for inspection) and the WorkloadResult."""
+    runner = make_runner(args, shards, boundary, batch, profile_dir)
+    return runner, asyncio.run(
+        runner.run(template, params, timeout=timeout))
 
 
 def _run_churn(args, nodes: int, shards, boundary, batch: int) -> int:
@@ -96,8 +174,6 @@ def _run_churn(args, nodes: int, shards, boundary, batch: int) -> int:
     its exact p999; per-row details (p50/p99/p999, backlog growth,
     fault/recovery records) go to stderr like the drain detail JSON."""
     from kubernetes_tpu.perf.churn.driver import run_rate_sweep
-    from kubernetes_tpu.perf.scheduler_perf import PerfRunner
-    from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATES
 
     rates = PRESET_CHURN_RATES[args.preset]
     if args.churn_rates:
@@ -106,7 +182,6 @@ def _run_churn(args, nodes: int, shards, boundary, batch: int) -> int:
     if args.churn_fault:
         kind, _, at = args.churn_fault.partition("@")
         fault = {"kind": kind, "at": float(at or 5.0)}
-    use_tpu = DEFAULT_FEATURE_GATES.enabled("TPUScorer")
     if args.profile_dir:
         print("warning: --profile-dir is not supported in --churn mode "
               "(per-row runs would overwrite each other's traces); no "
@@ -114,18 +189,7 @@ def _run_churn(args, nodes: int, shards, boundary, batch: int) -> int:
     _warn_policy_needs_boundary(args, boundary, "churn rows")
 
     def runner_factory():
-        be = None
-        if use_tpu:
-            from kubernetes_tpu.ops import TPUBackend
-            be = TPUBackend(max_batch=args.chunk)
-        return PerfRunner(backend=be, batch_size=batch if be else 1,
-                          through_apiserver=boundary, shards=shards,
-                          policy_count=args.policy_set,
-                          policy_tenants=args.policy_tenants,
-                          audit_rules=[{"level": args.audit_level}]
-                          if args.audit_level else None,
-                          processes=args.processes,
-                          data_dir=args.data_dir or None)
+        return make_runner(args, shards, boundary, batch)
 
     sweep = run_rate_sweep(
         nodes=nodes, rates=rates, duration=args.churn_duration,
@@ -133,15 +197,18 @@ def _run_churn(args, nodes: int, shards, boundary, batch: int) -> int:
         warmup=args.churn_warmup, agents=args.churn_agents,
         fault=fault, fault_rate=args.churn_fault_rate,
         runner_factory=runner_factory, timeout=1800.0)
-    prov = _provenance(args.backend)
+    details = sweep["rows"] + ([sweep["fault_row"]]
+                               if sweep["fault_row"] is not None else [])
+    prov = _provenance(args, details)
+    ran_on = _ran_on(args, prov)
     print(json.dumps({"churn": sweep, "preset": args.preset,
-                      "backend": args.backend,
+                      "backend": ran_on,
                       "provenance": prov}), file=sys.stderr)
     knee = sweep["knee"]
     value = knee["knee_rate"] or 0.0
     out = {
         "provenance": prov,
-        "metric": f"churn_knee_arrival_rate_{args.preset}_{args.backend}"
+        "metric": f"churn_knee_arrival_rate_{args.preset}_{ran_on}"
                   + (f"_apiserver_{args.transport}" if boundary else "")
                   + _proc_tag(args),
         "value": value,
@@ -154,7 +221,7 @@ def _run_churn(args, nodes: int, shards, boundary, batch: int) -> int:
         out["fault_recovery_seconds_max"] = \
             sweep["fault_row"]["churn_recovery_seconds_max"]
     print(json.dumps(out))
-    return 0
+    return _device_exit(args, details)
 
 
 def _run_serve(args, nodes: int, warmup: int, measured: int, shards,
@@ -167,57 +234,25 @@ def _run_serve(args, nodes: int, warmup: int, measured: int, shards,
     the serving tier's figure of merit. Fresh runner per phase so the
     drain's warmed chunk programs can't subsidize the serve numbers or
     vice versa."""
-    from kubernetes_tpu.perf.scheduler_perf import PerfRunner
-    from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATES
-
-    use_tpu = DEFAULT_FEATURE_GATES.enabled("TPUScorer")
     _warn_policy_needs_boundary(args, boundary, "serve rows")
-
-    def make_runner():
-        be = None
-        if use_tpu:
-            from kubernetes_tpu.ops import TPUBackend
-            be = TPUBackend(max_batch=args.chunk)
-        return PerfRunner(backend=be, batch_size=batch if be else 1,
-                          through_apiserver=boundary, shards=shards,
-                          policy_count=args.policy_set,
-                          policy_tenants=args.policy_tenants,
-                          audit_rules=[{"level": args.audit_level}]
-                          if args.audit_level else None,
-                          processes=args.processes,
-                          data_dir=args.data_dir or None)
-
-    drain_template = [
-        {"opcode": "createNodes", "countParam": "$nodes"},
-        {"opcode": "createPods", "countParam": "$warmup"},
-        {"opcode": "barrier"},
-        {"opcode": "createPods", "countParam": "$measured",
-         "collectMetrics": True},
-        {"opcode": "barrier"},
-    ]
-    drain = asyncio.run(make_runner().run(
-        drain_template, {"nodes": nodes, "warmup": warmup,
-                         "measured": measured}, timeout=1800.0))
-    serve_template = [
-        {"opcode": "createNodes", "countParam": "$nodes"},
-        {"opcode": "createPods", "countParam": "$warmup"},
-        {"opcode": "barrier"},
-        {"opcode": "churnOpenLoop", "collectMetrics": True,
-         "arrival": {"model": "poisson", "rate": "$rate"},
-         "duration": "$duration", "seed": 17},
-    ]
-    serve = asyncio.run(make_runner().run(
-        serve_template, {"nodes": nodes, "warmup": warmup,
-                         "rate": args.serve_rate,
-                         "duration": args.serve_duration}, timeout=1800.0))
+    _, drain = run_template(
+        args, DRAIN_TEMPLATE, {"nodes": nodes, "warmup": warmup,
+                               "measured": measured},
+        shards, boundary, batch)
+    _, serve = run_template(
+        args, SERVE_TEMPLATE, {"nodes": nodes, "warmup": warmup,
+                               "rate": args.serve_rate,
+                               "duration": args.serve_duration},
+        shards, boundary, batch)
     d, s = drain.as_dict(), serve.as_dict()
-    prov = _provenance(args.backend)
+    prov = _provenance(args, [d, s])
+    ran_on = _ran_on(args, prov)
     print(json.dumps({"serve": s, "drain": d, "preset": args.preset,
-                      "backend": args.backend,
+                      "backend": ran_on,
                       "provenance": prov}), file=sys.stderr)
     print(json.dumps({
         "provenance": prov,
-        "metric": f"serve_single_pod_p50_ms_{args.preset}_{args.backend}"
+        "metric": f"serve_single_pod_p50_ms_{args.preset}_{ran_on}"
                   + (f"_apiserver_{args.transport}" if boundary else "")
                   + _proc_tag(args),
         "value": s["attempt_p50_ms"],
@@ -231,10 +266,10 @@ def _run_serve(args, nodes: int, warmup: int, measured: int, shards,
         "drain_vs_baseline": round(
             d["throughput_pods_per_sec"] / REFERENCE_PODS_PER_SEC, 3),
     }))
-    return 0
+    return _device_exit(args, [d, s])
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--preset", choices=PRESETS, default="5k")
     ap.add_argument("--backend", choices=["host", "tpu"], default="tpu")
@@ -247,10 +282,8 @@ def main(argv=None) -> int:
                          "work to overlap)")
     ap.add_argument("--chunk", type=int, default=None,
                     help="OVERRIDE the backend solve chunk (jit batch "
-                         "signature). Default: flagless — the backend's "
-                         "adaptive tuner picks chunk AND pipeline depth "
-                         "from warmup-measured transfer latency and "
-                         "dirty-upload ratio (BASELINE.md r6 envelope)")
+                         "signature). Default: 1024, with the pipeline "
+                         "depth from the AdaptiveTuner's table")
     ap.add_argument("--shards", type=int, default=None,
                     help="OVERRIDE the control-plane shard count (the "
                          "sweep knob; 1 = the classic single store). "
@@ -301,9 +334,8 @@ def main(argv=None) -> int:
                          "in milliseconds (0 = always dispatch "
                          "immediately). Default: flagless — the "
                          "AdaptiveTuner policy row sizes it from the "
-                         "measured transfer latency and offered-rate "
-                         "estimate (thresholds seeded from the r15 "
-                         "churn knee)")
+                         "offered-rate estimate (thresholds seeded "
+                         "from the r15 churn knee)")
     ap.add_argument("--serving", choices=["on", "off"], default="on",
                     help="KTPU_SERVING kill switch: 'off' degrades the "
                          "dispatch loop structurally to the pre-serving "
@@ -319,14 +351,14 @@ def main(argv=None) -> int:
                          "sweeps greedy vs optimal on one preset")
     ap.add_argument("--pallas", choices=["auto", "on", "off"],
                     default=None,
-                    help="KTPU_PALLAS: 'off' pins the r20 lax.scan call "
-                         "graph (bit-identical kill switch), 'on' forces "
-                         "the fused Pallas wavefront kernel (compiled "
-                         "where lowering exists, interpret elsewhere), "
-                         "'auto' (the default policy) compiles on "
-                         "accelerator backends only. The r21 relay "
-                         "battery sweeps off vs on per preset; the "
-                         "headline JSON stamps the resolved mode")
+                    help="KTPU_PALLAS: 'off' and 'auto' (the default) run "
+                         "the lax.scan call graph — the fused Pallas "
+                         "wavefront kernel does not lower for the TPU "
+                         "(ops/pallas_kernel.resolve_mode quotes the "
+                         "compiler), so policy routes it off everywhere; "
+                         "'on' compiles the real kernel or fails the run, "
+                         "it never interprets. The headline JSON stamps "
+                         "the resolved mode")
     ap.add_argument("--churn", action="store_true",
                     help="ChurnDay mode (perf/churn): instead of one "
                          "bulk drain, sweep an OPEN-LOOP Poisson/burst/"
@@ -417,32 +449,29 @@ def main(argv=None) -> int:
                          "internal error")
     ap.add_argument("--lint-json", action="store_true",
                     help="--lint with machine-readable JSON on stdout")
-    args = ap.parse_args(argv)
+    return ap
 
-    if args.lint or args.lint_json:
-        from kubernetes_tpu.analysis import main as lint_main
-        return lint_main(["--json"] if args.lint_json else [])
+
+def prepare(args) -> tuple:
+    """Apply a parsed command line to the process (flag overrides, the
+    TPUScorer gate, the compile cache) and size the run; returns
+    (nodes, warmup, measured, shards, boundary, batch). Shared with
+    chip_smoke.py so the smoke takes the same path as `python bench.py`."""
+    import os
 
     if args.shortlist_k is not None:
         # Flag reads are live (utils/flags.py), so ordering vs the
-        # backend import no longer matters — the old import-time read
-        # was the flag lint's first catch.
-        import os
+        # backend import does not matter.
         os.environ["KTPU_SHORTLIST_K"] = str(args.shortlist_k)
     if args.admission_window is not None:
-        import os
         os.environ["KTPU_ADMISSION_WINDOW"] = str(args.admission_window)
     if args.serving == "off":
-        import os
         os.environ["KTPU_SERVING"] = "0"
     if args.solve_mode is not None:
-        import os
         os.environ["KTPU_SOLVE_MODE"] = args.solve_mode
     if args.pallas is not None:
-        import os
         os.environ["KTPU_PALLAS"] = args.pallas
     if args.class_pad is not None:
-        import os
         if args.class_pad <= 0:
             os.environ["KTPU_CLASS_PLANES"] = "0"
         else:
@@ -452,13 +481,7 @@ def main(argv=None) -> int:
             os.environ["KTPU_CLASS_PLANES"] = "1"
             os.environ["KTPU_CLASS_PAD"] = str(args.class_pad)
 
-    tracer = None
-    if args.trace:
-        from kubernetes_tpu.utils.tracing import DEFAULT_TRACER
-        tracer = DEFAULT_TRACER
-        tracer.enabled = True
-
-    from kubernetes_tpu.perf.scheduler_perf import PerfRunner
+    from kubernetes_tpu.perf.scheduler_perf import resolve_processes
     from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATES
 
     # Backend selection goes through the TPUScorer feature gate (SURVEY
@@ -466,50 +489,53 @@ def main(argv=None) -> int:
     DEFAULT_FEATURE_GATES.set("TPUScorer", args.backend == "tpu")
     if args.feature_gates:
         DEFAULT_FEATURE_GATES.set_from_spec(args.feature_gates)
+    device = DEFAULT_FEATURE_GATES.enabled("TPUScorer")
+    args.backend = "tpu" if device else "host"
+    args.processes = resolve_processes(args.processes)
+    if device and args.processes <= 1:
+        # Before the first compile. With children the leader replica
+        # does this itself (multiproc/schedproc.py); this process
+        # stays off JAX.
+        from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
 
     nodes, warmup, measured = PRESETS[args.preset]
     from kubernetes_tpu.store.sharded import control_plane_shards
     # PerfRunner owns propagating the override (it scopes KTPU_SHARDS
     # around the run so the host prep's policy sees the same S).
     shards = control_plane_shards(nodes, args.shards)
-    backend = None
-    batch = 1
-    if DEFAULT_FEATURE_GATES.enabled("TPUScorer"):
-        batch = args.batch_size
-        args.backend = "tpu"
-        if not args.churn and not args.serve:
-            # Churn/serve modes build fresh backends per phase in their
-            # own factories; constructing one here would be dead work.
-            from kubernetes_tpu.ops import TPUBackend
-            backend = TPUBackend(max_batch=args.chunk)  # None = adaptive
-    else:
-        args.backend = "host"
-
-    # Warmup phase triggers jit compilation (first TPU compile is ~20-40s)
-    # before the measured phase starts.
-    template = [
-        {"opcode": "createNodes", "countParam": "$nodes"},
-        {"opcode": "createPods", "countParam": "$warmup"},
-        {"opcode": "barrier"},
-        {"opcode": "createPods", "countParam": "$measured",
-         "collectMetrics": True},
-        {"opcode": "barrier"},
-    ]
-    params = {"nodes": nodes, "warmup": warmup, "measured": measured}
-
+    boundary = False
+    if args.through_apiserver:
+        boundary = "wire" if args.transport == "wire" else True
     # The workload churns millions of short-lived dicts; default gen-0
     # collection every 700 allocations makes the interpreter spend ~6% of
     # the measured phase in GC (plus XLA's gc callback). Raising the
     # threshold trades peak RSS for wall, like tuning GOGC on the reference.
     gc.set_threshold(100_000, 50, 50)
+    return (nodes, warmup, measured, shards, boundary,
+            args.batch_size if device else 1)
 
-    if args.profile_dir and backend is None:
-        print("warning: --profile-dir needs --backend tpu; no trace "
-              "will be written", file=sys.stderr)
-    boundary = False
-    if args.through_apiserver:
-        boundary = "wire" if args.transport == "wire" else True
-    if (args.processes or 0) > 1 and (args.policy_set or args.audit_level):
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+
+    if args.lint or args.lint_json:
+        from kubernetes_tpu.analysis import main as lint_main
+        return lint_main(["--json"] if args.lint_json else [])
+
+    tracer = None
+    if args.trace:
+        from kubernetes_tpu.utils.tracing import DEFAULT_TRACER
+        tracer = DEFAULT_TRACER
+        tracer.enabled = True
+
+    nodes, warmup, measured, shards, boundary, batch = prepare(args)
+
+    if args.profile_dir and (args.backend != "tpu" or args.processes > 1):
+        print("warning: --profile-dir needs --backend tpu in one process; "
+              "no trace will be written", file=sys.stderr)
+        args.profile_dir = ""
+    if args.processes > 1 and (args.policy_set or args.audit_level):
         print("warning: the multi-process control plane carries no "
               "policy chain yet; --policy-set/--audit-level are ignored "
               "at --processes >= 2", file=sys.stderr)
@@ -519,22 +545,14 @@ def main(argv=None) -> int:
         return _run_serve(args, nodes, warmup, measured, shards, boundary,
                           batch)
     _warn_policy_needs_boundary(args, boundary, "the run")
-    runner = PerfRunner(backend=backend, batch_size=batch,
-                        through_apiserver=boundary,
-                        profile_dir=args.profile_dir or None,
-                        policy_count=args.policy_set,
-                        policy_tenants=args.policy_tenants,
-                        audit_rules=[{"level": args.audit_level}]
-                        if args.audit_level else None,
-                        shards=shards,
-                        processes=args.processes,
-                        data_dir=args.data_dir or None)
-    res = asyncio.run(runner.run(
-        template, params,
+    _, res = run_template(
+        args, DRAIN_TEMPLATE,
+        {"nodes": nodes, "warmup": warmup, "measured": measured},
+        shards, boundary, batch, profile_dir=args.profile_dir or None,
         # The 1m stretch preset stages and syncs ~200x the 5k object
         # count before the measured phase begins; everything else keeps
         # the tighter window so a hung run fails fast.
-        timeout=5400.0 if args.preset == "1m" else 1800.0))
+        timeout=5400.0 if args.preset == "1m" else 1800.0)
 
     if tracer is not None:
         with open(args.trace, "w") as f:
@@ -543,13 +561,14 @@ def main(argv=None) -> int:
               "https://ui.perfetto.dev)", file=sys.stderr)
 
     detail = res.as_dict()
-    prov = _provenance(args.backend)
+    prov = _provenance(args, [detail])
+    ran_on = _ran_on(args, prov)
     print(json.dumps({"detail": detail, "preset": args.preset,
-                      "backend": args.backend,
+                      "backend": ran_on,
                       "provenance": prov}, ), file=sys.stderr)
     print(json.dumps({
         "provenance": prov,
-        "metric": f"pods_per_sec_{args.preset}_nodes_{args.backend}"
+        "metric": f"pods_per_sec_{args.preset}_nodes_{ran_on}"
                   + (f"_apiserver_{args.transport}"
                      if args.through_apiserver else "")
                   + _proc_tag(args),
@@ -564,7 +583,7 @@ def main(argv=None) -> int:
         "fragmentation_occupied_pct": detail["fragmentation_occupied_pct"],
         "solve_mode": args.solve_mode or "auto",
     }))
-    return 0
+    return _device_exit(args, [detail])
 
 
 if __name__ == "__main__":

@@ -18,7 +18,7 @@ queues are what this pass audits:
   when wrapped in `asyncio.wait_for`.
 - **LK203 device fetch under a lock**: `np.asarray` / `.item()` /
   `block_until_ready` / `jax.device_get` while holding any lock — a
-  device round-trip (up to ~100 ms on a relay) stalls every other
+  device round-trip stalls every other
   holder. The runtime twin is `locking.check_dispatch_seam` at the
   sanctioned fetch seams.
 - **LK204 wire send under a lock**: `transport.write` / `.sendall` /

@@ -538,6 +538,8 @@ class SchedulerMetrics:
         #: increment per affected pod/gang: kind="spread_poisoned"
         #: (spread pod missed the union scan table — steady-state zero),
         #: kind="host_fallback" (pod took a per-pod host plugin row),
+        #: kind="host_path" (a scheduler WITH a backend placed the pod
+        #: through the plugin-by-plugin host path),
         #: kind="gang_overflow" (gangs beyond the solver's capacity
         #: degrade to Permit-barrier-only atomicity).
         self.backend_degradations = r.counter(
@@ -622,11 +624,11 @@ class SchedulerMetrics:
         #: solve ran the fused kernel (interpret or compiled), and
         #: chunks where the router WANTED the kernel (KTPU_PALLAS
         #: resolved on) but fell back to the lax.scan reference — the
-        #: reason label separates structural shapes the kernel does not
-        #: fuse (spread/shortlist/optimal/wave_off/shape) from a
-        #: backend without a pallas lowering (unavailable). The kill
-        #: switch (KTPU_PALLAS=off) and the CPU auto default do NOT
-        #: count: off-by-policy is not a fallback.
+        #: reason label names the structural shape the kernel does not
+        #: fuse (spread/shortlist/optimal/wave_off/shape). A backend
+        #: that cannot lower the kernel is an error, not a fallback.
+        #: The kill switch (KTPU_PALLAS=off) and the auto default do
+        #: NOT count: off-by-policy is not a fallback.
         self.solver_pallas_solves = r.counter(
             "solver_pallas_solves_total",
             "Chunks solved through the fused Pallas wavefront kernel")
@@ -716,6 +718,14 @@ class SchedulerMetrics:
         self.serving_coalesced_batches = r.counter(
             "serving_coalesced_batches_total",
             "Dispatches whose admission window merged extra pods")
+        #: the fast path's twin of schedule_attempts{result=
+        #: "backend_fallback"}: its catch reroutes the pod through the
+        #: batch path and stays off the circuit breaker, so without
+        #: this a run whose every single-pod solve raised looked clean.
+        self.serving_fast_path_failures = r.counter(
+            "serving_fast_path_failures_total",
+            "Fast-path solves or warm-ups that raised (the pod took "
+            "the batch path instead)")
         self.resident_plane_refreshes = r.counter(
             "resident_plane_refreshes_total",
             "Refreshes of the device-resident used-state planes "
@@ -753,6 +763,22 @@ class SchedulerMetrics:
         if w is None:
             w = self.attempt_windows[key] = WindowedLatencyRecorder()
         w.observe(seconds)
+
+    def device_loss_counts(self) -> dict[str, int]:
+        """How often this scheduler placed pods without its device, over
+        its whole life: batches the backend raised on (every profile),
+        fast-path solves/warm-ups that raised, and pods placed plugin
+        by plugin although a backend was attached. The names are the
+        bench detail JSON's (perf/scheduler_perf.WorkloadResult)."""
+        return {
+            "backend_fallback_total": int(sum(
+                v for (result, _), v in self.schedule_attempts._values.items()
+                if result == "backend_fallback")),
+            "fast_path_failures_total": int(
+                self.serving_fast_path_failures.value()),
+            "host_path_pods": int(
+                self.backend_degradations.value(kind="host_path")),
+        }
 
     def set_pending(self, stats: Mapping[str, int]) -> None:
         for queue, n in stats.items():

@@ -4,11 +4,13 @@
 Each replica builds a `ProcessShardedStore` over the shard sockets
 and blocks in `LeaderElector.run` on the `ktpu-scheduler` Lease
 (client/leaderelection.py — lease CAS, KTPU_LEASE_DURATION clock).
-Only the LEADER constructs the Scheduler, rebuilds its assume-cache
-from fresh informer LISTs (the reference's behavior: scheduler cache
-state is never replicated, it is REBUILT on failover), and schedules;
-the standby holds no informers and costs nothing until the lease
-frees.
+Only the LEADER constructs the Scheduler and its device backend,
+rebuilds its assume-cache from fresh informer LISTs (the reference's
+behavior: scheduler cache state is never replicated, it is REBUILT on
+failover), and schedules; the standby holds no informers and no
+backend — a chip belongs to one process, so a standby that initialised
+JAX before the election would take it from the leader — and costs
+nothing until the lease frees.
 
 Measurement rides the store, not a side channel: the parent writes a
 marker ConfigMap (`kube-system/ktpu-measure`, `{id, op}`) and the
@@ -18,10 +20,14 @@ marker — exact attempt percentiles over the marked window (the r11
 WindowedLatencyRecorder, same recorder the in-process harness
 reads). After a failover the new leader marks from ITS window start,
 so percentiles cover the post-failover tail — honest, and visible in
-the detail JSON via `leader_elections_total` > 1.
+the detail JSON via `leader_elections_total` > 1. The same status row
+carries the leader's device-loss counters and solve provenance: the
+parent of a device run never touches JAX, so it learns from here which
+device scheduled and whether it was kept.
 
-The replica imports jax only when the parent requests a device
-backend — a host-path scheduler pair boots in interpreter time.
+A replica imports jax only after it holds the lease, and only when the
+parent requested a device backend — a host-path scheduler pair boots
+in interpreter time.
 """
 
 from __future__ import annotations
@@ -53,11 +59,6 @@ async def _replica(identity: str, targets: list,
     from kubernetes_tpu.multiproc.client import ProcessShardedStore
 
     store = ProcessShardedStore(targets)
-    backend = None
-    if backend_spec and backend_spec.get("kind") == "tpu":
-        from kubernetes_tpu.ops import TPUBackend
-        backend = TPUBackend(max_batch=backend_spec.get("chunk"))
-
     elector = LeaderElector(store, "ktpu-scheduler", identity)
     stop = asyncio.Event()
     loop = asyncio.get_running_loop()
@@ -65,7 +66,7 @@ async def _replica(identity: str, targets: list,
         loop.add_signal_handler(sig, stop.set)
 
     async def lead() -> None:
-        await _lead(store, identity, backend, batch_size,
+        await _lead(store, identity, backend_spec, batch_size,
                     scheduler_kwargs, elector)
 
     run_task = asyncio.ensure_future(elector.run(lead))
@@ -78,13 +79,31 @@ async def _replica(identity: str, targets: list,
     await store.close()
 
 
-async def _lead(store, identity: str, backend, batch_size: int,
-                scheduler_kwargs: dict, elector) -> None:
-    """The leader payload: assume-cache rebuild (fresh informers), the
+def _device_backend(chunk: int | None):
+    from kubernetes_tpu.ops import TPUBackend
+    from kubernetes_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    return TPUBackend(max_batch=chunk)
+
+
+async def _lead(store, identity: str, backend_spec: dict | None,
+                batch_size: int, scheduler_kwargs: dict, elector) -> None:
+    """The leader payload: the device backend (built only now that the
+    lease is held), assume-cache rebuild (fresh informers), the
     scheduling loop, and the status/marker responder."""
     from kubernetes_tpu.client.informer import InformerFactory
     from kubernetes_tpu.metrics.registry import SchedulerMetrics
     from kubernetes_tpu.scheduler.scheduler import Scheduler
+
+    backend = None
+    if backend_spec and backend_spec.get("kind") == "tpu":
+        # In a worker thread: importing jax and reaching the chip takes
+        # ~10 s (more on a busy host), and the elector renews the lease
+        # on THIS loop. Built inline, a slow start outlived the 15 s
+        # lease on the v5e (PR 21): the standby took it, and then needed
+        # the chip this process still held.
+        backend = await asyncio.to_thread(
+            _device_backend, backend_spec.get("chunk"))
 
     metrics = SchedulerMetrics()
     metrics.registry._metrics.setdefault(
@@ -103,7 +122,8 @@ async def _lead(store, identity: str, backend, batch_size: int,
     # the deadline only guards against a truly wedged apiserver.
     await factory.wait_for_sync(timeout=900.0)
     status = asyncio.ensure_future(
-        _status_loop(store, identity, metrics, elector))
+        _status_loop(store, identity, metrics, elector,
+                     sched if backend is not None else None))
     try:
         await sched.run(batch_size=batch_size)
     finally:
@@ -113,12 +133,22 @@ async def _lead(store, identity: str, backend, batch_size: int,
         factory.stop()
 
 
-async def _status_loop(store, identity: str, metrics, elector) -> None:
+async def _status_loop(store, identity: str, metrics, elector,
+                       device_sched=None) -> None:
     """Answer measure markers and publish leader status via ConfigMaps.
     Store writes ride the meta shard like any client's — no side
-    channel to keep alive across failover."""
+    channel to keep alive across failover. `device_sched` is the
+    Scheduler when it was given a device backend: its device-loss
+    counters and solve provenance ride the row (see the module doc)."""
+    import json
+
     from kubernetes_tpu.api.meta import new_object
     from kubernetes_tpu.store.mvcc import NotFound, StoreError
+
+    provenance = ""
+    if device_sched is not None:
+        from kubernetes_tpu.ops.backend import solve_provenance
+        provenance = json.dumps(solve_provenance())
 
     win = metrics.attempt_window()
     mark: int | None = None
@@ -152,6 +182,16 @@ async def _status_loop(store, identity: str, metrics, elector) -> None:
                 for q, label in ((0.50, "p50"), (0.90, "p90"),
                                  (0.99, "p99"), (0.999, "p999")):
                     data[label] = repr(pcts[q])
+            if device_sched is not None:
+                data.update(
+                    {k: str(v)
+                     for k, v in metrics.device_loss_counts().items()},
+                    provenance=provenance,
+                    backendAttached=(
+                        "1" if device_sched.backend is not None else "0"),
+                    deviceSolves=str(
+                        int(metrics.solve_duration.count())
+                        + int(metrics.serving_fast_path_pods.value())))
 
             def put(obj):
                 obj["data"] = data

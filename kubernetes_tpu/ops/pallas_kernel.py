@@ -23,12 +23,13 @@ scan's `wave_step` — it calls the identical `ops/kernels.py` score
 functions and the identical `_wave_spec_picks`/`_wave_conflicts` helpers
 from ops/solver.py on values read from refs — so assignments are
 bit-identical to the lax.scan reference at every wave width, strategy,
-and class-plane shape. The scan REMAINS the semantic reference: routing
-is off by default on CPU (`KTPU_PALLAS=auto`), interpret mode validates
-the kernel on CPU tier-1, and compiled mode activates only on
-accelerator backends, with structural fallback to the scan (counted in
-`solver_pallas_fallbacks_total`) when lowering is unavailable or the
-chunk shape is unsupported.
+and class-plane shape. The scan REMAINS the semantic reference, and as
+of PR 21 it is also the only form that runs on a device: neither kernel
+in this module lowers through Mosaic (see `resolve_mode` for the
+compiler's own words), so `KTPU_PALLAS=auto` routes off on every
+platform and the kernels are exercised by the CPU interpret-mode suites
+only. A chunk the flag wants on the kernel but whose shape it does not
+fuse keeps its scan, counted in `solver_pallas_fallbacks_total`.
 
 Unsupported shapes, stated honestly: the kernel holds the full (C,N)
 planes and the (W,N) wave evaluation in one grid step, so chunks whose
@@ -40,11 +41,10 @@ router counts each as a distinct fallback reason.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 from kubernetes_tpu.ops import kernels
 from kubernetes_tpu.ops import solver
@@ -54,22 +54,10 @@ NEG_INF = -jnp.inf
 #: per-grid-step working-set ceiling (bytes). The fused step keeps the
 #: unpacked (C,N) mask, the (C,N) score plane, the (W,N) evaluation
 #: block, and the (N,R) carries resident at once; chunks above this
-#: fall back to the scan with reason="shape".
+#: fall back to the scan with reason="shape". Bounds the interpreter's
+#: host memory only: it was never derived from a chip's VMEM (a v5e
+#: core has ~16 MiB), so a kernel that lowers must re-derive it.
 MAX_STATE_BYTES = 128 * 1024 * 1024
-
-
-def is_available() -> bool:
-    """Pallas importability on this jax build (cheap, cached)."""
-    return _import_pallas() is not None
-
-
-@functools.lru_cache(maxsize=1)
-def _import_pallas():
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        return pl
-    except Exception:  # pragma: no cover - pallas ships with jax>=0.4
-        return None
 
 
 def state_bytes(n_nodes: int, n_classes: int, n_res: int,
@@ -85,8 +73,6 @@ def unsupported_reason(n_nodes: int, n_classes: int, n_res: int,
                        wave_w: int) -> str | None:
     """Structural shape gate: None = the kernel supports this chunk,
     else the scan-fallback reason for `solver_pallas_fallbacks_total`."""
-    if not is_available():
-        return "unavailable"
     if wave_w < 2:
         return "wave_off"
     if n_nodes < 1 or n_classes < 1:
@@ -96,35 +82,52 @@ def unsupported_reason(n_nodes: int, n_classes: int, n_res: int,
     return None
 
 
-@functools.lru_cache(maxsize=4)
-def lowering_supported(platform: str) -> bool:
-    """Can COMPILED (non-interpret) pallas lower on `platform`?
+def resolve_mode(flag: str, platform: str) -> str:
+    """KTPU_PALLAS value -> 'off' | 'interpret' | 'compiled' on `platform`
+    (`jax.default_backend()`): the one copy of the policy, read by the
+    router (`AdaptiveTuner.pallas_mode`) and by `solve_provenance`.
 
-    Probed once per process by compiling a trivial kernel; interpret
-    mode never needs this. CPU answers False without probing — the
-    pallas CPU path IS interpret mode, and the scan is faster there.
-    """
-    if platform == "cpu" or not is_available():
-        return False
-    pl = _import_pallas()
+    | flag        | cpu         | any other platform            |
+    |-------------|-------------|-------------------------------|
+    | `off`       | off         | off                           |
+    | `auto`      | off         | off                           |
+    | `interpret` | interpret   | ValueError (CPU test mode)    |
+    | `on`        | compiled    | compiled                      |
 
-    def _probe_kernel(x_ref, o_ref):
-        o_ref[...] = x_ref[...] + 1
+    `auto` is off everywhere BY POLICY, not by probe: the real kernels
+    were lowered for the TPU v5e (jax 0.9.0 / libtpu 0.0.34, PR 21) and
+    Mosaic refused both. `wave_solve` at N=5120, P=1024, W=32: "The
+    Pallas TPU lowering currently requires that the last two dimensions
+    of your block shape are divisible by 8 and 128 respectively, or be
+    equal to the respective dimensions of the overall array. Block spec
+    for args[2] in pallas_call _wave_step_kernel ... has block shape
+    (Blocked(block_size=1), Blocked(block_size=1),
+    Blocked(block_size=32)), array shape (1, 32, 32)". With every block
+    made full-array (the small repair) the next refusal is the class-
+    plane row gather `mask[row]`: "ValueError: Shape mismatch in input,
+    indices and output" (Mosaic lowers only take_along_axis-shaped 2-D
+    gathers), which is also what `wave_eval` dies on; behind it wait
+    the `[row[:, None], safe[None, :]]` gather, the `.at[safe].add`
+    scatters, bool refs and the in-kernel cond/fori_loop replay. That
+    is a rewrite of the op sequence the bit-identity contract rests on
+    (ROADMAP C3), not a repair.
 
-    try:
-        fn = pl.pallas_call(
-            _probe_kernel,
-            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32))
-        jax.jit(fn).lower(
-            jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
-        return True
-    except Exception:
-        return False
-
-
-def default_interpret() -> bool:
-    """Interpret mode unless a compiled lowering is actually available."""
-    return not lowering_supported(jax.default_backend())
+    `on` therefore means "compile the real kernel or fail": the router
+    answers `compiled`, the fused program's first compile at the
+    chunk's own static shape raises the compiler's error, and the run
+    fails — on CPU too, where pallas has no compiled lowering. It
+    never degrades to interpret. `interpret` runs the kernel body
+    through the Pallas interpreter, which is how the CPU suites
+    validate it; asking for it on an accelerator is an error."""
+    if flag in ("off", "auto"):
+        return "off"
+    if flag == "interpret":
+        if platform != "cpu":
+            raise ValueError(
+                "KTPU_PALLAS=interpret is a CPU test mode; refused on "
+                f"platform {platform!r}")
+        return "interpret"
+    return "compiled"
 
 
 # ---------------------------------------------------------------------------
@@ -134,8 +137,7 @@ def default_interpret() -> bool:
 def wave_solve(req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
                mask, static_scores, fit_col_w, bal_col_mask, shape_u,
                shape_s, w_fit, w_bal, strategy: str, wave_w: int,
-               rows, exc, *, poison: bool, perms=None,
-               interpret: bool = True):
+               rows, exc, *, poison: bool, interpret: bool, perms=None):
     """Run the full wavefront solve as one fused pallas_call.
 
     Argument contract matches `solver._rescoring_wave_scan` (class
@@ -151,7 +153,6 @@ def wave_solve(req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
     commits (K,), replays (K,), poisoned (K,) bool) — the caller
     un-permutes and selects, exactly like the scan wrappers.
     """
-    pl = _import_pallas()
     n = free_q.shape[0]
     p = req_q.shape[0]
     r = req_q.shape[1]
@@ -385,7 +386,7 @@ def wave_solve(req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
 def wave_eval(mask, static_sc, alloc_q, free_q, free_pods, used_nz,
               req, req_nz, row, e, el, real, fit_col_w, bal_col_mask,
               shape_u, shape_s, w_fit, w_bal, strategy: str,
-              *, interpret: bool = True):
+              *, interpret: bool):
     """Fused shard-local (W, local_n) wave evaluation.
 
     Returns (masked (W, local_n) scores with NEG_INF = infeasible,
@@ -393,7 +394,6 @@ def wave_eval(mask, static_sc, alloc_q, free_q, free_pods, used_nz,
     `wave_step` computes inline; `el` is the exception column in LOCAL
     shard coordinates (e - base), `e` the global one (for the -1 gate).
     """
-    pl = _import_pallas()
     local_n = free_q.shape[0]
     sc_dtype = jnp.result_type(static_sc.dtype, jnp.float32)
 

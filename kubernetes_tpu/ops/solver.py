@@ -1204,15 +1204,16 @@ def greedy_assign_rescoring_wave_pallas(req_q, req_nz_q, free_q,
                                         bal_col_mask, shape_u, shape_s,
                                         w_fit, w_bal, strategy: str,
                                         wave_w: int, rows=None, exc=None,
-                                        interpret: bool = True):
+                                        *, interpret: bool):
     """greedy_assign_rescoring_wave with the wave scan replaced by the
     fused Pallas kernel (ops/pallas_kernel.py) — one grid step per wave,
     carry resident, in-step serial replay of conflicted waves inside the
     kernel. Same signature, same returns, assignments bit-identical to
     the scan at every wave_w (the kernel body runs the identical op
     sequence); the scan stays the semantic reference and the router's
-    fallback target. interpret=True validates on CPU; False compiles
-    (accelerator backends only)."""
+    fallback target. interpret=True runs the Pallas interpreter (the
+    CPU test mode); False compiles the real kernel, and a backend that
+    cannot lower it raises (see pallas_kernel.resolve_mode)."""
     from kubernetes_tpu.ops import pallas_kernel  # local: import cycle
 
     if rows is None:
@@ -1233,8 +1234,7 @@ def multistart_greedy_assign_wave_pallas(req_q, req_nz_q, free_q,
                                          w_fit, w_bal, strategy: str,
                                          wave_w: int, perms, gang_onehot,
                                          gang_required, rows=None,
-                                         exc=None,
-                                         interpret: bool = True):
+                                         exc=None, *, interpret: bool):
     """multistart_greedy_assign_wave with the K vmapped wave scans
     replaced by ONE fused pallas_call whose grid major axis is the order
     index k (each order owns its carry block). The poison contract, the

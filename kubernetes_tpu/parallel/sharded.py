@@ -41,24 +41,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubernetes_tpu.ops import kernels, pallas_kernel, solver
 from kubernetes_tpu.parallel.mesh import NODES_AXIS, PODS_AXIS, SLICE_AXIS
-
-try:  # jax>=0.8 top-level; fall back for older versions
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# The replication-check kwarg was renamed across jax versions
-# (check_rep → check_vma); pass whichever this jax understands.
-import inspect as _inspect
-
-_params = _inspect.signature(shard_map).parameters
-_SHARD_MAP_KW = {"check_vma": False} if "check_vma" in _params else (
-    {"check_rep": False} if "check_rep" in _params else {})
 
 _INT_MAX = jnp.int32(2**31 - 1)
 
@@ -144,7 +131,7 @@ def sharded_greedy_assign(mesh: Mesh, req_q, req_nz_q, free_q, free_pods,
                           w_fit, w_bal, strategy: str,
                           shortlist_k: int = 0, rows=None, exc=None,
                           row_req_q=None, row_req_nz_q=None,
-                          wave_w: int = 0, pallas: bool = False,
+                          wave_w: int = 0, pallas: str = "off",
                           block_w: int = 0):
     """Sequential-equivalent greedy with live re-scoring, node axis sharded.
 
@@ -175,15 +162,19 @@ def sharded_greedy_assign(mesh: Mesh, req_q, req_nz_q, free_q, free_pods,
     keeps the full-width local prefilter (same clamp rule as the
     backend's tuner row).
 
-    pallas=True fuses each wave's shard-local (W, local_n) evaluation —
-    plane gather, exception gate, capacity fit, live re-score, feasible
-    masking — into one Pallas kernel per wave step
-    (ops/pallas_kernel.wave_eval). Everything that crosses the mesh is
-    UNCHANGED: the W pmax/pmin winner rounds, the global-coordinate
-    conflict OR-reduce, and the commit/replay cond stay in the shard_map
-    body (SURVEY §5.8's ICI reduction contract), so assignments remain
-    bit-identical at every shard count. The shortlist path keeps its
-    W=1 scan (shortlist_k wins when both are set), as before.
+    `pallas` is a mode `ops/pallas_kernel.resolve_mode` answered — the
+    policy lives there, none here. "interpret" (the CPU test mode) or
+    "compiled" (the real kernel, or its compile error) fuses each
+    wave's shard-local (W, local_n) evaluation — plane gather,
+    exception gate, capacity fit, live re-score, feasible masking —
+    into one Pallas kernel per wave step
+    (ops/pallas_kernel.wave_eval); "off" keeps the inline form.
+    Everything that crosses the mesh is UNCHANGED: the W pmax/pmin
+    winner rounds, the global-coordinate conflict OR-reduce, and the
+    commit/replay cond stay in the shard_map body (SURVEY §5.8's ICI
+    reduction contract), so assignments remain bit-identical at every
+    shard count. The shortlist path keeps its W=1 scan (shortlist_k
+    wins when both are set), as before.
 
     Class-dictionary planes (the r14 format): `mask`/`static_scores` may
     carry C CLASS rows instead of P pod rows — pass `rows` ((P,) pod →
@@ -211,7 +202,7 @@ def sharded_greedy_assign(mesh: Mesh, req_q, req_nz_q, free_q, free_pods,
     k = min(shortlist_k, local_n - 1) if shortlist_k else 0
     run = _solver_fn(mesh, strategy, local_n, shortlist_k=max(k, 0),
                      wave_w=0 if k else max(0, wave_w),
-                     pallas=bool(pallas and not k and wave_w > 1),
+                     pallas=pallas if not k and wave_w > 1 else "off",
                      block_w=_block_w_for(block_w, k, local_n))
     p = req_q.shape[0]
     if rows is None:
@@ -234,7 +225,7 @@ def _wave_body(mesh, axes, local_n, base, iota, strategy, wave_w,
                local_full, _reduce,
                req_q, req_nz_q, rows, exc, free_q, free_pods, used_nz,
                alloc_q, mask, static_sc, fit_col_w, bal_col_mask,
-               shape_u, shape_s, w_fit, w_bal, pallas: bool = False):
+               shape_u, shape_s, w_fit, w_bal, pallas: str = "off"):
     """The wavefront wave-step body of the sharded solver (traced inside
     the shard_map `run`; see sharded_greedy_assign's wave_w contract).
 
@@ -258,13 +249,11 @@ def _wave_body(mesh, axes, local_n, base, iota, strategy, wave_w,
     (req_w, req_nz_w, rows_w, ex_w), real_w, _ = _wave_split(
         W, (req_q, req_nz_q, rows, ex))
     w_iota = jnp.arange(W, dtype=jnp.int32)
-    interp = pallas_kernel.default_interpret() if pallas else True
-
     def wave_step(carry, inp):
         free_q, free_pods, used_nz = carry
         req, req_nz, row, e, real = inp
         el = e - base                                   # local exc coords
-        if pallas:
+        if pallas != "off":
             # Fused shard-local evaluation: same op sequence, one
             # kernel — the inline form below is the bit-identical
             # reference (tests/test_pallas_solver.py).
@@ -272,7 +261,7 @@ def _wave_body(mesh, axes, local_n, base, iota, strategy, wave_w,
                 mask, static_sc, alloc_q, free_q, free_pods, used_nz,
                 req, req_nz, row, e, el, real, fit_col_w, bal_col_mask,
                 shape_u, shape_s, w_fit, w_bal, strategy,
-                interpret=interp)
+                interpret=pallas == "interpret")
         else:
             m = mask[row] \
                 & ((e < 0)[:, None] | (iota[None, :] == el[:, None])) \
@@ -380,7 +369,7 @@ def _wave_body(mesh, axes, local_n, base, iota, strategy, wave_w,
 def _solver_fn(mesh: Mesh, strategy: str, local_n: int,
                axes: tuple[str, ...] = (NODES_AXIS,),
                shortlist_k: int = 0, wave_w: int = 0,
-               pallas: bool = False, block_w: int = 0):
+               pallas: str = "off", block_w: int = 0):
     """One solver body for every mesh shape: the node dimension shards over
     `axes` (flattened, first axis major). Reductions run innermost-axis
     first, so a (slice, nodes) pair reduces slice-locally over ICI before
@@ -409,7 +398,7 @@ def _solver_fn(mesh: Mesh, strategy: str, local_n: int,
              in_specs=(rep, rep, rep, rep, rep, rep,
                        spec_nr, spec_n, spec_nr, spec_nr,
                        spec_pn, spec_pn, rep, rep, rep, rep, rep, rep),
-             out_specs=rep, **_SHARD_MAP_KW)
+             out_specs=rep, check_vma=False)
     def run(req_q, req_nz_q, rows, exc, row_req_q, row_req_nz_q,
             free_q, free_pods, used_nz, alloc_q,
             mask, static_sc, fit_col_w, bal_col_mask, shape_u, shape_s,
@@ -613,7 +602,7 @@ def _sinkhorn_fn(mesh: Mesh, axes: tuple[str, ...]):
     @jax.jit
     @partial(shard_map, mesh=mesh,
              in_specs=(spec_cn, spec_cn, rep, spec_n, rep, rep),
-             out_specs=(spec_cn, spec_cn), **_SHARD_MAP_KW)
+             out_specs=(spec_cn, spec_cn), check_vma=False)
     def sink_run(feasible, cost, row_counts, col_cap, iters, temp):
         from kubernetes_tpu.ops.solver import SINKHORN_STAGES
 
@@ -672,9 +661,7 @@ def resident_row_scatter(mesh: Mesh | None, sharding=None):
     would re-pay the full-upload cost the scatter exists to avoid). On
     a single device (mesh=None) it is a plain jitted scatter.
 
-    Cached per (mesh, sharding) like the solver bodies; jax versions
-    without jit out_shardings fall back to propagation (correct, at
-    worst one re-shard on the next dispatch)."""
+    Cached per (mesh, sharding) like the solver bodies."""
     key = (mesh, sharding)
     fn = _SCATTER_CACHE.get(key)
     if fn is not None:
@@ -684,10 +671,7 @@ def resident_row_scatter(mesh: Mesh | None, sharding=None):
         return pack.at[rows].set(vals)
 
     if mesh is not None and sharding is not None:
-        try:
-            fn = jax.jit(body, out_shardings=sharding)
-        except TypeError:  # pragma: no cover - older jax kwarg names
-            fn = jax.jit(body)
+        fn = jax.jit(body, out_shardings=sharding)
     else:
         fn = jax.jit(body)
     _SCATTER_CACHE[key] = fn
@@ -706,7 +690,7 @@ def sharded_greedy_assign_multislice(mesh: Mesh, req_q, req_nz_q, free_q,
                                      rows=None, exc=None,
                                      row_req_q=None, row_req_nz_q=None,
                                      wave_w: int = 0,
-                                     pallas: bool = False,
+                                     pallas: str = "off",
                                      block_w: int = 0):
     """Sequential-equivalent greedy over a (slice × nodes) mesh: the same
     solver body as `sharded_greedy_assign`, with the node dimension sharded
@@ -726,7 +710,7 @@ def sharded_greedy_assign_multislice(mesh: Mesh, req_q, req_nz_q, free_q,
     run = _solver_fn(mesh, strategy, local_n,
                      axes=(SLICE_AXIS, NODES_AXIS), shortlist_k=max(k, 0),
                      wave_w=0 if k else max(0, wave_w),
-                     pallas=bool(pallas and not k and wave_w > 1),
+                     pallas=pallas if not k and wave_w > 1 else "off",
                      block_w=_block_w_for(block_w, k, local_n))
     p = req_q.shape[0]
     if rows is None:

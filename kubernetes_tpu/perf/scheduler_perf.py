@@ -47,6 +47,7 @@ from __future__ import annotations
 import asyncio
 import copy
 import json
+import sys
 import time
 from typing import Any, Mapping
 
@@ -76,6 +77,9 @@ class WorkloadResult:
     def __init__(self):
         self.throughput = 0.0          # pods/s over the measured phase
         self.measured_pods = 0
+        #: time.monotonic() at the start of the measured window (for
+        #: callers that place their own events inside or outside it).
+        self.measured_start = 0.0
         self.measured_seconds = 0.0
         self.attempt_p50 = 0.0
         self.attempt_p90 = 0.0
@@ -167,10 +171,26 @@ class WorkloadResult:
         #: kernel and fell back to the lax.scan reference, plus the
         #: solve-backend provenance row (jax platform, device count,
         #: resolved pallas mode, carry donation) stamped per family so a
-        #: relay row and a CPU row are never mistaken for each other.
+        #: CPU pre-flight row is never mistaken for a chip row.
         self.solver_pallas_solves_total = 0
         self.solver_pallas_fallbacks_total = 0
         self.solve_provenance: dict = {}
+        #: Device-loss accounting over the WHOLE run (warm-up included —
+        #: a backend that failed there and recovered still failed):
+        #: batches the backend raised on and the host path scheduled
+        #: (schedule_attempts{result="backend_fallback"}), fast-path
+        #: solves/warm-ups that raised, and whether the backend was
+        #: still attached at the end (False = the circuit opened; None =
+        #: the run never had one). `device_run_failures` reads these.
+        #: host_path_pods: pods a scheduler WITH a backend still placed
+        #: plugin by plugin (backend_degradations{kind="host_path"}) —
+        #: by design for a lone pod the fast path cannot take, so
+        #: reported, and only chip_smoke.py's accounting gates on it.
+        #: All three come from SchedulerMetrics.device_loss_counts.
+        self.host_path_pods = 0
+        self.backend_fallback_total = 0
+        self.fast_path_failures_total = 0
+        self.backend_attached: bool | None = None
         #: Class-dictionary device-plane accounting over the measured
         #: phase (r14): host-side chunk-prep wall (the prep-vs-solve
         #: split per family), equivalence classes behind the latest
@@ -345,6 +365,10 @@ class WorkloadResult:
             "solver_pallas_fallbacks_total":
                 self.solver_pallas_fallbacks_total,
             "solve_provenance": self.solve_provenance,
+            "host_path_pods": self.host_path_pods,
+            "backend_fallback_total": self.backend_fallback_total,
+            "fast_path_failures_total": self.fast_path_failures_total,
+            "backend_attached": self.backend_attached,
             "solver_optimal_solves_total": self.solver_optimal_solves_total,
             "solver_optimal_fallbacks_total":
                 self.solver_optimal_fallbacks_total,
@@ -401,6 +425,66 @@ class WorkloadResult:
             "topology_plane_rebuilds_total":
                 self.topology_plane_rebuilds_total,
         }
+
+
+def resolve_processes(processes: int | None = None) -> int:
+    """The control plane's OS-process count: the explicit request, else
+    KTPU_PROCESSES, else 1. Every entry point resolves it HERE, before
+    it constructs anything — with N >= 2 the chip is the leader
+    replica's, and a parent that has built a backend holds it."""
+    from kubernetes_tpu.utils import flags
+    if processes is None:
+        processes = flags.get("KTPU_PROCESSES") or 1
+    return int(processes)
+
+
+def device_backend(chunk: int | None, processes: int):
+    """(backend, backend_spec) for a PerfRunner that was asked for the
+    device: in one process a `TPUBackend`; with `processes` >= 2 only
+    the SPEC the elected leader builds its own from — this process then
+    never imports jax (importing it here is what the caller avoids)."""
+    if processes > 1:
+        return None, {"kind": "tpu", "chunk": chunk}
+    from kubernetes_tpu.ops import TPUBackend
+    return TPUBackend(max_batch=chunk), None
+
+
+def device_run_failures(detail: Mapping[str, Any]) -> list[str]:
+    """Why a run that WAS ASKED FOR THE DEVICE counts as failed, from
+    its `WorkloadResult.as_dict()` ([] = it kept the device throughout).
+    The circuit breaker and the fast-path reroute keep pods scheduling
+    through a device fault — a product behaviour — but the numbers such
+    a run prints are the host scheduler's. A run nobody attached a
+    backend to (`backend_attached` None) is the same thing from the
+    start. Call it only for runs asked for the device; host runs have
+    nothing to lose."""
+    out = [f"{k}={detail[k]}"
+           for k in ("backend_fallback_total", "fast_path_failures_total")
+           if detail[k]]
+    if detail["backend_attached"] is None:
+        out.append("backend_attached=null (no device backend was ever "
+                   "attached: the host path scheduled this run)")
+    elif not detail["backend_attached"]:
+        out.append("backend_attached=false (the circuit opened, or no "
+                   "replica vouched for a device solve)")
+    return out
+
+
+def device_exit(details: list[Mapping[str, Any]], processes: int) -> int:
+    """Exit code of a finished run that was asked for the device — the
+    one rule behind `bench.py --backend tpu` (all modes) and this
+    module's `--backend tpu`: non-zero when any detail row lost the
+    device, or when the parent of a multi-process run imported jax (the
+    chip is the leader replica's)."""
+    lost = [why for d in details if (why := device_run_failures(d))]
+    if processes > 1 and "jax" in sys.modules:
+        lost.append(["this parent process imported jax; the chip is the "
+                     "leader replica's"])
+    if lost:
+        print(f"FAILED: the device was lost: {json.dumps(lost)}",
+              file=sys.stderr)
+        return 1
+    return 0
 
 
 DEFAULT_NODE_TEMPLATE = {
@@ -488,6 +572,7 @@ class PerfRunner:
     store + scheduler, mirroring mustSetupCluster → runWorkload."""
 
     def __init__(self, backend=None, batch_size: int = 1,
+                 backend_spec: Mapping | None = None,
                  scheduler_kwargs: Mapping | None = None,
                  scheduler_config: Mapping | None = None,
                  through_apiserver: bool = False,
@@ -499,6 +584,11 @@ class PerfRunner:
                  processes: int | None = None,
                  data_dir: str | None = None):
         self.backend = backend
+        #: what the leader replica of a multi-process run builds ITS
+        #: backend from ({"kind": "tpu", "chunk": N|None}). The chip
+        #: belongs to that one process: the parent passes this spec,
+        #: never a backend, and so never touches JAX.
+        self.backend_spec = dict(backend_spec) if backend_spec else None
         self.batch_size = batch_size
         self.scheduler_kwargs = dict(scheduler_kwargs or {})
         #: control-plane shard count for the backing store (>1 builds a
@@ -564,15 +654,22 @@ class PerfRunner:
                          params: Mapping[str, Any],
                          timeout: float = 600.0) -> WorkloadResult:
         from kubernetes_tpu.utils import flags
-        nproc = self.processes
-        if nproc is None:
-            nproc = int(flags.get("KTPU_PROCESSES") or 1)
+        nproc = resolve_processes(self.processes)
+        if nproc > 1 and self.backend is not None:
+            # Whoever built that backend touched JAX and holds the chip
+            # the leader replica needs; and no child would schedule
+            # through it (children build their own from backend_spec),
+            # so the run would be the host path under a device's name.
+            raise ValueError(
+                f"a {nproc}-process run takes backend_spec, not a "
+                "backend: resolve the process count first "
+                "(resolve_processes / device_backend)")
         cp = None
         self._mp = None
         self._cp = None
         server = None
         client = None
-        if int(nproc) > 1:
+        if nproc > 1:
             # r22 tentpole topology: one apiserver OS process per shard
             # plus a leader-elected scheduler pair; the parent only
             # stages the workload and reads results through the
@@ -582,14 +679,11 @@ class PerfRunner:
                 MeasureProtocol,
                 MultiProcessControlPlane,
             )
-            backend_spec = None
-            if self.backend is not None:
-                backend_spec = {"kind": "tpu", "chunk": int(getattr(
-                    self.backend, "max_batch", 1) or 1)}
             cp = MultiProcessControlPlane(
-                int(nproc),
+                nproc,
                 data_dir=self.data_dir or flags.get("KTPU_DATA_DIR"),
-                backend_spec=backend_spec, batch_size=self.batch_size,
+                backend_spec=self.backend_spec,
+                batch_size=self.batch_size,
                 scheduler_kwargs=self.scheduler_kwargs)
             try:
                 await cp.start()
@@ -710,6 +804,7 @@ class PerfRunner:
         agents: list = []
         agent_dir: str | None = None
         deadline = time.monotonic() + timeout
+        completed = False
         try:
             for op in template_ops:
                 opcode = op["opcode"]
@@ -979,6 +1074,7 @@ class PerfRunner:
 
                 else:
                     raise ValueError(f"unknown opcode {opcode!r}")
+            completed = True
         finally:
             if agents:
                 await asyncio.gather(
@@ -989,23 +1085,31 @@ class PerfRunner:
             await sched.stop()
             run_task.cancel()
             factory.stop()
+            finalize_error = None
             if cp is not None:
-                # WAL/HA counters live in the children: pull them while
-                # the shard sockets still answer (best-effort on an
-                # exception path — the primary failure must surface).
+                # WAL/HA and device counters live in the children: pull
+                # them while the shard sockets still answer. A failure
+                # here waits until the children are stopped (the leader
+                # holds the chip the next run's leader needs), and is
+                # dropped only when another exception is already on its
+                # way out — the primary failure must surface.
                 try:
                     await self._finalize_multiproc(result, backing)
-                except Exception:
-                    pass
-            if client is not None:
-                await client.close()
-            if server is not None:
-                await server.stop()
-            backing.stop()
-            if cp is not None:
-                await cp.stop()
-                self._cp = None
-                self._mp = None
+                except Exception as e:
+                    finalize_error = e
+            try:
+                if client is not None:
+                    await client.close()
+                if server is not None:
+                    await server.stop()
+                backing.stop()
+            finally:
+                if cp is not None:
+                    await cp.stop()
+                    self._cp = None
+                    self._mp = None
+            if completed and finalize_error is not None:
+                raise finalize_error
 
         # Percentiles were captured over the measured window above
         # (scheduler_scheduling_attempt_duration_seconds — SURVEY §5.5);
@@ -1021,6 +1125,10 @@ class PerfRunner:
             result.scheduled_total = _result_count(metrics, "scheduled")
             result.unschedulable_total = _result_count(
                 metrics, "unschedulable")
+            for k, v in metrics.device_loss_counts().items():
+                setattr(result, k, v)
+            if self.backend is not None:
+                result.backend_attached = sched.backend is not None
         result.shard_count = int(getattr(backing, "node_shards", 1))
         result.fragmentation_pct = self._fragmentation(sched)
         result.fragmentation_occupied_pct = \
@@ -1431,6 +1539,17 @@ class PerfRunner:
         result.process_count = int(backing.node_shards)
         result.scheduled_total = _i(row.get("scheduledTotal"))
         result.leader_elections_total = _i(row.get("elections"))
+        if self.backend_spec is not None:
+            # The leader's word on the device (multiproc/schedproc.py):
+            # a row that cannot vouch for it — no leader answered, or
+            # no device solve ever ran — counts as the device lost.
+            for k in ("backend_fallback_total", "fast_path_failures_total",
+                      "host_path_pods"):
+                setattr(result, k, _i(row.get(k)))
+            result.backend_attached = row.get("backendAttached") == "1" \
+                and _i(row.get("deviceSolves")) > 0
+            result.solve_provenance = json.loads(
+                row.get("provenance") or "{}")
         total = (await backing.control_stats()).get("total") or {}
         result.wal_appends_total = _i(total.get("walAppends"))
         result.wal_replay_entries_total = _i(total.get("walReplayed"))
@@ -1494,6 +1613,7 @@ class PerfRunner:
          window_mark) = window
         dt = time.monotonic() - t0
         result.measured_pods = count
+        result.measured_start = t0
         result.measured_seconds = dt
         result.throughput = count / dt if dt > 0 else 0.0
         h = metrics.attempt_duration
@@ -1676,19 +1796,23 @@ def load_config(path: str) -> list[dict]:
 def run_suite(config: list[dict], backend_factory=None, batch_size: int = 1,
               filter_name: str | None = None, timeout: float = 600.0,
               through_apiserver=False) -> dict[str, dict]:
-    """Run every (testcase × workload) pair, like BenchmarkPerfScheduling."""
+    """Run every (testcase × workload) pair, like BenchmarkPerfScheduling.
+    `backend_factory()` -> (backend, backend_spec), fresh per workload
+    (see `device_backend`); None runs the host scheduler."""
     out: dict[str, dict] = {}
     for case in config:
         for wl in case.get("workloads") or [{"name": "default", "params": {}}]:
             full = f"{case['name']}/{wl['name']}"
             if filter_name and filter_name not in full:
                 continue
-            backend = backend_factory() if backend_factory else None
+            backend, spec = backend_factory() if backend_factory \
+                else (None, None)
             # Per-family runner settings: a family may pin the apiserver
             # boundary and a policy/audit load (PolicyScale carries the
             # 1k-tenant set) so headline rows are reproducible from
             # config alone.
-            runner = PerfRunner(backend=backend, batch_size=batch_size,
+            runner = PerfRunner(backend=backend, backend_spec=spec,
+                                batch_size=batch_size,
                                 scheduler_config=case.get("schedulerConfig"),
                                 through_apiserver=case.get(
                                     "throughApiserver", through_apiserver),
@@ -1712,8 +1836,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--batch-size", type=int, default=1)
     ap.add_argument("--chunk", type=int, default=None,
                     help="OVERRIDE the backend solve chunk (jit batch "
-                         "signature); default lets the adaptive tuner "
-                         "choose per measured latency/dirty ratio")
+                         "signature); default 1024")
     ap.add_argument("--filter", default=None)
     ap.add_argument("--timeout", type=float, default=600.0,
                     help="per-workload deadline in seconds (the 20k-agent "
@@ -1727,18 +1850,27 @@ def main(argv: list[str] | None = None) -> int:
 
     factory = None
     batch = args.batch_size
+    # Before anything is constructed: with KTPU_PROCESSES >= 2 this
+    # process hands the leader replica a spec and stays off JAX.
+    nproc = resolve_processes()
     if args.backend == "tpu":
-        from kubernetes_tpu.ops import TPUBackend
         batch = max(batch, 128)
         chunk = None if args.chunk is None \
             else max(min(args.chunk, batch), 2)
-        factory = lambda: TPUBackend(max_batch=chunk)  # noqa: E731
+        if nproc <= 1:  # the leader enables its own (schedproc.py)
+            from kubernetes_tpu.utils.compile_cache import (
+                enable_compile_cache,
+            )
+            enable_compile_cache()
+        factory = lambda: device_backend(chunk, nproc)  # noqa: E731
     boundary = {"": False, "http": True, "wire": "wire"}[
         args.through_apiserver]
     results = run_suite(load_config(args.config), backend_factory=factory,
                         batch_size=batch, filter_name=args.filter,
                         timeout=args.timeout, through_apiserver=boundary)
     print(json.dumps(results, indent=2))
+    if args.backend == "tpu":
+        return device_exit(list(results.values()), nproc)
     return 0
 
 

@@ -42,7 +42,6 @@ import numpy as np
 from kubernetes_tpu.scheduler.framework import CycleState, Plugin, Status
 from kubernetes_tpu.scheduler.plugins.coscheduling import POD_GROUP_LABEL
 from kubernetes_tpu.scheduler.types import NodeInfo, PodInfo, Snapshot
-from kubernetes_tpu.topology import device as topo_device
 from kubernetes_tpu.topology.mesh import (
     MeshSpec,
     node_cell,
@@ -194,6 +193,11 @@ class TopologySlice(Plugin):
             other = self._claims.get(ni.name)
             free[cell] = (other is None or other == gk) \
                 and self._node_fits(ni, pod)
+        # Imported at first use: topology.device pulls in jax, and the
+        # parent of a multi-process run (which imports every plugin
+        # through the scheduler) must stay off it — a chip belongs to
+        # the one process that schedules.
+        from kubernetes_tpu.topology import device as topo_device
         scan = topo_device.device_scan(free, spec, shape)
         if scan is not None:
             key, _feas, _frag, covered = scan

@@ -632,7 +632,7 @@ class Scheduler:
                     pods, snapshot, fwk, t0)
             if hasattr(self.backend, "assign_async"):
                 # Pipelined path: device fetches run in a worker thread, so
-                # binding tasks keep draining during device/relay waits.
+                # binding tasks keep draining during device waits.
                 assignments, diagnostics = await self.backend.assign_async(
                     pods, snapshot, fwk)
             else:
@@ -783,6 +783,14 @@ class Scheduler:
                                attempts=pi.attempts)
 
     async def _schedule_host_path(self, pi: PodInfo, snapshot) -> None:
+        if self.backend is not None:
+            # A scheduler that HAS a device backend is placing this pod
+            # plugin by plugin: a one-pod dispatch the fast path
+            # declined, a profile outside backend_profiles, configured
+            # extenders, or the batch after a backend failure. By
+            # design — and counted, so a device run can show that no
+            # pod took it.
+            self.metrics.backend_degradations.inc(kind="host_path")
         fwk = self.profiles.get(pi.scheduler_name)
         if fwk is None:
             logger.error("no profile for schedulerName=%s", pi.scheduler_name)

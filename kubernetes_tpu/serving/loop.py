@@ -64,8 +64,7 @@ class ServingTier:
     def __init__(self, sched):
         self.sched = sched
         backend = sched.backend
-        self.window = AdmissionWindow(
-            tuner=getattr(backend, "_tuner", None), metrics=sched.metrics)
+        self.window = AdmissionWindow(metrics=sched.metrics)
         self.resident = ResidentPlanes(backend, metrics=sched.metrics)
         self.fastpath = SinglePodFastPath(
             backend, self.resident, metrics=sched.metrics)
@@ -159,6 +158,15 @@ class ServingTier:
                 pods = await self._drain_fast(pods)
                 if not pods:
                     return True
+            elif len(pods) == 1 and await self._try_fast_path(pods[0]):
+                # The gates above choose between a serial drain and the
+                # batch pipeline, but a ONE-pod dispatch has no batch
+                # pipeline to fall to: Scheduler._schedule_pods places a
+                # lone pod plugin by plugin on the host, O(N·plugins) of
+                # Python against one device solve. On the chip at 250/s
+                # that silently put 164 of 4,986 trickle pods on the
+                # host scheduler (PR 21, chip_smoke.py).
+                return True
         await self._schedule_batch_timed(pods)
         return True
 
@@ -228,8 +236,10 @@ class ServingTier:
             # The fast path must never break scheduling: any device/host
             # error just reroutes the pod through the normal path (and
             # does NOT count toward the batch backend's circuit breaker
-            # — a fast-path-only fault shouldn't kill batch solves).
+            # — a fast-path-only fault shouldn't kill batch solves). It
+            # is counted: entry points asked for the device fail on it.
             logger.exception("fast path failed for %s; normal path", pi.key)
+            sched.metrics.serving_fast_path_failures.inc()
             return False
         wall = time.perf_counter() - t0
         if node is None:
@@ -269,5 +279,8 @@ class ServingTier:
             return
         try:
             self.fastpath.warm(pi, sched.cache.update_snapshot(), fwk)
-        except Exception:  # pragma: no cover - warmup is best-effort
-            logger.debug("fast-path warmup failed", exc_info=True)
+        except Exception:
+            # Retried on the next dispatch; a warm-up that keeps failing
+            # is a device fault like any other fast-path one.
+            logger.warning("fast-path warmup failed", exc_info=True)
+            sched.metrics.serving_fast_path_failures.inc()

@@ -33,22 +33,11 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kubernetes_tpu.parallel.mesh import SLICE_AXIS
 from kubernetes_tpu.topology.mesh import MeshSpec, orientations
-
-try:  # jax>=0.8 top-level; fall back for older versions
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-_params = _inspect.signature(shard_map).parameters
-_SHARD_MAP_KW = {"check_vma": False} if "check_vma" in _params else (
-    {"check_rep": False} if "check_rep" in _params else {})
 
 #: compiled scan per (dims, wrap, orientations) signature.
 _SCAN_CACHE: dict = {}
@@ -203,7 +192,7 @@ def best_key(key: np.ndarray, shards: int | None = None) -> int:
 
         fn = _SHARDED_MAX_CACHE[S] = jax.jit(shard_map(
             local_max, mesh=mesh, in_specs=P(SLICE_AXIS), out_specs=P(),
-            **_SHARD_MAP_KW))
+            check_vma=False))
     return int(fn(jnp.asarray(padded)))
 
 

@@ -128,11 +128,12 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
     _flag("KTPU_PALLAS", "auto", _parse_pallas,
           "Fused Pallas wavefront solve kernel (ops/pallas_kernel.py). "
           "`off` is the kill switch — the exact r20 lax.scan call graph, "
-          "bit-identical assignments. `auto` (default) compiles the "
-          "kernel on accelerator backends only and keeps the scan on "
-          "CPU; `on` forces the kernel (compiled when lowering is "
-          "available, else interpret); `interpret` forces the "
-          "interpreter everywhere (the CPU tier-1 validation mode). "
+          "bit-identical assignments. `auto` (default) is off on every "
+          "platform by policy: the kernel does not lower for the TPU "
+          "(`pallas_kernel.resolve_mode` quotes the compiler). `on` "
+          "compiles the real kernel or fails the run — never "
+          "interpret; `interpret` runs the Pallas interpreter, a CPU "
+          "test mode that is refused on any other platform. "
           "Structural fallbacks to the scan are counted in "
           "`solver_pallas_fallbacks_total`.", kill_switch=True),
     _flag("KTPU_BLOCK_INDEX", True, _parse_bool,
@@ -233,8 +234,8 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
           "two)."),
     _flag("KTPU_PIPELINE_DEPTH", None, _parse_int,
           "Solve-pipeline depth override (chunks in flight ahead of "
-          "the fetch). Unset = the AdaptiveTuner picks from measured "
-          "transfer latency."),
+          "the fetch). Unset = the AdaptiveTuner's table: 4, then 2 "
+          "once warmed up (from the first assign at large N)."),
     _flag("KTPU_SHORTLIST_K", None, _parse_int,
           "Shortlist width override for the pruned solve; `0` disables "
           "pruning. Unset = the tuner derives K from chunk width and "
@@ -266,8 +267,11 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
           "Recursively freeze stored/watch-delivered objects so a "
           "mutating handler fails loudly (enabled by the test suite)."),
     _flag("KTPU_TEST_PLATFORM", "cpu", _parse_str,
-          "jax platform the test suite runs against (tests/conftest.py; "
-          "set to run the suite on real hardware)."),
+          "jax platform the test suite runs against (tests/conftest.py "
+          "exports it as JAX_PLATFORMS before jax initializes). `tpu` "
+          "points a suite at the chip: the Pallas interpret-mode suites "
+          "and tests that need more devices than it has skip; PERF.md "
+          "records which suites have run there."),
 )}
 
 

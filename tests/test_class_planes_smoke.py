@@ -5,10 +5,8 @@ class row, a mixed chunk a handful, and the plane-byte/prep metrics
 flow; (b) the KTPU_CLASS_PLANES=0 kill switch degrading structurally to
 per-pod planes (C == P) with identical assignments; (c) the exception
 list carrying single-column host rows (NodeName pins) without splitting
-a class; (d) the KTPU_CLASS_PAD overflow fallback counting its pods;
-(e) the AdaptiveTuner chunk table re-swept under class-plane prep costs
-(BASELINE r14: the large-N row held at 1024). The heavyweight
-randomized parity lives in tests/test_class_planes.py.
+a class; (d) the KTPU_CLASS_PAD overflow fallback counting its pods.
+The heavyweight randomized parity lives in tests/test_class_planes.py.
 """
 
 import pytest
@@ -16,7 +14,6 @@ import pytest
 from kubernetes_tpu.api.types import make_node, make_pod
 from kubernetes_tpu.metrics.registry import SchedulerMetrics
 from kubernetes_tpu.ops.backend import (
-    AdaptiveTuner,
     TPUBackend,
     _class_rows_bucket,
     class_pad,
@@ -134,17 +131,3 @@ class TestActiveByDefault:
         assert all(v is not None for v in assignments.values())
         assert b.metrics.class_split_fallbacks.value() == len(pods)
         assert b.metrics.plane_classes.value() == len(pods)
-
-
-class TestTunerResweep:
-    def test_chunk_rows_post_class_planes(self):
-        """BASELINE r14 re-sweep under O(C·N) prep: the large-N local
-        row HELD at (1024, 2) — the shortlist scan width (2·chunk), not
-        the per-chunk plane cost the class format shrank, still sets
-        the optimum. Remote rows and the small-N local row unchanged."""
-        assert AdaptiveTuner.pick(0.0002, 0.0, n_nodes=50_000) == (1024, 2)
-        assert AdaptiveTuner.pick(0.0002, 0.9, n_nodes=50_000) == (1024, 2)
-        assert AdaptiveTuner.pick(0.0002, 0.0, n_nodes=200_000) == (1024, 2)
-        assert AdaptiveTuner.pick(0.020, 0.0) == (2048, 4)
-        assert AdaptiveTuner.pick(0.020, 0.5) == (1024, 4)
-        assert AdaptiveTuner.pick(0.0002, 0.0) == (1024, 2)

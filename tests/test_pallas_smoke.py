@@ -1,7 +1,8 @@
 """Tier-1 guard for the fused Pallas wavefront kernel (small-N, fast).
 
-Pins: (a) the AdaptiveTuner's KTPU_PALLAS policy row — auto keeps the
-scan on CPU (no compiled lowering), off is the kill switch, and every
+Pins: (a) the KTPU_PALLAS policy table (pallas_kernel.resolve_mode) —
+auto keeps the scan on every platform, off is the kill switch, on means
+compile-or-fail, interpret is refused off the CPU — and every
 structural gate (optimal mode, spread, shortlist, W<=1, working-set
 ceiling) routes back to the scan with a labeled fallback reason;
 (b) CPU default = the EXACT r20 scan call graph with both pallas
@@ -13,7 +14,9 @@ working-set ceiling. The heavyweight randomized differential parity
 lives in tests/test_pallas_solver.py.
 """
 
+import jax
 import numpy as np
+import pytest
 
 from kubernetes_tpu.metrics.registry import SchedulerMetrics
 from kubernetes_tpu.ops import pallas_kernel
@@ -21,16 +24,38 @@ from kubernetes_tpu.ops.backend import AdaptiveTuner, TPUBackend, \
     solve_provenance
 from kubernetes_tpu.utils import flags
 
+#: KTPU_PALLAS=interpret is refused off the CPU (the policy table), so
+#: the tests that set it run only there.
+interpret_only = pytest.mark.skipif(
+    jax.default_backend() != "cpu",
+    reason="KTPU_PALLAS=interpret is a CPU test mode")
+
 
 class TestPallasPolicy:
-    def test_auto_keeps_scan_on_cpu(self):
-        """auto (the default) compiles on accelerator backends only —
-        on CPU the chunk keeps the scan with NO fallback count (the
-        routing never wanted the kernel), so CPU presets are untouched."""
+    def test_auto_keeps_scan(self):
+        """auto (the default) is off BY POLICY on every platform (the
+        kernel does not lower for the TPU): the chunk keeps the scan
+        with NO fallback count — the routing never wanted the kernel."""
         t = AdaptiveTuner()
         mode, fall = t.pallas_mode(8, 0, False, "greedy")
         assert mode == "off" and fall is None
+        for platform in ("cpu", "tpu", "gpu"):
+            assert pallas_kernel.resolve_mode("auto", platform) == "off"
+            assert pallas_kernel.resolve_mode("off", platform) == "off"
 
+    def test_never_interpret_off_the_cpu(self):
+        """interpret is a CPU test mode: no flag value resolves to it on
+        another platform — `on` means the real kernel, `interpret`
+        itself is refused."""
+        for platform in ("tpu", "gpu"):
+            for flag in ("auto", "on", "off"):
+                assert pallas_kernel.resolve_mode(flag, platform) \
+                    != "interpret"
+            with pytest.raises(ValueError, match="CPU test mode"):
+                pallas_kernel.resolve_mode("interpret", platform)
+            assert pallas_kernel.resolve_mode("on", platform) == "compiled"
+
+    @interpret_only
     def test_kill_switch_and_force(self):
         t = AdaptiveTuner()
         with flags.scoped_set("KTPU_PALLAS", "off"):
@@ -41,10 +66,11 @@ class TestPallasPolicy:
             assert t.pallas_mode(8, 0, False, "greedy") == \
                 ("interpret", None)
         with flags.scoped_set("KTPU_PALLAS", "on"):
-            # CPU has no compiled lowering: "on" degrades to interpret.
-            mode, fall = t.pallas_mode(8, 0, False, "greedy")
-            assert mode == "interpret" and fall is None
+            # "on" is the real kernel or nothing — never interpret.
+            assert t.pallas_mode(8, 0, False, "greedy") == \
+                ("compiled", None)
 
+    @interpret_only
     def test_structural_gates_label_fallbacks(self):
         """The kernel fuses only the plain greedy wave branch; every
         other shape keeps the scan, labeled by why."""
@@ -112,6 +138,7 @@ class TestBackendSmoke:
         assert off == auto
         assert b2.metrics.solver_pallas_solves.value() == 0
 
+    @interpret_only
     def test_interpret_activates_with_identical_assignments(self):
         """KTPU_PALLAS=interpret routes wave chunks through the fused
         kernel end-to-end: assignments match the scan exactly and the
@@ -132,6 +159,23 @@ class TestBackendSmoke:
         assert prov["solve_kernel"] == "pallas"
         assert prov["pallas_mode"] == "interpret"
 
+    def test_on_raises_where_the_kernel_does_not_compile(self):
+        """KTPU_PALLAS=on routes the chunk `compiled`; a backend that
+        cannot lower the real kernel raises the compiler's own error
+        from the fused program's compile — nothing catches it to fall
+        through to the scan. Pallas has no compiled CPU lowering; on
+        the v5e Mosaic refuses the block shapes (resolve_mode quotes
+        it)."""
+        from test_tpu_backend import default_fwk
+        refusal = "[Oo]nly interpret mode" \
+            if jax.default_backend() == "cpu" else "Pallas TPU lowering"
+        b = TPUBackend(max_batch=16, mesh=None)
+        with flags.scoped_set("KTPU_PALLAS", "on"), \
+                flags.scoped_set("KTPU_SOLVE_MODE", "greedy"), \
+                pytest.raises(ValueError, match=refusal):
+            b.assign(self._pods(24), self._cluster(100), default_fwk())
+
+    @interpret_only
     def test_shape_fallback_counted(self, monkeypatch):
         """A chunk above the working-set ceiling keeps the scan,
         counted under reason="shape", with identical assignments."""

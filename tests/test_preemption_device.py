@@ -184,29 +184,18 @@ class TestDeviceHostParity:
 
 
 class TestAdaptiveTunerEnvelope:
-    def test_policy_envelope(self):
-        # The documented envelope (AdaptiveTuner docstring / BASELINE r6).
-        assert AdaptiveTuner.pick(0.020, 0.0) == (2048, 4)
-        assert AdaptiveTuner.pick(0.020, 0.5) == (1024, 4)
-        assert AdaptiveTuner.pick(0.0002, 0.0) == (1024, 2)
-        assert AdaptiveTuner.pick(0.0002, 0.9) == (1024, 2)
-
-    def test_flagless_backend_decides_within_envelope(self):
-        backend = TPUBackend()          # flagless: tuner owns both knobs
-        assert not backend._chunk_override
+    def test_flagless_backend_decides_after_warmup(self):
+        backend = TPUBackend()          # flagless: tuner owns the depth
+        assert backend.max_batch == 1024 and backend.pipeline_depth == 4
         t = backend._tuner
         assert t.decide() is None       # warmup: no decision yet
         for _ in range(t.WARMUP_CHUNKS):
-            t.observe_chunk(False)
-        chunk, depth = t.decide()       # probes the (local) device
-        assert chunk in (512, 1024, 2048)
-        assert depth in (2, 4)
-        assert t.latency_s is not None
+            t.observe_chunk()
+        assert t.decide() == 2
+        assert t.probe() > 0 and t.latency_s is not None
 
     def test_explicit_chunk_is_an_override(self):
-        backend = TPUBackend(max_batch=8)
-        assert backend._chunk_override
-        assert backend.max_batch == 8
+        assert TPUBackend(max_batch=8).max_batch == 8
 
 
 class TestWorkloadResultEventDrops:

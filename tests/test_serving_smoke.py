@@ -74,19 +74,13 @@ def _fast(backend):
 class TestAdmissionPolicy:
     def test_tuner_policy_row(self):
         # At/below the r15 trickle (250/s): always immediate.
-        assert AdaptiveTuner.admission_window(0.0, 0.0) == 0.0
-        assert AdaptiveTuner.admission_window(0.0, 250.0) == 0.0
-        # Above it: sized to ~TARGET pods, capped at 4 ms local.
-        w = AdaptiveTuner.admission_window(0.0, 1000.0)
-        assert 0.0 < w <= AdaptiveTuner.ADMISSION_MAX_WINDOW_S
-        assert AdaptiveTuner.admission_window(0.0, 100000.0) \
+        assert AdaptiveTuner.admission_window(0.0) == 0.0
+        assert AdaptiveTuner.admission_window(250.0) == 0.0
+        # Above it: sized to ~TARGET pods, capped at 4 ms.
+        assert AdaptiveTuner.admission_window(1000.0) \
+            == AdaptiveTuner.ADMISSION_MAX_WINDOW_S
+        assert AdaptiveTuner.admission_window(100000.0) \
             == pytest.approx(8.0 / 100000.0)
-        # Relay-attached: the cap quadruples (dispatches cost an RTT),
-        # so a rate the local cap would clamp gets a wider window.
-        assert AdaptiveTuner.admission_window(0.030, 600.0) \
-            > AdaptiveTuner.ADMISSION_MAX_WINDOW_S
-        assert AdaptiveTuner.admission_window(0.030, 600.0) \
-            <= 4 * AdaptiveTuner.ADMISSION_MAX_WINDOW_S
 
     def test_fast_path_cap_row(self):
         # Seeds before any measurement: 0.25 s chunk / 1 ms fast → 250.
@@ -377,3 +371,77 @@ class TestServingE2E:
         assert m_off.resident_plane_refreshes.value() == 0
         # ... and bit-identical batch placements across the switch.
         assert a_on == a_off
+
+
+class TestLonePodDispatch:
+    """A one-pod dispatch has no batch pipeline to fall to
+    (Scheduler._schedule_pods places a lone pod plugin by plugin on the
+    host), so when the cap/rate gates decline the serial drain it still
+    tries the fast path first; only a pod the fast path cannot take
+    reaches the host scheduler — and is counted there."""
+
+    @staticmethod
+    async def _lone_pods(pods):
+        """Create `pods` one at a time, each bound before the next is
+        created, so every dispatch carries exactly one pod."""
+        from conftest import start_scheduler
+        from kubernetes_tpu.store import install_core_validation, \
+            new_cluster_store
+        store = new_cluster_store()
+        install_core_validation(store)
+        for i in range(6):
+            await store.create("nodes", make_node(
+                f"n{i}", allocatable={"cpu": "4", "memory": "16Gi",
+                                      "pods": "32"}))
+        sched, factory = await start_scheduler(
+            store, backend=TPUBackend(max_batch=16, mesh=None))
+        run = asyncio.ensure_future(sched.run(batch_size=64))
+        try:
+            for pod in pods:
+                await store.create("pods", pod)
+                key = "default/" + pod["metadata"]["name"]
+                for _ in range(400):
+                    got = await store.get("pods", key)
+                    if got["spec"].get("nodeName"):
+                        break
+                    await asyncio.sleep(0.025)
+                else:
+                    raise AssertionError(f"{key} never bound")
+            return sched.metrics
+        finally:
+            await sched.stop()
+            run.cancel()
+            factory.stop()
+
+    @pytest.fixture
+    def gates_decline(self, monkeypatch):
+        """Every pop reads an offered rate far above
+        AdaptiveTuner.fast_path_rate_limit: the serial-drain gates
+        decline every dispatch."""
+        monkeypatch.delenv("KTPU_SERVING", raising=False)
+        monkeypatch.setattr(
+            AdmissionWindow, "observe_pop",
+            lambda self, n_pods, now=None: setattr(self, "rate_est", 1e9))
+        assert 1e9 > AdaptiveTuner.fast_path_rate_limit(0.0, n_nodes=6)
+
+    def test_declined_lone_pods_still_take_the_fast_path(
+            self, gates_decline):
+        pods = [make_pod(f"lone{t}", uid=f"lone{t}",
+                         requests={"cpu": "100m", "memory": "250Mi"})
+                for t in range(5)]
+        m = asyncio.run(self._lone_pods(pods))
+        assert m.serving_fast_path_pods.value() == len(pods)
+        assert m.backend_degradations.value(kind="host_path") == 0
+        assert m.solve_duration.count() == 0  # no batch solve either
+        assert m.device_loss_counts() == {
+            "backend_fallback_total": 0, "fast_path_failures_total": 0,
+            "host_path_pods": 0}
+
+    def test_lone_ineligible_pod_reaches_the_host_path_and_is_counted(
+            self, gates_decline):
+        pods = [make_pod("ports", uid="u-port", host_ports=[8080],
+                         requests={"cpu": "100m"})]
+        m = asyncio.run(self._lone_pods(pods))
+        assert m.serving_fast_path_pods.value() == 0
+        assert m.backend_degradations.value(kind="host_path") == 1
+        assert m.serving_fast_path_failures.value() == 0

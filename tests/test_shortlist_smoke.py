@@ -17,36 +17,23 @@ from kubernetes_tpu.ops.backend import AdaptiveTuner
 
 
 class TestTunerPolicy:
-    def test_chunk_depth_table(self):
-        # r6 envelope rows (unchanged)...
-        assert AdaptiveTuner.pick(0.020, 0.0) == (2048, 4)
-        assert AdaptiveTuner.pick(0.020, 0.5) == (1024, 4)
-        assert AdaptiveTuner.pick(0.0002, 0.0) == (1024, 2)
-        assert AdaptiveTuner.pick(0.0002, 0.9) == (1024, 2)
-        # ...plus the r10 large-N row: the 50k sweep measured chunk 1024
-        # as the local optimum (shortlist scan width is 2·chunk, so a
-        # wider chunk costs scan work faster than it amortizes the
-        # per-chunk O(N) prefilter); the row pins it regardless of the
-        # dirty signal, and remote rows are unaffected by N.
-        assert AdaptiveTuner.pick(0.0002, 0.0, n_nodes=50_000) == (1024, 2)
-        assert AdaptiveTuner.pick(0.0002, 0.9, n_nodes=50_000) == (1024, 2)
-        assert AdaptiveTuner.pick(0.020, 0.0, n_nodes=50_000) == (2048, 4)
-        assert AdaptiveTuner.pick(0.0002, 0.0, n_nodes=5_000) == (1024, 2)
-
-    def test_large_n_row_applies_before_warmup(self):
-        """The 50k preset must pick its chunk at the FIRST assign (the
-        recompile belongs in warmup, not the measured phase): node count
-        is structural, unlike the measured latency/dirty signals."""
+    def test_depth_table(self):
+        """What is left of the chunk/depth table now that no row keys
+        on transfer latency: depth 2 once the warm-up window has been
+        observed — or from the FIRST assign at large N, where node
+        count is structural and the recompile belongs in warmup, not
+        the measured phase."""
         t = AdaptiveTuner()
-        t.latency_s = 0.0002  # pre-probed: local
         t.n_nodes = 50_000
         assert t.total_chunks == 0
-        assert t.decide() == (1024, 2)
-        # Small-N still waits out the warmup window.
+        assert t.decide() == 2
+        # Small-N waits out the warmup window.
         t2 = AdaptiveTuner()
-        t2.latency_s = 0.0002
         t2.n_nodes = 5_000
         assert t2.decide() is None
+        for _ in range(t2.WARMUP_CHUNKS):
+            t2.observe_chunk()
+        assert t2.decide() == 2
 
     def test_shortlist_width_policy(self):
         t = AdaptiveTuner()

@@ -14,11 +14,18 @@ live in tests/test_wavefront_smoke.py.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import kernels, solver
 
 WIDTHS = (1, 2, 8)
+
+
+def _mesh_sizes():
+    """The mesh widths this machine can build (8 virtual CPU devices
+    under conftest; one or four real ones on the chip)."""
+    return [s for s in (1, 4, 8) if s <= len(jax.devices())]
 
 
 def _problem(rng, n, p, r, tight=False, strategy="LeastAllocated",
@@ -325,6 +332,8 @@ class TestShardedWaveParity:
     def test_mesh_bit_identity(self, shards):
         from kubernetes_tpu.parallel import build_mesh, \
             sharded_greedy_assign
+        if len(jax.devices()) < shards:
+            pytest.skip("not enough devices")
         rng = np.random.default_rng(700 + shards)
         n, p, r = 64, 18, 2
         args, _ = _problem(rng, n=n, p=p, r=r)
@@ -359,7 +368,7 @@ class TestShardedWaveParity:
                args["mask"], args["static_scores"], args["fit_col_w"],
                args["bal_col_mask"], args["shape_u"], args["shape_s"],
                args["w_fit"], args["w_bal"])
-        for shards in (1, 4, 8):
+        for shards in _mesh_sizes():
             got = np.asarray(sharded_greedy_assign(
                 build_mesh(shards), *pos, "LeastAllocated",
                 exc=jnp.asarray(exc), wave_w=4))
@@ -421,7 +430,7 @@ class TestBackendE2EParity:
         fwk = default_fwk()
         base, _ = TPUBackend(max_batch=16, mesh=None).assign(
             pods, snap, fwk)
-        for shards in (1, 4, 8):
+        for shards in _mesh_sizes():
             got, _ = TPUBackend(max_batch=16,
                                 mesh=build_mesh(shards)).assign(
                 pods, snap, fwk)
