@@ -869,12 +869,10 @@ class APIServer:
                 # stamped traceparent (no-op with tracing off).
                 stamp_traceparent(obj)
             if self.admission is not None:
-                with self.tracer.span("admission.webhooks",
-                                      resource=resource, op="create"):
-                    obj = await self.admission.admit(
-                        obj, resource, "create",
-                        user=request.get("user"),
-                        groups=self._request_groups(request))
+                obj = await self.admission.admit(
+                    obj, resource, "create",
+                    user=request.get("user"),
+                    groups=self._request_groups(request))
             if request.query.get("dryRun"):
                 # dryRun=All (kubectl diff's seam): the FULL admission
                 # chain ran above, and the store's mutators+validators
@@ -886,8 +884,7 @@ class APIServer:
                 if admit is not None:
                     admit(resource, obj, "create")
                 return _object_response(request, obj, status=201)
-            with self.tracer.span("store.create", resource=resource):
-                created = await self.store.create(resource, obj)
+            created = await self.store.create(resource, obj)
             return _object_response(request, created, status=201)
         raise web.HTTPMethodNotAllowed(request.method, ["GET", "POST"])
 
@@ -1032,9 +1029,7 @@ class APIServer:
         if request.method != "POST":
             raise web.HTTPMethodNotAllowed(request.method, ["POST"])
         body = await request.json()
-        with self.tracer.span(f"store.subresource.{sub}",
-                              resource=resource):
-            result = await self.store.subresource(resource, key, sub, body)
+        result = await self.store.subresource(resource, key, sub, body)
         return web.json_response(result, status=201)
 
     async def _watch(self, request: web.Request) -> web.StreamResponse:

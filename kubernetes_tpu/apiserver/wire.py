@@ -74,7 +74,11 @@ from kubernetes_tpu.store.mvcc import (
     NotFound,
     StoreError,
 )
-from kubernetes_tpu.utils.tracing import stamp_traceparent
+from kubernetes_tpu.utils.tracing import (
+    DEFAULT_TRACER,
+    ambient,
+    stamp_traceparent,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -222,6 +226,19 @@ class _Conn(asyncio.Protocol):
         self.server._conns.discard(self)
 
     def data_received(self, data: bytes) -> None:
+        tracer = self.server.tracer
+        if tracer is not None and tracer.enabled:
+            with tracer.span("wire.decode"):
+                frames = self._decode(data)
+        else:
+            frames = self._decode(data)
+        # spawned outside the span: a handler task must not inherit a
+        # decode span that closed before it ran
+        for frame in frames:
+            asyncio.ensure_future(self._handle(frame))
+
+    def _decode(self, data: bytes) -> list:
+        """The whole frames `data` completes, decoded, in order."""
         # Offset-scan then ONE tail compaction: a coalesced read can hold
         # hundreds of frames, and `del buf[:4+n]` per frame is an O(bytes)
         # memmove each time — quadratic over the burst.
@@ -229,12 +246,13 @@ class _Conn(asyncio.Protocol):
         buf.extend(data)
         end = len(buf)
         ofs = 0
+        frames = []
         while end - ofs >= 4:
             n = _LEN.unpack_from(buf, ofs)[0]
             if n > _MAX_FRAME:
                 logger.error("wire: oversized frame (%d bytes); closing", n)
                 self.transport.close()
-                return
+                return frames
             if end - ofs - 4 < n:
                 break
             payload = bytes(buf[ofs + 4:ofs + 4 + n])
@@ -244,10 +262,11 @@ class _Conn(asyncio.Protocol):
             except Exception:
                 logger.error("wire: undecodable frame; closing")
                 self.transport.close()
-                return
-            asyncio.ensure_future(self._handle(frame))
+                return frames
+            frames.append(frame)
         if ofs:
             del buf[:ofs]
+        return frames
 
     # -- batched writes ----------------------------------------------------
 
@@ -267,10 +286,20 @@ class _Conn(asyncio.Protocol):
             # (KTPU_LOCK_CHECK=1) raises here if the flushing thread
             # still holds an instrumented lock.
             check_dispatch_seam("wire.flush")
-            self.transport.write(b"".join(self._out))
+            tracer = self.server.tracer
+            # its own span: a call_soon callback runs in the context of
+            # whichever handler sent first, which has long moved on
+            with tracer.span("wire.flush") if tracer is not None \
+                    and tracer.enabled else _NULL_CM:
+                self.transport.write(b"".join(self._out))
             self._out.clear()
 
     def _ok(self, rid: str, result) -> None:
+        tracer = self.server.tracer
+        if tracer is not None and tracer.enabled:
+            with tracer.span("wire.encode"):
+                body = _encode_reply([rid, "ok", result], self._mp)
+            return self.send(body)
         self.send(_encode_reply([rid, "ok", result], self._mp))
 
     def _err(self, rid: str, reason: str, message: str) -> None:
@@ -723,7 +752,9 @@ class _Conn(asyncio.Protocol):
         except Expired as e:
             self.send(_encode_reply([wid, "exp", str(e)], self._mp))
             return
-        task = asyncio.ensure_future(self._watch_pump(wid, watch))
+        # per-event encode + send is the pump's whole life
+        with ambient(f"wire.watch.{resource}"):
+            task = asyncio.ensure_future(self._watch_pump(wid, watch))
         self.watches[wid] = task
         task.add_done_callback(lambda _t: self.watches.pop(wid, None))
 
@@ -857,18 +888,22 @@ class WireServer:
 
     async def start(self) -> None:
         loop = asyncio.get_event_loop()
-        if self.host.startswith("unix:"):
-            # Unix-domain listener: same frames, ~30% less per-byte
-            # syscall cost than TCP loopback — the co-located-component
-            # fast path (the reference's apiserver on the same host).
-            self._path = self.host[len("unix:"):] or \
-                f"/tmp/ktpu-wire-{id(self):x}.sock"
-            self._server = await loop.create_unix_server(
-                lambda: _Conn(self), self._path)
-            logger.info("wire server listening on unix:%s", self._path)
-            return
-        self._server = await loop.create_server(
-            lambda: _Conn(self), self.host, self.port)
+        # accept, read and write callbacks keep the context the listener
+        # was made in, and handler tasks inherit it: the transport's own
+        # time is `wire.io` wherever no frame span is open
+        with ambient("wire.io"):
+            if self.host.startswith("unix:"):
+                # Unix-domain listener: same frames, ~30% less per-byte
+                # syscall cost than TCP loopback — the co-located-component
+                # fast path (the reference's apiserver on the same host).
+                self._path = self.host[len("unix:"):] or \
+                    f"/tmp/ktpu-wire-{id(self):x}.sock"
+                self._server = await loop.create_unix_server(
+                    lambda: _Conn(self), self._path)
+                logger.info("wire server listening on unix:%s", self._path)
+                return
+            self._server = await loop.create_server(
+                lambda: _Conn(self), self.host, self.port)
         self.port = self._server.sockets[0].getsockname()[1]
         logger.info("wire server listening on %s:%d", self.host, self.port)
 
@@ -915,7 +950,15 @@ class _ClientProto(asyncio.Protocol):
         self.owner._conn_lost(exc)
 
     def data_received(self, data: bytes) -> None:
-        # Offset-scan + single compaction (see _Conn.data_received): the
+        tracer = self.owner.tracer
+        if tracer.enabled:
+            # frame decode, future resolution and watch-queue puts
+            with tracer.span("wire.client.recv"):
+                return self._received(data)
+        self._received(data)
+
+    def _received(self, data: bytes) -> None:
+        # Offset-scan + single compaction (see _Conn._decode): the
         # server's watch-push bursts coalesce into large reads. The
         # compaction runs in `finally` so a decode/handler error cannot
         # leave already-delivered frames at the buffer head (they would
@@ -989,6 +1032,9 @@ class WireStore:
         #: "json"; the server mirrors whichever the client speaks.
         self._encode = (_packb if enc == "msgpack" else
                         lambda f: _dumps(f, separators=(",", ":")).encode())
+        #: the process tracer: traceparent stamping on outgoing ops and
+        #: the client-side spans (send, flush, recv).
+        self.tracer = DEFAULT_TRACER
         self._proto: _ClientProto | None = None
         self._next_id = 0
         self._pending: dict[str, asyncio.Future] = {}
@@ -1021,12 +1067,14 @@ class WireStore:
         loop = asyncio.get_event_loop()
         self._connecting = loop.create_future()
         try:
-            if self.path is not None:
-                _t, proto = await loop.create_unix_connection(
-                    lambda: _ClientProto(self), self.path)
-            else:
-                _t, proto = await loop.create_connection(
-                    lambda: _ClientProto(self), self.host, self.port)
+            # the read callback keeps the context it is registered in
+            with ambient("wire.client.io"):
+                if self.path is not None:
+                    _t, proto = await loop.create_unix_connection(
+                        lambda: _ClientProto(self), self.path)
+                else:
+                    _t, proto = await loop.create_connection(
+                        lambda: _ClientProto(self), self.host, self.port)
             self._proto = proto
             hello_args = {"token": self.token, "ua": self.user_agent}
             if self.impersonate:
@@ -1098,6 +1146,14 @@ class WireStore:
             asyncio.get_event_loop().call_soon(self._flush)
 
     def _flush(self) -> None:
+        if self.tracer.enabled:
+            # frame encode + socket write; its own span because a
+            # call_soon callback runs in its first caller's context
+            with self.tracer.span("wire.client.flush"):
+                return self._flush_tick()
+        self._flush_tick()
+
+    def _flush_tick(self) -> None:
         self._flush_scheduled = False
         ops, self._tick_ops = self._tick_ops, []
         if len(ops) == 1:
@@ -1167,16 +1223,12 @@ class WireStore:
             exc = _EXC_OF.get(frame[2], StoreError)
             fut.set_exception(exc(frame[3]))
 
-    @staticmethod
-    def _trace_wrap(op_frame: list) -> list:
+    def _trace_wrap(self, op_frame: list) -> list:
         """W3C traceparent propagation, frame-field form: an op issued
         inside a span ships ["traced", tp, op, ...args] so the server's
         frame span parents to the caller's (the wire analog of
         RemoteStore's traceparent header)."""
-        from kubernetes_tpu.utils.tracing import DEFAULT_TRACER
-        if not DEFAULT_TRACER.enabled:
-            return op_frame
-        tp = DEFAULT_TRACER.current_traceparent()
+        tp = self.tracer.current_traceparent()
         return ["traced", tp, *op_frame] if tp else op_frame
 
     async def _call(self, op: str, *args, _pre_auth: bool = False):
@@ -1188,8 +1240,16 @@ class WireStore:
         self._pending[rid] = fut
         if _pre_auth:
             self._send([rid, op, *args])  # hello must not ride a multi
+        elif self.tracer.enabled:
+            # the traceparent is the CALLER's span, read before this
+            # client-side span (wall = the round trip as the caller
+            # sees it) opens
+            frame = self._trace_wrap([op, *args])
+            with self.tracer.span("wire.client.call", op=op):
+                self._send_op(rid, frame)
+                return await fut
         else:
-            self._send_op(rid, self._trace_wrap([op, *args]))
+            self._send_op(rid, [op, *args])
         return await fut
 
     # -- MVCCStore surface -------------------------------------------------

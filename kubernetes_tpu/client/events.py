@@ -101,6 +101,10 @@ class EventRecorder:
     def __init__(self, store: MVCCStore, component: str):
         self.store = store
         self.component = component
+        #: utils/tracing.Tracer injected by the owner (the Scheduler):
+        #: `events.record` per enqueue, `events.flush` per drain task;
+        #: None or disabled costs one check.
+        self.tracer = None
         #: per-(source, reason) token bucket: a repeating reason that
         #: outruns its refill budget sheds EARLY, before it can occupy
         #: buffer slots the priority reasons need.
@@ -133,6 +137,14 @@ class EventRecorder:
 
     def event(self, obj: Mapping, event_type: str, reason: str, message: str) -> None:
         """Fire-and-forget, like the reference's buffered broadcaster."""
+        t = self.tracer
+        if t is not None and t.enabled:
+            with t.section("events.record"):
+                return self._event(obj, event_type, reason, message)
+        self._event(obj, event_type, reason, message)
+
+    def _event(self, obj: Mapping, event_type: str, reason: str,
+               message: str) -> None:
         self.emitted += 1
         agg_key = (obj.get("kind", ""), namespace_of(obj), name_of(obj),
                    event_type, reason)
@@ -228,6 +240,13 @@ class EventRecorder:
         self._draining = True
 
     async def _drain(self) -> None:
+        t = self.tracer
+        if t is not None and t.enabled:
+            with t.span("events.flush"):
+                return await self._drain_pending()
+        await self._drain_pending()
+
+    async def _drain_pending(self) -> None:
         try:
             while self._pending:
                 batch, self._pending = self._pending, []

@@ -23,6 +23,7 @@ from typing import Any, Callable, Iterable, Mapping
 from kubernetes_tpu.api.labels import Selector
 from kubernetes_tpu.api.meta import namespaced_name, resource_version_of
 from kubernetes_tpu.store.mvcc import Expired, MVCCStore
+from kubernetes_tpu.utils.tracing import ambient
 
 logger = logging.getLogger(__name__)
 
@@ -167,7 +168,12 @@ class SharedInformer:
 
     def start(self) -> None:
         if self._task is None or self._task.done():
-            self._task = asyncio.ensure_future(self._run())
+            # event decode, cache update and handler dispatch are the
+            # reflector's whole life: the task (its shard loops and
+            # async handlers too) is `informer.<resource>` to the
+            # tracer's ledger
+            with ambient(f"informer.{self.resource}"):
+                self._task = asyncio.ensure_future(self._run())
 
     def stop(self) -> None:
         if self._task:

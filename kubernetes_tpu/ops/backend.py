@@ -88,6 +88,8 @@ logger = logging.getLogger(__name__)
 # timeline as the host-side work when a --profile-dir trace is taken.
 # TraceMe-backed — near-free when no trace is active.
 _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+#: what _span returns with tracing off: stateless, safe to re-enter.
+_NULL_CM = contextlib.nullcontext()
 _STEP_ANNOTATION = jax.profiler.StepTraceAnnotation
 
 #: Plugins with full device kernels.
@@ -1808,6 +1810,12 @@ class TPUBackend:
             self._finalize_chunk(run, got, ctx)
             yield run["pods"], ctx
 
+    def _span(self, name: str, **attrs):
+        """A span under the scheduler's attempt (the tracer's shared no-op
+        when tracing is off; per-chunk sites, where a call costs nothing)."""
+        tr = self.tracer
+        return tr.span(name, **attrs) if tr is not None else _NULL_CM
+
     def _fetch_assign(self, run: dict) -> np.ndarray:
         """Blocking device→host fetch of a chunk's assignments, timed.
 
@@ -1818,10 +1826,8 @@ class TPUBackend:
         the solver scan width / shortlist fallback counters extracted
         from the same fetch in _finalize_chunk."""
         check_dispatch_seam("backend.fetch_assign")
-        tr = self.tracer
-        span = tr.span("solver.solve", chunk=run.get("chunk_idx"),
-                       pods=run["batch"].p_real) \
-            if tr is not None and tr.enabled else contextlib.nullcontext()
+        span = self._span("solver.solve", chunk=run.get("chunk_idx"),
+                          pods=run["batch"].p_real)
         t0 = time.perf_counter()
         with span, _TRACE_ANNOTATION("ktpu.solve.fetch"):
             got = np.asarray(run["assign_d"])
@@ -1838,8 +1844,11 @@ class TPUBackend:
 
         pending: deque = deque()
         for chunk in ctx.chunks:
-            pending.append(
-                self._dispatch_chunk(self._prep_chunk(chunk, ctx), ctx))
+            # beside scheduler_tpu_prep_seconds: the histogram times the
+            # call, the span's self-time is what the loop thread ran
+            with self._span("solver.prep", pods=len(chunk)):
+                prep = self._prep_chunk(chunk, ctx)
+            pending.append(self._dispatch_chunk(prep, ctx))
             if len(pending) > self.pipeline_depth:
                 yield pending.popleft()
         while pending:
@@ -1854,7 +1863,8 @@ class TPUBackend:
         depth = self._tuner.decide()
         if depth is not None and _pipeline_depth_override() is None:
             self.pipeline_depth = depth
-        ct = self._tensors(snapshot)
+        with self._span("solver.tensors", nodes=len(snapshot.nodes)):
+            ct = self._tensors(snapshot)
         pods = list(pods)
         # namespaceSelector terms resolve through the framework's
         # InterPodAffinity plugin (its namespaces informer); spread
@@ -2784,12 +2794,9 @@ class TPUBackend:
         through self._dev_used without host sync. Bracketed with a
         StepTraceAnnotation (one profiler step per chunk) and, when
         tracing is on, a solver.dispatch span under the attempt."""
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            with tr.span("solver.dispatch", chunk=prep.get("chunk_idx"),
-                         pods=prep["batch"].p_real):
-                return self._dispatch_chunk_inner(prep, ctx)
-        return self._dispatch_chunk_inner(prep, ctx)
+        with self._span("solver.dispatch", chunk=prep.get("chunk_idx"),
+                        pods=prep["batch"].p_real):
+            return self._dispatch_chunk_inner(prep, ctx)
 
     def _dispatch_chunk_inner(self, prep: dict, ctx: "_AssignCtx") -> dict:
         with _STEP_ANNOTATION("ktpu.solve",
@@ -2993,7 +3000,8 @@ class TPUBackend:
         stateful = run["stateful_pods"]
         # (Templates are fixed at table-build time from ALL chunks, so a
         # later chunk can no longer invalidate scan-trusted placements.)
-        rejects = self._verify(pods, assign, ctx, stateful)
+        with self._span("solver.verify", pods=len(pods)):
+            rejects = self._verify(pods, assign, ctx, stateful)
 
         # Fold verify rejections back into the device-chained used-state so
         # later chunks don't see the rejected pods' resources as consumed.

@@ -106,6 +106,9 @@ class SchedulingQueue:
         # Every activeQ entry (first add, backoff flush, move_all) stamps
         # the queue-wait start for this attempt's retroactive span.
         pi.enqueued_at = self.clock()
+        tracer = self.framework.tracer
+        if tracer is not None and tracer.enabled:
+            tracer.cut()    # a retroactive scheduler.queue.wait starts here
         heapq.heappush(self._active, (self._sort_key(pi), next(self._seq), pi))
         self._active_keys.add(pi.key)
 

@@ -46,7 +46,7 @@ from kubernetes_tpu.scheduler.plugins.registry import (
 from kubernetes_tpu.scheduler.queue import ClusterEvent, SchedulingQueue
 from kubernetes_tpu.scheduler.types import NodeInfo, PodInfo, Snapshot
 from kubernetes_tpu.utils.trace import Trace
-from kubernetes_tpu.utils.tracing import traceparent_of
+from kubernetes_tpu.utils.tracing import ambient, traceparent_of
 
 logger = logging.getLogger(__name__)
 
@@ -99,6 +99,9 @@ class Scheduler:
         #: apiserver so one tracer assembles the whole pod journey.
         from kubernetes_tpu.utils.tracing import DEFAULT_TRACER
         self.tracer = tracer if tracer is not None else DEFAULT_TRACER
+        # the self-time ledger's counter families ride this registry to
+        # /metrics (and to whoever reads Registry.render())
+        self.tracer.register_into(self.metrics.registry)
         if profiles is None:
             plugins = build_plugins(store=store)
             fwk = Framework(plugins, DEFAULT_SCORE_WEIGHTS, metrics=self.metrics)
@@ -148,6 +151,7 @@ class Scheduler:
         #: loop structurally identical to the pre-serving shape.
         self.serving = None
         self.recorder = EventRecorder(store, "default-scheduler")
+        self.recorder.tracer = self.tracer
         self._informer_factory: InformerFactory | None = None
         self._binding_tasks: set[asyncio.Task] = set()
         self._permit_waiters: dict[str, asyncio.Future] = {}
@@ -459,6 +463,14 @@ class Scheduler:
         await self._schedule_pods(pods)
         return True
 
+    def _snapshot(self):
+        """cache.update_snapshot() under its own span: on a large
+        cluster the incremental walk is a visible slice of the attempt."""
+        if self.tracer.enabled:
+            with self.tracer.span("scheduler.snapshot"):
+                return self.cache.update_snapshot()
+        return self.cache.update_snapshot()
+
     async def _schedule_pods(self, pods: list[PodInfo]) -> None:
         with Trace("Scheduling", threshold_ms=self.trace_threshold_ms,
                    pods=len(pods)) as tr:
@@ -466,7 +478,7 @@ class Scheduler:
 
     async def _schedule_pods_traced(self, pods: list[PodInfo],
                                     tr) -> None:
-        snapshot = self.cache.update_snapshot()
+        snapshot = self._snapshot()
         tr.step("snapshot")
         # Extenders are per-pod HTTP webhooks whose round-trips dominate any
         # batch win, and their filter verdicts must precede assignment — so
@@ -494,7 +506,7 @@ class Scheduler:
                 placed = 0
                 for pi in nominated:
                     if await self._try_nominated(pi, snapshot):
-                        snapshot = self.cache.update_snapshot()
+                        snapshot = self._snapshot()
                         self._nominee_fails.pop(pi.key, None)
                         placed += 1
                         continue
@@ -535,17 +547,17 @@ class Scheduler:
                         sname in self.backend_profiles:
                     await self._schedule_via_backend(group, snapshot)
                     tr.step(f"backend assign [{sname}] ({len(group)} pods)")
-                    snapshot = self.cache.update_snapshot()
+                    snapshot = self._snapshot()
                 else:
                     for pi in group:
                         await self._schedule_host_path(pi, snapshot)
-                        snapshot = self.cache.update_snapshot()
+                        snapshot = self._snapshot()
                     tr.step(f"host path [{sname}] ({len(group)} pods)")
             return
         for pi in pods:
             await self._schedule_host_path(pi, snapshot)
             # Re-snapshot so pods later in the batch see earlier assumes.
-            snapshot = self.cache.update_snapshot()
+            snapshot = self._snapshot()
         tr.step(f"host path ({len(pods)} pods)")
 
     async def _try_nominated(self, pi: PodInfo, snapshot) -> bool:
@@ -833,7 +845,11 @@ class Scheduler:
                                pi: PodInfo, node_name: str) -> None:
         """assume → Reserve → Permit → async bindingCycle."""
         try:
-            self.cache.assume_pod(pi, node_name)
+            if self.tracer.enabled:
+                with self.tracer.section("scheduler.assume"):
+                    self.cache.assume_pod(pi, node_name)
+            else:
+                self.cache.assume_pod(pi, node_name)
         except (KeyError, ValueError) as e:
             logger.error("assume failed for %s: %s", pi.key, e)
             await self.queue.move_to_backoff(pi)
@@ -1025,22 +1041,25 @@ class Scheduler:
         serving tier (admission window + single-pod fast path —
         kubernetes_tpu/serving); KTPU_SERVING=0 degrades structurally
         to the plain schedule_batch loop below."""
-        flusher = asyncio.ensure_future(self.queue.run_flushers())
-        janitor = asyncio.ensure_future(self._cache_janitor())
         from kubernetes_tpu.serving import maybe_attach_serving
-        serving = maybe_attach_serving(self)
-        try:
-            while not self._stop:
-                if serving is not None:
-                    more = await serving.schedule_next(batch_size)
-                else:
-                    more = await self.schedule_batch(batch_size)
-                if not more:
-                    break
-                self.metrics.set_pending(self.queue.stats())
-        finally:
-            flusher.cancel()
-            janitor.cancel()
+        # pop, admission window and routing between attempts are the
+        # loop's loose time (the flusher and janitor tasks inherit it)
+        with ambient("scheduler.loop"):
+            flusher = asyncio.ensure_future(self.queue.run_flushers())
+            janitor = asyncio.ensure_future(self._cache_janitor())
+            serving = maybe_attach_serving(self)
+            try:
+                while not self._stop:
+                    if serving is not None:
+                        more = await serving.schedule_next(batch_size)
+                    else:
+                        more = await self.schedule_batch(batch_size)
+                    if not more:
+                        break
+                    self.metrics.set_pending(self.queue.stats())
+            finally:
+                flusher.cancel()
+                janitor.cancel()
 
     async def run_with_leader_election(self, elector,
                                        batch_size: int = 1) -> None:

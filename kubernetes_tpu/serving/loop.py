@@ -230,8 +230,13 @@ class ServingTier:
     async def _fast_cycle(self, pi, snapshot, fwk) -> bool:
         sched = self.sched
         t0 = time.perf_counter()
+        tr = sched.tracer
         try:
-            node = self.fastpath.try_schedule(pi, snapshot, fwk)
+            if tr.enabled:
+                with tr.span("solver.fast"):
+                    node = self.fastpath.try_schedule(pi, snapshot, fwk)
+            else:
+                node = self.fastpath.try_schedule(pi, snapshot, fwk)
         except Exception:
             # The fast path must never break scheduling: any device/host
             # error just reroutes the pod through the normal path (and

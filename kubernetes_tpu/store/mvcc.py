@@ -35,6 +35,7 @@ from typing import Any, AsyncIterator, Awaitable, Callable, Mapping
 from kubernetes_tpu.api.labels import Selector
 from kubernetes_tpu.utils import flags
 from kubernetes_tpu.metrics.registry import WatchMetrics
+from kubernetes_tpu.utils.tracing import DEFAULT_TRACER
 from kubernetes_tpu.api.meta import (
     deep_copy,
     name_of,
@@ -266,6 +267,13 @@ class MVCCStore:
         #: dispatch efficiency counters (metrics/registry.py); the bench
         #: harness reports the deltas per measured phase.
         self.watch_metrics = WatchMetrics()
+        #: the process tracer: one section (ledger only, no record) per
+        #: write (`store.create.<res>`, `.update.`, `.delete.`), per
+        #: commit (`store.commit.<res>`: ring, sinks; `store.cacher.<res>`
+        #: the watch cache) and per watch fan-out (`store.fanout.<res>`),
+        #: one span per subresource call — both wires and in-process
+        #: callers pass through here. Disabled: one attribute check each.
+        self.tracer = DEFAULT_TRACER
         self._bookmark_task: asyncio.Task | None = None
         # Subresource hooks, e.g. ("pods", "binding") -> handler.
         self._subresources: dict[tuple[str, str], Callable[..., Awaitable[dict]]] = {}
@@ -324,6 +332,17 @@ class MVCCStore:
         return self._rv_counter.value
 
     def _record(self, resource: str, ev: Event) -> None:
+        t = self.tracer
+        if t.enabled:
+            with t.section(f"store.commit.{resource}"):
+                self._commit(resource, ev)
+            with t.section(f"store.fanout.{resource}"):
+                self._dispatch(resource, ev)
+            return
+        self._commit(resource, ev)
+        self._dispatch(resource, ev)
+
+    def _commit(self, resource: str, ev: Event) -> None:
         self._events.append((resource, ev))
         if len(self._events) > self._event_window:
             drop = len(self._events) - self._event_window
@@ -344,8 +363,12 @@ class MVCCStore:
         # watch dispatch, so a handler that reads during dispatch sees a
         # cache consistent with the event it was handed.
         if self.cacher is not None:
-            self.cacher.ingest(resource, ev)
-        self._dispatch(resource, ev)
+            t = self.tracer
+            if t.enabled:
+                with t.section(f"store.cacher.{resource}"):
+                    self.cacher.ingest(resource, ev)
+            else:
+                self.cacher.ingest(resource, ev)
 
     def add_event_sink(self, sink) -> None:
         """Register a synchronous (resource, Event) observer for every
@@ -606,6 +629,14 @@ class MVCCStore:
         (event recording, binding): deep-copying every wire object 4× per
         write is the store's top CPU cost at scheduler_perf scale.
         """
+        t = self.tracer
+        if t.enabled:
+            with t.section(f"store.create.{resource}"):
+                return self._create(resource, obj, _owned, return_copy)
+        return self._create(resource, obj, _owned, return_copy)
+
+    def _create(self, resource: str, obj: Mapping, _owned: bool,
+                return_copy: bool) -> dict | None:
         obj = dict(obj) if _owned else deep_copy(dict(obj))
         key = self._key(obj)
         if not name_of(obj):
@@ -646,6 +677,14 @@ class MVCCStore:
 
         `_owned`/`return_copy`: see create().
         """
+        t = self.tracer
+        if t.enabled:
+            with t.section(f"store.update.{resource}"):
+                return self._update(resource, obj, _owned, return_copy)
+        return self._update(resource, obj, _owned, return_copy)
+
+    def _update(self, resource: str, obj: Mapping, _owned: bool,
+                return_copy: bool) -> dict | None:
         obj = dict(obj) if _owned else deep_copy(dict(obj))
         key = self._key(obj)
         table = self._table(resource)
@@ -710,6 +749,13 @@ class MVCCStore:
         raise Conflict(f"{resource} {key!r}: too many conflicts in guaranteed_update")
 
     async def delete(self, resource: str, key: str, *, uid: str | None = None) -> dict:
+        t = self.tracer
+        if t.enabled:
+            with t.section(f"store.delete.{resource}"):
+                return self._delete(resource, key, uid)
+        return self._delete(resource, key, uid)
+
+    def _delete(self, resource: str, key: str, uid: str | None) -> dict:
         table = self._table(resource)
         if key not in table:
             raise NotFound(f"{resource} {key!r} not found")
@@ -945,6 +991,10 @@ class MVCCStore:
         handler = self._subresources.get((resource, sub))
         if handler is None:
             raise NotFound(f"subresource {resource}/{sub} not registered")
+        t = self.tracer
+        if t.enabled:
+            with t.span(f"store.subresource.{sub}", resource=resource):
+                return await handler(self, key, body)
         return await handler(self, key, body)
 
     # -- persistence (WAL-lite) -------------------------------------------

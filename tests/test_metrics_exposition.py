@@ -150,6 +150,13 @@ class TestExpositionLint:
         engine = PolicyEngine(store)
         engine.register_into(r)
         store.stop()
+        # the tracer's self-time ledger (the Scheduler registers it)
+        from kubernetes_tpu.utils.tracing import Tracer
+        tracer = Tracer(enabled=True)
+        with tracer.span("wire.create.pods"):
+            pass
+        tracer.enabled = False
+        tracer.register_into(r)
         return r
 
     def test_full_default_registry_renders_clean(self):
@@ -161,7 +168,13 @@ class TestExpositionLint:
                      "apiserver_request_duration_seconds",
                      "apiserver_current_inflight_requests",
                      "audit_events_total",
-                     "policy_evaluations_total"):
+                     "policy_evaluations_total",
+                     "ktpu_host_self_seconds_total",
+                     "ktpu_span_wall_seconds_total",
+                     "ktpu_span_total",
+                     "ktpu_loop_wall_seconds_total",
+                     "ktpu_loop_busy_seconds_total",
+                     "ktpu_trace_spans_dropped_total"):
             assert want in names, (want, names)
 
     def test_register_into_is_idempotent(self):
