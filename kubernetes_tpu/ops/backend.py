@@ -168,18 +168,19 @@ class AdaptiveTuner:
     | before WARMUP_CHUNKS chunks, N < 32768       | 1024  | 4     |
     | after them, or N ≥ 32768 from the 1st assign | 1024  | 2     |
 
-    With no round trip to amortize, 1024 measured best and stable on
-    both clean and dirty families (r6 sweep) and depth beyond 2 just
-    delays verify feedback. At large N the shortlist scan width is
-    K+P = 2·chunk, so widening the chunk costs scan work faster than it
-    amortizes the per-chunk fixed costs; the r14 class-dictionary planes
-    cut those fixed costs from O(P·N) to O(C·N) without moving the
-    optimum (1024 still beat 2048 and 512 at N=50k). Node count is
-    STRUCTURAL (known at the first assign), so that row applies without
-    waiting out the warmup window. The pre-warm-up depth of 4 is the
-    constructor's default, and is what a drain's first super-batch
-    runs; it and every constant below were chosen on a CPU container
-    and wait for ROADMAP A4 to be re-derived on the chip.
+    The chunk of 1024 and the depths were chosen on a CPU container and
+    have not been swept on the chip (ROADMAP A4): against 2048 or 512,
+    not measured. What the chip has shown (PERF.md, PR 26, TPU v5 lite,
+    `kwok-50k.drain`): a 1,024-pod chunk at 50,000 nodes — shortlist
+    K = 1024, W = 64, four orders — is 8.8 ms of device now that the
+    wave scan looks each candidate up once (725 ms while every wave
+    member gathered its own (K+P)-wide candidate row), so the chunk's
+    fixed host cost (prep ~14 ms, `solver.tensors` ~27 ms a batch), not
+    its scan, is what a wider chunk would amortize. Node count is
+    STRUCTURAL (known at the first assign), so the large-N row applies
+    without waiting out the warmup window. The pre-warm-up depth of 4
+    is the constructor's default, and is what a drain's first
+    super-batch runs.
 
     **Shortlist width** (the r10 pruned solve): K = chunk × boost, active
     only while the node count dwarfs the scan width (N ≥ 4·(K + chunk) —
@@ -217,16 +218,13 @@ class AdaptiveTuner:
     #: (N ≥ LARGE_N with shortlist active).
     BLOCK_WIDTH = 128
     #: Wavefront policy rows (the r18 speculative solve): W pods per
-    #: scan step, swept at the 5k/50k/200k presets (BASELINE r18). The
-    #: win GROWS with node count — the scan-length cut frees the XLA
-    #: compute threads that contend with the host path, in proportion
-    #: to how big each step's arrays are: 200k median 1508 at W=64 vs
-    #: ~1036 serial (+46%), 50k 1517–1677 across W∈{16,32,64} vs 1411,
-    #: while 5k (full-scan multistart, host-bound) is flat within the
-    #: run spread — W=32 keeps it active without cost, mirroring the
-    #: shortlist's 5k finding. Replay fraction was 0% throughout (all
-    #: template workloads). Node count is STRUCTURAL, so like the
-    #: large-N chunk row the tier applies from the first assign.
+    #: scan step. The two widths were picked from a sweep on a CPU
+    #: container; on the chip no other width has been tried: not
+    #: measured (ROADMAP A4). At W=64 the 50k cell commits every wave
+    #: speculatively (replays 0, shortlist fallbacks 0: ledger, PR 24
+    #: on) and its 16 wave steps a chunk take 7.5 ms of device together
+    #: (PERF.md, PR 26). Node count is STRUCTURAL, so like the large-N
+    #: chunk row the tier applies from the first assign.
     #: Conflict rate is WORKLOAD-dependent (packing strategies re-pick
     #: debited nodes; contested spread domains force replays), so the
     #: width halves at decide() boundaries whenever the measured replay
@@ -907,8 +905,6 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                 strategy)
             cand_s, thresh_s = solver.shortlist_prefilter(
                 feasible, sc0, shortlist_k)
-        sl_cand = cand_s[cls_idx]                               # (P, K)
-        sl_thresh = thresh_s[cls_idx]                           # (P,)
         # has_node: class-level any(), narrowed to the pinned column for
         # exception pods (their only possibly-feasible node).
         has_c = jnp.any(mask, axis=1)                           # (C,)
@@ -926,8 +922,8 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                     shape_s, w_fit, w_bal, strategy,
                     dom_onehot, cid_onehot, dom_counts, max_skew,
                     sp_min_ok, sp_haskey, sp_applies, sp_contrib,
-                    sc0, cls_idx, sl_cand, sl_thresh, has_node,
-                    rows=cls_idx, exc=exc_col)
+                    sc0, cls_idx, cand_s[cls_idx], thresh_s[cls_idx],
+                    has_node, rows=cls_idx, exc=exc_col)
         elif wave_w > 1:
             a0, dom_counts2, wave_com, wave_rep = \
                 solver.greedy_assign_rescoring_spread_wave(
@@ -957,20 +953,28 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                       dom_onehot[safe] * contrib_d, 0.0), axis=0)
     else:
         if shortlist_k and wave_w > 1:
+            # The wave scan takes the shortlist as CLASS tables and never
+            # expands them per pod. A slot's chunk-start masked value is
+            # what the prefilter ranked: sc0 where the class is feasible
+            # on the node, else -inf — one (C,K) lookup a chunk, so that
+            # no untouched slot is looked up inside the scan at all.
+            sl_val = jnp.where(
+                jnp.take_along_axis(feasible, cand_s, axis=1),
+                jnp.take_along_axis(sc0, cand_s, axis=1), solver.NEG_INF)
             assign, nfall, wave_com, wave_rep = \
                 solver.multistart_greedy_assign_shortlist_wave(
                     req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
                     mask, static_scores, fit_col_w, bal_col_mask, shape_u,
                     shape_s, w_fit, w_bal, strategy, wave_w, perms,
-                    gang_onehot, gang_required, sc0, cls_idx, sl_cand,
-                    sl_thresh, has_node, rows=cls_idx, exc=exc_col)
+                    gang_onehot, gang_required, cls_idx, cand_s, sl_val,
+                    thresh_s, has_node, rows=cls_idx, exc=exc_col)
         elif shortlist_k:
             assign, nfall = solver.multistart_greedy_assign_shortlist(
                 req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
                 mask, static_scores, fit_col_w, bal_col_mask, shape_u,
                 shape_s, w_fit, w_bal, strategy, perms, gang_onehot,
-                gang_required, sc0, cls_idx, sl_cand, sl_thresh, has_node,
-                rows=cls_idx, exc=exc_col)
+                gang_required, sc0, cls_idx, cand_s[cls_idx],
+                thresh_s[cls_idx], has_node, rows=cls_idx, exc=exc_col)
         elif wave_w > 1:
             if pallas != "off":
                 assign, wave_com, wave_rep = \

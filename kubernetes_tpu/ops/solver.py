@@ -393,6 +393,16 @@ def shortlist_prefilter(feas0, sc0, k: int):
     threshold has a HIGHER index than every in-list node at that value, so
     an untouched in-list winner at exactly the threshold still wins the
     full scan's lowest-index tie-break.
+
+    What is chunk-constant: all three of cand, thresh and each slot's
+    ranked value where(feas0, sc0, -inf)[s, cand[s, k]]. A node's score
+    and capacity move only when the node is debited, so until a scan
+    debits it a slot is worth exactly what was ranked here — the same
+    float, from the same evaluation that produced thresh. The W=1 scans
+    read it back per step (sc0[cls, ci]); the wave scan takes it as a
+    table made once a chunk (`sl_val`, _shortlist_wave_scan) and looks
+    nothing up for an untouched slot. A debited node joins the scans'
+    touched list and is evaluated live there from then on.
     """
     vals, cand = lax.top_k(jnp.where(feas0, sc0, NEG_INF), k + 1)
     return cand[:, :k].astype(jnp.int32), vals[:, k]
@@ -1428,11 +1438,27 @@ def greedy_assign_rescoring_spread_wave(req_q, req_nz_q, free_q, free_pods,
     return out.reshape(-1)[:p], dom_counts2, ncom, nrep
 
 
+def _per_row(table, idx, n_rows: int, wave_w: int):
+    """`table(r)` at each wave member's row `idx` (W,), where `table` maps
+    a vector of row ids (G,) to a (G, ...) table of per-row lookups.
+
+    Both shapes are static. When there are no more rows than wave members
+    (class planes and class shortlists: a handful of rows) the table is
+    evaluated once per ROW and the members read their row — a gather of W
+    contiguous rows — instead of once per member: the lookups behind it
+    fall from W·M to n_rows·M. The per-pod degenerate form (n_rows == P,
+    `class_split_fallbacks`) evaluates the W members' rows directly and
+    never builds an (n_rows, ·) table."""
+    if n_rows <= wave_w:
+        return table(jnp.arange(n_rows, dtype=jnp.int32))[idx]
+    return table(idx)
+
+
 def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
                          used_nz_q, alloc_q, mask, static_scores, fit_col_w,
                          bal_col_mask, shape_u, shape_s, w_fit, w_bal,
                          strategy: str, wave_w: int,
-                         sc0, sl_class, sl_cand, sl_thresh, has_node,
+                         sl_class, sl_cand, sl_val, sl_thresh, has_node,
                          poison: bool, exc=None):
     """_shortlist_scan with W pods per wave step.
 
@@ -1450,101 +1476,130 @@ def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
     A member failing either falls into the serial replay, which runs the
     full N-wide row (exact regardless of why the bound failed); replays
     count into `fallbacks` — they pay the same O(N) a W=1 bound-check
-    fallback pays. Chunk-touched candidates are already evaluated LIVE
-    against the carry (the `touched` gather), so wave-start candidate
-    values equal serial values everywhere except same-wave commits.
-    poison semantics as _rescoring_wave_scan (the vmapped multistart
-    shape). Returns (assign, fallbacks, commits, replays, poisoned)."""
+    fallback pays. poison semantics as _rescoring_wave_scan (the vmapped
+    multistart shape). Returns (assign, fallbacks, commits, replays,
+    poisoned).
+
+    Each candidate is looked up ONCE — per chunk where it cannot change,
+    per wave step where it can — never once per wave member (a TPU
+    gathers element by element at scalar pace: the per-member form was
+    8·W·(K+P) lookups a step and set the pace of the 50k drain):
+
+    - chunk constants — the shortlist tables, one row per shortlist
+      CLASS (`sl_class` (P,) maps a pod to its row; pods of a class share
+      plane row and request, which is what makes them share a shortlist):
+      `sl_cand` (S,K) node ids, `sl_thresh` (S,), and `sl_val` (S,K), each
+      slot's masked value at chunk start: sc0 where the class is
+      chunk-start feasible on the node (plane mask ∧ capacity fit), else
+      -inf — the value `shortlist_prefilter` ranked. An UNTOUCHED node
+      still has exactly that state, so its slot needs no lookup at all;
+      and because the value is sc0's own float, the `== thresh`
+      comparison never straddles two evaluations of one quantity (the
+      W=1 rule). Also `mstat` (C,N), the mask folded into the static
+      plane as -inf, so that one lookup reads both.
+    - per step, once for the whole wave — the touched list `tidx` is
+      shared by all members: node state at `tidx` is gathered once
+      ((P_pad,) rows of alloc/used_nz/free/free_pods) and the W members
+      are scored against it by broadcasting their request rows through
+      the kernels the full-width scans call (elementwise per node:
+      bitwise the W=1 values); `mstat` at `tidx` is read per plane row,
+      then by member (`_per_row`).
+    - a TOUCHED shortlist node sits in `tidx` with its live value, so its
+      shortlist slot reads -inf (`_wave_spec_picks` resolves by node id;
+      the two slots held the same value before). Touched-ness is a
+      compare against `tidx`, per class row (`_per_row`), not a lookup.
+    - per member, elementwise on the node ids only: the `exc` pin and the
+      padding bit `real`.
+    """
     from kubernetes_tpu.ops import kernels  # local to avoid import cycle
 
     n = free_q.shape[0]
     p = req_q.shape[0]
     W = max(1, min(wave_w, p))
+    n_rows = mask.shape[0]
+    n_cls = sl_cand.shape[0]
     iota_n = jnp.arange(n, dtype=jnp.int32)
     ex = jnp.full((p,), -1, jnp.int32) if exc is None else exc
-    (req_w, req_nz_w, rows_w, cand_w, t_w, cls_w, hn_w, ex_w), real_w, \
-        p_pad = _wave_split(
-            W, (req_q, req_nz_q, rows, sl_cand, sl_thresh, sl_class,
-                has_node, ex))
-
-    def live_scores(ci, row, cls, req_nz, used_nz, touched):
-        """(W,M) candidate scores: live recompute for touched nodes,
-        chunk-start sc0 gather for untouched (the W=1 float-consistency
-        rule — the == threshold comparison never straddles two
-        evaluations of the same quantity)."""
-        live = static_scores[row[:, None], ci]
-        live = live + w_fit * jax.vmap(
-            lambda a, u, rn: kernels.fit_score(
-                a, u, rn[None, :], fit_col_w, strategy, shape_u,
-                shape_s)[0])(alloc_q[ci], used_nz[ci], req_nz)
-        live = live + w_bal * jax.vmap(
-            lambda a, u, rn: kernels.balanced_allocation_score(
-                a, u, rn[None, :], bal_col_mask)[0])(
-                    alloc_q[ci], used_nz[ci], req_nz)
-        return jnp.where(touched[ci], live, sc0[cls[:, None], ci])
+    (req_w, req_nz_w, rows_w, cls_w, hn_w, ex_w), real_w, p_pad = \
+        _wave_split(W, (req_q, req_nz_q, rows, sl_class, has_node, ex))
+    mstat = jnp.where(mask, static_scores, NEG_INF)             # (C,N)
 
     def wave_step(carry, inp):
-        (free_q, free_pods, used_nz, touched, tidx, kstep, nfall,
-         ncom, nrep, pois) = carry
-        req, req_nz, row, cand, t, cls, hn, e, real = inp
-        cset = jnp.concatenate(
-            [cand, jnp.broadcast_to(tidx[None, :], (W, p_pad))], axis=1)
-        valid = cset < n
-        ci = jnp.where(valid, cset, 0)                          # (W,M)
-        live = live_scores(ci, row, cls, req_nz, used_nz, touched)
-        fits = mask[row[:, None], ci] & valid \
-            & jnp.all(req[:, None, :] <= free_q[ci], axis=-1) \
-            & (free_pods[ci] >= 1) \
-            & ((e < 0)[:, None] | (ci == e[:, None])) \
-            & real[:, None]
-        masked = jnp.where(fits, live, NEG_INF)
-        b, y = _wave_spec_picks(masked, ci, n, W)
+        (free_q, free_pods, used_nz, tidx, kstep, nfall, ncom, nrep,
+         pois) = carry
+        req, req_nz, row, cls, hn, e, real = inp
+        # Touched half: live, against node state gathered once.
+        ti = jnp.minimum(tidx, n - 1)                           # (P_pad,)
+        al_t, unz_t = alloc_q[ti], used_nz[ti]
+        ms_t = _per_row(lambda r: mstat[r[:, None], ti[None, :]],
+                        row, n_rows, W)                         # (W,P_pad)
+        live_t = ms_t + w_fit * kernels.fit_score(
+            al_t, unz_t, req_nz, fit_col_w, strategy, shape_u, shape_s)
+        live_t = live_t + w_bal * kernels.balanced_allocation_score(
+            al_t, unz_t, req_nz, bal_col_mask)
+        fits_t = (ms_t > NEG_INF) & (tidx < n)[None, :] \
+            & jnp.all(req[:, None, :] <= free_q[ti][None, :, :], axis=-1) \
+            & (free_pods[ti] >= 1)[None, :]
+        # Shortlist half: the chunk-start value unless the node was
+        # touched since (tidx's sentinel n equals no node id).
+        dead = _per_row(
+            lambda s: jnp.any(
+                sl_cand[s][:, :, None] == tidx[None, None, :], axis=-1),
+            cls, n_cls, W)                                      # (W,K)
+        node_of = jnp.concatenate(
+            [sl_cand[cls], jnp.broadcast_to(tidx[None, :], (W, p_pad))],
+            axis=1)                                             # (W,M)
+        masked = jnp.concatenate(
+            [jnp.where(dead, NEG_INF, sl_val[cls]),
+             jnp.where(fits_t, live_t, NEG_INF)], axis=1)
+        masked = jnp.where(
+            ((e < 0)[:, None] | (node_of == e[:, None])) & real[:, None],
+            masked, NEG_INF)
+        b, y = _wave_spec_picks(masked, node_of, n, W)
         safe = jnp.minimum(y, n - 1)
         hit = y < n
         # The W=1 trusted rule on each member's pick (chunk-touched
         # status at wave start; picks are never same-wave commits).
+        t = sl_thresh[cls]
+        y_touched = jnp.any(y[:, None] == tidx[None, :], axis=1)
         trusted = jnp.where(
             hit,
-            (b > t) | ((b == t) & jnp.logical_not(touched[safe])),
+            (b > t) | ((b == t) & jnp.logical_not(y_touched)),
             t == NEG_INF) | jnp.logical_not(hn)
+        ms_pair = _per_row(lambda r: mstat[r[:, None], safe[None, :]],
+                           row, n_rows, W)                      # (W,W)
         conflict = jnp.logical_not(trusted) | _wave_conflicts(
             b, y, n, req, req_nz, free_q, free_pods, used_nz, alloc_q,
-            mask[row[:, None], safe[None, :]]
+            (ms_pair > NEG_INF)
             & ((e < 0)[:, None] | (safe[None, :] == e[:, None]))
             & real[:, None],
-            static_scores[row[:, None], safe[None, :]],
-            fit_col_w, bal_col_mask, shape_u, shape_s, w_fit, w_bal,
-            strategy)
+            ms_pair, fit_col_w, bal_col_mask, shape_u, shape_s, w_fit,
+            w_bal, strategy)
         nreal = jnp.sum(real.astype(jnp.int32))
 
         def fast(st):
-            (fq, fp, unz, tch, tix, ks, nf, nc, nr, po) = st
+            (fq, fp, unz, tix, ks, nf, nc, nr, po) = st
             fq = fq.at[safe].add(
                 jnp.where(hit[:, None], -req, 0).astype(fq.dtype))
             fp = fp.at[safe].add(jnp.where(hit, -1, 0).astype(fp.dtype))
             unz = unz.at[safe].add(
                 jnp.where(hit[:, None], req_nz, 0).astype(unz.dtype))
-            # max-combine, NOT read-modify-write set: every no-pick
-            # member aliases index n-1 through `safe`, and a duplicate-
-            # index .set() scatter leaves which update wins unspecified
-            # — a stale False could erase a same-wave commit's mark.
-            tch = tch.at[safe].max(hit)
             tix = lax.dynamic_update_slice(
                 tix, jnp.where(hit, y, n), (ks,))
-            return (fq, fp, unz, tch, tix, ks + W, nf, nc + nreal, nr,
+            return (fq, fp, unz, tix, ks + W, nf, nc + nreal, nr,
                     po), jnp.where(hit, y, jnp.int32(-1))
 
         if poison:
             carry2, out = fast(
-                (free_q, free_pods, used_nz, touched, tidx, kstep, nfall,
-                 ncom, nrep, pois | jnp.any(conflict)))
+                (free_q, free_pods, used_nz, tidx, kstep, nfall, ncom,
+                 nrep, pois | jnp.any(conflict)))
             return carry2, out
 
         def slow(st):
-            (fq, fp, unz, tch, tix, ks, nf, nc, nr, po) = st
+            (fq, fp, unz, tix, ks, nf, nc, nr, po) = st
 
             def body(w, s):
-                fq, fp, unz, tch, tix, out = s
+                fq, fp, unz, tix, out = s
                 rq, rnz = req[w], req_nz[w]
                 fits_n = mask[row[w]] & real[w] \
                     & jnp.all(rq[None, :] <= fq, axis=1) & (fp >= 1) \
@@ -1564,29 +1619,27 @@ def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
                 fp = fp.at[sf].add(jnp.where(hitw, -1, 0).astype(fp.dtype))
                 unz = unz.at[sf].add(
                     jnp.where(hitw, rnz, 0).astype(unz.dtype))
-                tch = tch.at[sf].set(tch[sf] | hitw)
                 tix = tix.at[ks + w].set(jnp.where(hitw, idx, n))
-                return (fq, fp, unz, tch, tix, out.at[w].set(idx))
+                return (fq, fp, unz, tix, out.at[w].set(idx))
 
-            fq, fp, unz, tch, tix, out = lax.fori_loop(
+            fq, fp, unz, tix, out = lax.fori_loop(
                 0, W, body,
-                (fq, fp, unz, tch, tix, jnp.full((W,), -1, jnp.int32)))
-            return (fq, fp, unz, tch, tix, ks + W, nf + nreal, nc,
+                (fq, fp, unz, tix, jnp.full((W,), -1, jnp.int32)))
+            return (fq, fp, unz, tix, ks + W, nf + nreal, nc,
                     nr + nreal, po), out
 
         return lax.cond(
             jnp.any(conflict), slow, fast,
-            (free_q, free_pods, used_nz, touched, tidx, kstep, nfall,
-             ncom, nrep, pois))
+            (free_q, free_pods, used_nz, tidx, kstep, nfall, ncom, nrep,
+             pois))
 
     carry0 = (free_q, free_pods, used_nz_q,
-              jnp.zeros((n,), jnp.bool_),
               jnp.full((p_pad,), n, jnp.int32),
               jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
               jnp.bool_(False))
-    (_, _, _, _, _, _, nfall, ncom, nrep, pois), out = lax.scan(
+    (_, _, _, _, _, nfall, ncom, nrep, pois), out = lax.scan(
         wave_step, carry0,
-        (req_w, req_nz_w, rows_w, cand_w, t_w, cls_w, hn_w, ex_w, real_w))
+        (req_w, req_nz_w, rows_w, cls_w, hn_w, ex_w, real_w))
     return out.reshape(-1)[:p], nfall, ncom, nrep, pois
 
 
@@ -1597,19 +1650,22 @@ def greedy_assign_rescoring_shortlist_wave(req_q, req_nz_q, free_q,
                                            bal_col_mask, shape_u, shape_s,
                                            w_fit, w_bal, strategy: str,
                                            wave_w: int,
-                                           sc0, sl_class, sl_cand,
+                                           sl_class, sl_cand, sl_val,
                                            sl_thresh, has_node, rows=None,
                                            exc=None):
     """greedy_assign_rescoring_shortlist with wavefront waves: exact via
     the in-step serial replay (full N-wide rows, counted as fallbacks).
-    Returns (assign (P,), fallbacks, commits, replays)."""
+    The shortlist arrives as CLASS tables (sl_cand/sl_val (S,K),
+    sl_thresh (S,)) addressed through sl_class (P,) — see
+    _shortlist_wave_scan. Returns (assign (P,), fallbacks, commits,
+    replays)."""
     if rows is None:
         rows = jnp.arange(req_q.shape[0], dtype=jnp.int32)
     assign, nfall, ncom, nrep, _ = _shortlist_wave_scan(
         req_q, req_nz_q, rows, free_q, free_pods, used_nz_q, alloc_q,
         mask, static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
-        w_fit, w_bal, strategy, wave_w, sc0, sl_class, sl_cand, sl_thresh,
-        has_node, poison=False, exc=exc)
+        w_fit, w_bal, strategy, wave_w, sl_class, sl_cand, sl_val,
+        sl_thresh, has_node, poison=False, exc=exc)
     return assign, nfall, ncom, nrep
 
 
@@ -1621,13 +1677,16 @@ def multistart_greedy_assign_shortlist_wave(req_q, req_nz_q, free_q,
                                             w_fit, w_bal, strategy: str,
                                             wave_w: int, perms,
                                             gang_onehot, gang_required,
-                                            sc0, sl_class, sl_cand,
+                                            sl_class, sl_cand, sl_val,
                                             sl_thresh, has_node, rows=None,
                                             exc=None):
     """multistart_greedy_assign_shortlist with wavefront waves under the
     vmap: each order runs speculation-only and poisons on its first wave
     conflict OR failed bound check; one outer lax.cond reruns the whole
     chunk through the W=1 full multistart when any order was poisoned.
+    The class shortlist tables are chunk-start state, so they are
+    permutation-independent and stay unpermuted; only the per-pod
+    vectors (sl_class among them) reorder.
     Returns (assign (P,), fallback_pods, commits, replays) — fallback
     and replay accounting is whole-chunk here, like the W=1 variant."""
     P = req_q.shape[0]
@@ -1640,9 +1699,8 @@ def multistart_greedy_assign_shortlist_wave(req_q, req_nz_q, free_q,
             req_q[perm], req_nz_q[perm], rows[perm], free_q, free_pods,
             used_nz_q, alloc_q, mask, static_scores, fit_col_w,
             bal_col_mask, shape_u, shape_s, w_fit, w_bal, strategy, wave_w,
-            sc0, sl_class[perm], sl_cand[perm], sl_thresh[perm],
-            has_node[perm], poison=True,
-            exc=None if exc is None else exc[perm])
+            sl_class[perm], sl_cand, sl_val, sl_thresh, has_node[perm],
+            poison=True, exc=None if exc is None else exc[perm])
         inv = jnp.zeros_like(perm).at[perm].set(arange_p)
         return a[inv], pois
 

@@ -133,20 +133,12 @@ class TestRescoringWaveParity:
 
 
 class TestMultistartWaveParity:
-    def _multi_args(self, rng, p, k=4):
-        perms = np.tile(np.arange(p, dtype=np.int32), (k, 1))
-        for i in range(1, k):
-            perms[i] = rng.permutation(p).astype(np.int32)
-        gang = np.zeros((p, 16), np.float32)
-        gr = np.zeros((16,), np.float32)
-        return (jnp.asarray(perms), jnp.asarray(gang), jnp.asarray(gr))
-
     def test_permuted_orders_and_gangs(self):
         for seed in range(3):
             rng = np.random.default_rng(200 + seed)
             p = 24
             args, _ = _problem(rng, n=48, p=p, r=2, tight=(seed == 0))
-            perms, gang, gr = self._multi_args(rng, p)
+            perms, gang, gr = _multi_orders(rng, p, 4).values()
             # One gang of 5 with an unreachable quota: all-or-nothing
             # must drop its partial placements identically.
             gang = np.asarray(gang).copy()
@@ -168,28 +160,63 @@ class TestMultistartWaveParity:
                 assert int(com) + int(rep) == p
 
 
-class TestShortlistWaveParity:
-    def _shortlist_state(self, args, masks, k, strategy):
-        mask_np, scores_np = masks
-        free_q = np.asarray(args["free_q"])
-        req = np.asarray(args["req_q"])
-        rows = np.asarray(args["rows"]) if "rows" in args \
-            else np.arange(req.shape[0], dtype=np.int32)
-        sc0 = kernels.chunk_start_scores(
-            args["alloc_q"], args["used_nz_q"],
-            jnp.asarray(req), jnp.asarray(scores_np[rows]),
-            args["fit_col_w"], args["bal_col_mask"], args["shape_u"],
-            args["shape_s"], args["w_fit"], args["w_bal"], strategy)
-        feas0 = mask_np[rows] \
-            & np.all(req[:, None, :] <= free_q[None, :, :], axis=-1) \
-            & (np.asarray(args["free_pods"]) >= 1)[None, :]
-        cand, thresh = solver.shortlist_prefilter(
-            jnp.asarray(feas0), sc0, k)
-        hn = jnp.asarray(mask_np[rows].any(axis=1))
-        cls = jnp.arange(req.shape[0], dtype=jnp.int32)
-        return dict(sc0=sc0, sl_class=cls, sl_cand=cand,
-                    sl_thresh=thresh, has_node=hn)
+def _shortlist_tables(args, masks, k, strategy, per_pod=False, exc=None):
+    """Shortlist state for one problem, as `_mask_solve_update` builds
+    it: one shortlist row per plane row (S = C, sl_class = rows — pods
+    of a class share requests, so they share chunk-start scores), or
+    with per_pod the identity form S = P (a row per pod, whatever the
+    planes). Returns (wave, serial): the class tables the wave entries
+    take, and their per-pod expansion plus sc0 for the W=1 entries."""
+    mask_np, scores_np = masks
+    free_q = np.asarray(args["free_q"])
+    req = np.asarray(args["req_q"])
+    p = req.shape[0]
+    rows = np.asarray(args["rows"]) if "rows" in args \
+        else np.arange(p, dtype=np.int32)
+    if per_pod:
+        sl_class = np.arange(p, dtype=np.int32)
+        req_s, mask_s, stat_s = req, mask_np[rows], scores_np[rows]
+    else:
+        sl_class = rows
+        req_s = np.zeros((mask_np.shape[0], req.shape[1]), np.int32)
+        req_s[rows] = req
+        mask_s, stat_s = mask_np, scores_np
+    sc0 = kernels.chunk_start_scores(
+        args["alloc_q"], args["used_nz_q"], jnp.asarray(req_s),
+        jnp.asarray(stat_s), args["fit_col_w"], args["bal_col_mask"],
+        args["shape_u"], args["shape_s"], args["w_fit"], args["w_bal"],
+        strategy)
+    feas0 = jnp.asarray(
+        mask_s
+        & np.all(req_s[:, None, :] <= free_q[None, :, :], axis=-1)
+        & (np.asarray(args["free_pods"]) >= 1)[None, :])
+    cand, thresh = solver.shortlist_prefilter(feas0, sc0, k)
+    val = jnp.where(jnp.take_along_axis(feas0, cand, axis=1),
+                    jnp.take_along_axis(sc0, cand, axis=1), solver.NEG_INF)
+    hn = mask_np[rows].any(axis=1)
+    if exc is not None:
+        # the backend's narrowing: a pinned pod's only possible node
+        pin = np.clip(exc, 0, mask_np.shape[1] - 1)
+        hn = np.where(exc >= 0, mask_np[rows, pin], hn)
+    hn = jnp.asarray(hn)
+    cls = jnp.asarray(sl_class)
+    wave = dict(sl_class=cls, sl_cand=cand, sl_val=val, sl_thresh=thresh,
+                has_node=hn)
+    serial = dict(sc0=sc0, sl_class=cls, sl_cand=cand[cls],
+                  sl_thresh=thresh[cls], has_node=hn)
+    return wave, serial
 
+
+def _multi_orders(rng, p, k):
+    perms = np.tile(np.arange(p, dtype=np.int32), (k, 1))
+    for i in range(1, k):
+        perms[i] = rng.permutation(p).astype(np.int32)
+    return dict(perms=jnp.asarray(perms),
+                gang_onehot=jnp.zeros((p, 16), jnp.float32),
+                gang_required=jnp.zeros((16,), jnp.float32))
+
+
+class TestShortlistWaveParity:
     @pytest.mark.parametrize("strategy",
                              ["LeastAllocated", "MostAllocated"])
     def test_shortlist_wave_bit_identity(self, strategy):
@@ -199,9 +226,9 @@ class TestShortlistWaveParity:
             rng = np.random.default_rng(300 + seed)
             args, masks = _problem(rng, n=64, p=19, r=2,
                                    tight=(seed % 2 == 0))
-            sl = self._shortlist_state(args, masks, k=6, strategy=strategy)
-            # sc0 here is per-POD (rows gathered), so the scan's class
-            # index is the identity.
+            # the identity form: a shortlist row per pod
+            sl, _ = _shortlist_tables(args, masks, k=6, strategy=strategy,
+                                      per_pod=True)
             ref = np.asarray(solver.greedy_assign_rescoring(
                 strategy=strategy, **args))
             for w in WIDTHS + (19,):
@@ -217,25 +244,231 @@ class TestShortlistWaveParity:
             rng = np.random.default_rng(400 + seed)
             p = 16
             args, masks = _problem(rng, n=96, p=p, r=2)
-            sl = self._shortlist_state(args, masks, k=5,
-                                       strategy="LeastAllocated")
-            perms = np.tile(np.arange(p, dtype=np.int32), (3, 1))
-            for i in range(1, 3):
-                perms[i] = rng.permutation(p).astype(np.int32)
-            gang = jnp.zeros((p, 16), jnp.float32)
-            gr = jnp.zeros((16,), jnp.float32)
+            sl, sl1 = _shortlist_tables(args, masks, k=5,
+                                        strategy="LeastAllocated",
+                                        per_pod=True)
+            multi = _multi_orders(rng, p, 3)
             ref, _ = solver.multistart_greedy_assign_shortlist(
-                strategy="LeastAllocated", perms=jnp.asarray(perms),
-                gang_onehot=gang, gang_required=gr, **sl, **args)
+                strategy="LeastAllocated", **multi, **sl1, **args)
             for w in WIDTHS:
                 a, _, com, rep = \
                     solver.multistart_greedy_assign_shortlist_wave(
-                        strategy="LeastAllocated", wave_w=w,
-                        perms=jnp.asarray(perms), gang_onehot=gang,
-                        gang_required=gr, **sl, **args)
+                        strategy="LeastAllocated", wave_w=w, **multi,
+                        **sl, **args)
                 np.testing.assert_array_equal(np.asarray(a),
                                               np.asarray(ref))
                 assert int(com) + int(rep) == p
+
+    @pytest.mark.parametrize("strategy",
+                             ["LeastAllocated", "MostAllocated"])
+    @pytest.mark.parametrize("classes", [1, 2, 5, None])
+    def test_class_tables_bit_identity(self, classes, strategy):
+        """Shared class rows (S = C < P: the members of a wave read
+        their class's shortlist row and plane row) and the per-pod
+        identity form (S = C = P: every lookup per member) against the
+        full-width serial scan."""
+        for seed in range(3):
+            rng = np.random.default_rng(900 + seed)
+            p = 37
+            args, masks = _problem(rng, n=80, p=p, r=2,
+                                   tight=(seed == 0), classes=classes)
+            sl, _ = _shortlist_tables(args, masks, k=9, strategy=strategy)
+            assert sl["sl_cand"].shape[0] == (classes or p)
+            ref = np.asarray(solver.greedy_assign_rescoring(
+                strategy=strategy, **args))
+            for w in (4, 16):
+                a, _, com, rep = \
+                    solver.greedy_assign_rescoring_shortlist_wave(
+                        strategy=strategy, wave_w=w, **sl, **args)
+                np.testing.assert_array_equal(
+                    np.asarray(a), ref,
+                    err_msg=f"S={classes} W={w} seed={seed}")
+                assert int(com) + int(rep) == p
+
+    @pytest.mark.parametrize("wave_w", [2, 8])
+    def test_touched_shortlist_nodes_repicked(self, wave_w):
+        """MostAllocated on tight, uniform-request capacity: a node's
+        score RISES when it is debited, so shortlist nodes committed in
+        an early wave are picked again in later waves. Their shortlist
+        slot must read -inf from then on (its chunk-start value is
+        stale) while `tidx` carries the live value."""
+        n, p, r, k = 48, 24, 2, 6
+        rng = np.random.default_rng(77)
+        args, masks = _problem(rng, n=n, p=p, r=r, classes=2)
+        small = np.full((p, r), 600, np.int32)
+        args["req_q"] = args["req_nz_q"] = jnp.asarray(small)
+        alloc = np.asarray(args["alloc_q"])
+        args["used_nz_q"] = jnp.asarray(np.zeros_like(alloc))
+        args["free_q"] = jnp.asarray(alloc)
+        # five slots a node: the piles straddle wave boundaries
+        args["free_pods"] = jnp.asarray(np.full((n,), 5, np.int32))
+        sl, _ = _shortlist_tables(args, masks, k=k,
+                                  strategy="MostAllocated")
+        ref = np.asarray(solver.greedy_assign_rescoring(
+            strategy="MostAllocated", **args))
+        a, nfall, com, rep = solver.greedy_assign_rescoring_shortlist_wave(
+            strategy="MostAllocated", wave_w=wave_w, **sl, **args)
+        np.testing.assert_array_equal(np.asarray(a), ref)
+        # not vacuous: some shortlist node of the pod's class was
+        # committed in one wave and picked again in a later one
+        cand = np.asarray(sl["sl_cand"])
+        cls = np.asarray(sl["sl_class"])
+        first_wave = {}
+        repicked = 0
+        for i, node in enumerate(ref):
+            if node < 0:
+                continue
+            wv = i // wave_w
+            if node in first_wave and first_wave[node] < wv \
+                    and node in cand[cls[i]]:
+                repicked += 1
+            first_wave.setdefault(node, wv)
+        assert repicked > 0
+
+    @pytest.mark.parametrize("classes", [2, None])
+    def test_exception_pins_in_and_out_of_shortlist(self, classes):
+        """`exc` pins to a column inside the class shortlist (the slot
+        survives the elementwise pin test) and to one outside it (every
+        slot masks out: the bound check fails and the replay — or, for
+        the multistart, the whole-chunk rerun — resolves it exactly)."""
+        rng = np.random.default_rng(1234)
+        n, p, k = 72, 20, 8
+        args, masks = _problem(rng, n=n, p=p, r=2, classes=classes)
+        plain, _ = _shortlist_tables(args, masks, k=k,
+                                     strategy="LeastAllocated")
+        cand = np.asarray(plain["sl_cand"])
+        cls = np.asarray(plain["sl_class"])
+        exc = np.full((p,), -1, np.int32)
+        inside = [1, 6, 11]
+        outside = [3, 8, 15]
+        for i in inside:
+            exc[i] = cand[cls[i], i % k]
+        for i in outside:
+            exc[i] = next(c for c in range(n) if c not in cand[cls[i]])
+        args["exc"] = jnp.asarray(exc)
+        sl, sl1 = _shortlist_tables(args, masks, k=k,
+                                    strategy="LeastAllocated", exc=exc)
+        ref = np.asarray(solver.greedy_assign_rescoring(
+            strategy="LeastAllocated", **args))
+        # not vacuous: pins of both kinds land
+        assert any(ref[i] == exc[i] for i in inside)
+        assert any(ref[i] == exc[i] for i in outside)
+        for w in (2, 8):
+            a, nfall, _, _ = solver.greedy_assign_rescoring_shortlist_wave(
+                strategy="LeastAllocated", wave_w=w, **sl, **args)
+            np.testing.assert_array_equal(np.asarray(a), ref)
+            assert int(nfall) > 0      # the outside pins replayed
+        multi = _multi_orders(rng, p, 3)
+        mref, _ = solver.multistart_greedy_assign_shortlist(
+            strategy="LeastAllocated", **multi, **sl1, **args)
+        a, _, _, _ = solver.multistart_greedy_assign_shortlist_wave(
+            strategy="LeastAllocated", wave_w=4, **multi, **sl, **args)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(mref))
+
+    @pytest.mark.parametrize("wave_w", [1, 2, 8, 64])
+    @pytest.mark.parametrize("entry", ["single", "multistart"])
+    def test_widths_and_orders(self, entry, wave_w):
+        """W ∈ {1, 2, 8, 64 > P} on class tables, single order and the
+        multistart entry under four orders."""
+        rng = np.random.default_rng(4321)
+        p = 28
+        args, masks = _problem(rng, n=120, p=p, r=2, classes=3)
+        sl, sl1 = _shortlist_tables(args, masks, k=p,
+                                    strategy="LeastAllocated")
+        if entry == "single":
+            ref = np.asarray(solver.greedy_assign_rescoring(
+                strategy="LeastAllocated", **args))
+            a, _, com, rep = solver.greedy_assign_rescoring_shortlist_wave(
+                strategy="LeastAllocated", wave_w=wave_w, **sl, **args)
+        else:
+            multi = _multi_orders(rng, p, 4)
+            ref, _ = solver.multistart_greedy_assign_shortlist(
+                strategy="LeastAllocated", **multi, **sl1, **args)
+            ref = np.asarray(ref)
+            a, _, com, rep = solver.multistart_greedy_assign_shortlist_wave(
+                strategy="LeastAllocated", wave_w=wave_w, **multi, **sl,
+                **args)
+        np.testing.assert_array_equal(np.asarray(a), ref)
+        assert int(com) + int(rep) == p
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            if hasattr(x, "jaxpr") and hasattr(x.jaxpr, "eqns"):
+                yield x.jaxpr           # ClosedJaxpr
+            elif hasattr(x, "eqns"):
+                yield x
+
+
+def _scans_of_length(jaxpr, length):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == length:
+            yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _scans_of_length(sub, length)
+
+
+def _gathered_index_rows(jaxpr):
+    """Index rows of every gather under `jaxpr`: the count of separate
+    lookups, whatever the width of the slice each one returns."""
+    rows = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            rows += int(np.prod(eqn.invars[1].aval.shape[:-1]))
+        for sub in _sub_jaxprs(eqn):
+            rows += _gathered_index_rows(sub)
+    return rows
+
+
+class TestShortlistWaveGatherBudget:
+    """Structural pin (abstract trace, CPU, a count and not a speed): the
+    lookups inside one wave step of the shortlist wave scan, at the
+    50,000-node cell's shapes. A TPU gathers element by element at
+    scalar pace, and the per-member form of this scan — every member
+    gathering node state, planes and sc0 through its own (K+P)-wide
+    candidate row, 1,048,576 index rows a step and order in eight
+    distinct gathers (1,319,232 in ten before XLA merges two) — set the
+    pace of the 50k drain (PERF.md, PR 26). A refactor must not put it
+    back."""
+
+    PER_MEMBER_FORM = 8 * 64 * 2048
+
+    @pytest.mark.parametrize("classes,budget", [
+        (2, PER_MEMBER_FORM // 64),      # class planes: today 6,912
+        (1024, PER_MEMBER_FORM // 8),    # per-pod planes: today 74,240
+    ])
+    def test_gathered_rows_per_wave_step(self, classes, budget):
+        n, p, k, w, r, orders = 50_176, 1024, 1024, 64, 2, 4
+        f32, i32 = jnp.float32, jnp.int32
+        S = jax.ShapeDtypeStruct
+        args = dict(
+            req_q=S((p, r), i32), req_nz_q=S((p, r), i32),
+            free_q=S((n, r), i32), free_pods=S((n,), i32),
+            used_nz_q=S((n, r), i32), alloc_q=S((n, r), i32),
+            mask=S((classes, n), jnp.bool_),
+            static_scores=S((classes, n), f32),
+            fit_col_w=S((r,), f32), bal_col_mask=S((r,), jnp.bool_),
+            shape_u=S((2,), f32), shape_s=S((2,), f32),
+            w_fit=S((), f32), w_bal=S((), f32),
+            perms=S((orders, p), i32), gang_onehot=S((p, 16), f32),
+            gang_required=S((16,), f32),
+            sl_class=S((p,), i32), sl_cand=S((classes, k), i32),
+            sl_val=S((classes, k), f32), sl_thresh=S((classes,), f32),
+            has_node=S((p,), jnp.bool_), rows=S((p,), i32),
+            exc=S((p,), i32))
+        closed = jax.make_jaxpr(
+            lambda kw: solver.multistart_greedy_assign_shortlist_wave(
+                strategy="LeastAllocated", wave_w=w, **kw))(args)
+        # the wave scan is the one of P/W steps (the whole-chunk rerun
+        # behind the poison cond scans P steps)
+        wave_scans = list(_scans_of_length(closed.jaxpr, p // w))
+        assert len(wave_scans) == 1
+        rows = _gathered_index_rows(wave_scans[0].params["jaxpr"].jaxpr)
+        per_order = rows / orders
+        assert 0 < per_order <= budget, \
+            f"{per_order:.0f} gathered index rows a wave step and " \
+            f"order at S={classes}; budget {budget}"
 
 
 class TestSpreadWaveParity:
