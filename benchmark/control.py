@@ -1,12 +1,15 @@
 """The control of a cell, run as the cell is: the plain reference in the
-program's place with one guarantee broken (lib/control.py). The last
-line's `correct` has to read false.
+program's place with one guarantee broken (lib/control.py) — which one
+is the deployment's to say (lib/reference.py: `placer`; the default
+places 1,024 pods, the program's chunk width, by one look at the
+cluster). The last line's `correct` has to read false.
 
     python3 benchmark/control.py --workload <name> --seed <n> --seconds <s> [--sound]
 
 `--sound` runs the reference unbroken (one look per pod): `correct` has
 to read true, which shows that it is the fault, not the reference, that
-fails. The benchmark's own runs never run this.
+fails. The benchmark's own runs never run this. It drives no device, so
+any host will do: the result names the platform it found.
 """
 
 import time
@@ -18,9 +21,6 @@ import os  # noqa: E402
 import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-#: pods placed by one look at the cluster: the program's chunk width
-STALE_CHUNK = 1024
 
 
 def main(argv=None) -> int:
@@ -36,13 +36,12 @@ def main(argv=None) -> int:
     from benchmark.lib.harness import Refused, run_cell
     from benchmark.lib.manifest import Manifest
     manifest = Manifest()
-    config = manifest.config(manifest.cell(args.workload))
+    model = manifest.deployment(manifest.config(manifest.cell(args.workload)))
     try:
         return run_cell(
             args.workload, args.seed, args.seconds, False,
-            manifest=manifest, t_process=_T_PROCESS,
-            cluster_factory=control_cluster(
-                config, 1 if args.sound else STALE_CHUNK))
+            manifest=manifest, t_process=_T_PROCESS, require_chip=False,
+            cluster_factory=control_cluster(model, args.sound))
     except Refused as e:
         print(f"benchmark: refused: {e}", file=sys.stderr)
         return 2

@@ -31,6 +31,8 @@ class Cluster:
     device = True
 
     def __init__(self):
+        #: the cell's `chips`: the devices the backend may span
+        self.chips = 1
         self.backing = None
         self.api = None
         self.wire = None
@@ -50,7 +52,7 @@ class Cluster:
         self.rebound: list[str] = []
         self._waiting: set[str] = set()
 
-    async def start(self, batch_size: int = 16384) -> None:
+    async def start(self, batch_size: int = 16384, chips: int = 1) -> None:
         from kubernetes_tpu.apiserver.admission import WebhookAdmission
         from kubernetes_tpu.apiserver.server import APIServer
         from kubernetes_tpu.apiserver.wire import WireServer, WireStore
@@ -66,6 +68,7 @@ class Cluster:
             new_cluster_store,
         )
 
+        self.chips = int(chips)
         # bench.prepare's collector setting: the entry a user runs has it.
         gc.set_threshold(100_000, 50, 50)
         self.backing = new_cluster_store()
@@ -103,7 +106,14 @@ class Cluster:
         from kubernetes_tpu.scheduler import Scheduler
         if self.device:
             from kubernetes_tpu.ops import TPUBackend
-            self.backend = TPUBackend(max_batch=None)
+            # exactly the cell's chips, whatever the machine holds: left
+            # to itself (mesh="auto") the backend shards the node axis
+            # over every device it can see
+            mesh = None
+            if self.chips > 1:
+                from kubernetes_tpu.parallel import build_mesh
+                mesh = build_mesh(self.chips)
+            self.backend = TPUBackend(max_batch=None, mesh=mesh)
         return Scheduler(self.sched_store, seed=42, backend=self.backend,
                          metrics=self.metrics)
 
@@ -131,6 +141,14 @@ class Cluster:
                 return False
             await asyncio.sleep(0.005)
         return True
+
+    def chips_used(self) -> int | None:
+        """Devices the backend's mesh spans (no mesh: one); None where
+        there is no device backend."""
+        if self.backend is None:
+            return None
+        mesh = self.backend.mesh
+        return 1 if mesh is None else int(mesh.devices.size)
 
     def backend_attached(self) -> bool | None:
         """None: no device backend was asked for."""

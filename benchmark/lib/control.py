@@ -1,13 +1,15 @@
 """The control: the plain reference put in the program's place, with one
 guarantee broken. `correct` has to come out false.
 
-`ReferenceScheduler` is the default scheduler of lib/reference.py driven
-as a live component: it watches pending pods on its own wire connection
-and binds them through the pods/binding subresource, one look at the
-cluster per pod. With `stale_chunk` > 1 it looks once every that many
-pods and places the whole chunk by that look — a solve that does not
-carry its own placements forward, the fault a faster pipeline would
-tempt — and nodes go past their allocatable.
+`ReferenceScheduler` is the deployment's placer (lib/reference.py:
+`model.placer(sound)`) driven as a live component: it watches pending
+pods on its own wire connection and binds them through the pods/binding
+subresource. Sound, it looks at the cluster once per pod and keeps what
+the deployment guarantees. Broken, it is whatever the deployment says
+breaks ITS guarantee; the default deployment's looks once every
+`stale_chunk` pods and places the whole chunk by that look — a solve
+that does not carry its own placements forward, the fault a faster
+pipeline would tempt — and nodes go past their allocatable.
 """
 
 from __future__ import annotations
@@ -15,14 +17,14 @@ from __future__ import annotations
 import asyncio
 
 from benchmark.lib.cluster import Cluster
-from benchmark.lib.reference import ClusterModel
+from benchmark.lib.reference import ClusterModel, pod_key
 
 
 class ReferenceScheduler:
-    def __init__(self, store, model: ClusterModel, stale_chunk: int = 1):
+    def __init__(self, store, model: ClusterModel, sound: bool):
         self.store = store
         self.model = model
-        self.stale_chunk = int(stale_chunk)
+        self.sound = sound
         self.backend = None
         self._pending: asyncio.Queue = asyncio.Queue()
         self._stop = False
@@ -38,28 +40,26 @@ class ReferenceScheduler:
 
     async def run(self, batch_size: int = 1) -> None:
         from kubernetes_tpu.api.types import make_binding
-        placer = self.model.placer(self.stale_chunk)
+        placer = self.model.placer(self.sound)
         while not self._stop:
             pod = await self._pending.get()
-            best = placer.place()
+            best = placer.place(pod)
             if best < 0:
                 continue
-            meta = pod["metadata"]
-            key = f"{meta.get('namespace', 'default')}/{meta['name']}"
             await self.store.subresource(
-                "pods", key, "binding", make_binding(pod, f"node-{best}"))
+                "pods", pod_key(pod), "binding",
+                make_binding(pod, self.model.node_names[best]))
 
     async def stop(self) -> None:
         self._stop = True
 
 
-def control_cluster(config: dict, stale_chunk: int):
-    """A Cluster factory whose scheduler is the reference (sound when
-    `stale_chunk` is 1, the control when it is larger)."""
+def control_cluster(model: ClusterModel, sound: bool):
+    """A Cluster factory whose scheduler is the deployment's reference:
+    sound, or with the deployment's guarantee broken (the control)."""
     class ControlCluster(Cluster):
         device = False
 
         def build_scheduler(self):
-            return ReferenceScheduler(
-                self.sched_store, ClusterModel(config), stale_chunk)
+            return ReferenceScheduler(self.sched_store, model, sound)
     return ControlCluster

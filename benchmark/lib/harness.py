@@ -24,12 +24,11 @@ import sys
 import time
 from collections import deque
 
-import numpy as np
-
-from benchmark.lib import counters, reference, trace_reduce
+from benchmark.lib import counters, trace_reduce
 from benchmark.lib.arrivals import stable_seed
 from benchmark.lib.gc_log import GcLog
 from benchmark.lib.manifest import Manifest
+from benchmark.lib.reference import is_correct
 from benchmark.lib.traffic import Generator
 
 
@@ -46,7 +45,8 @@ def host_speed_index() -> float:
 
 
 class Refused(Exception):
-    """The run may not start (no chip, too few chips)."""
+    """The run may not go on (no chip, too few chips, a backend on
+    another number of chips than the cell's)."""
 
 
 class Context:
@@ -63,6 +63,7 @@ class Context:
         self.trace = None             # trace_reduce.reduce(...) or None
         self.traced_pods = 0          # pods bound inside the traced window
         self.config: dict = {}
+        self.model = None             # the deployment (lib/reference.py)
         self.device_kind = ""
         self.memory_stats: list = []  # per device, {} where not reported
 
@@ -72,16 +73,18 @@ class Context:
 
 
 def device_facts(chips: int, require_chip: bool) -> dict:
+    """The devices this cell runs on: exactly `chips` of them, whatever
+    else the machine holds (`count` is the chips used)."""
     import jax
     devices = jax.devices()
     dev = devices[0]
-    if require_chip and (dev.platform != "tpu" or len(devices) < chips):
+    if len(devices) < chips or (require_chip and dev.platform != "tpu"):
         raise Refused(
             f"this cell needs {chips} TPU chip(s); JAX found "
             f"{len(devices)} x {dev.platform!r} ({dev.device_kind!r}). "
             "Nothing was run.")
     return {"platform": dev.platform, "kind": dev.device_kind,
-            "count": len(devices)}
+            "count": chips}
 
 
 def _memory_stats() -> list[dict]:
@@ -154,9 +157,9 @@ class _Profile:
         return trace_reduce.reduce(trace, spans)
 
 
-async def _drive(config: dict, mix: dict, seed: int, seconds: float,
-                 trace: bool, ctx: Context, t_process: float,
-                 cluster_factory, scratch: str) -> dict:
+async def _drive(model, mix: dict, kind, chips: int, seed: int,
+                 seconds: float, trace: bool, ctx: Context,
+                 t_process: float, cluster_factory, scratch: str) -> dict:
     """Set-up, the window and the read-back, on one event loop; returns
     what the comparison needs once the cluster is gone."""
     from benchmark.lib.cluster import Cluster
@@ -165,10 +168,15 @@ async def _drive(config: dict, mix: dict, seed: int, seconds: float,
     stopper = None
     out: dict = {}
     try:
-        await cluster.start()
+        await cluster.start(chips=chips)
+        used = cluster.chips_used()
+        if used is not None and used != chips:
+            raise Refused(
+                f"this cell runs on {chips} chip(s); the backend's mesh "
+                f"spans {used}")
         ctx.metrics = cluster.metrics
-        gen = Generator(cluster, config, mix, seed, ctx.compile_log,
-                        ctx.gc_log)
+        gen = Generator(cluster, model, mix, seed, ctx.compile_log,
+                        ctx.gc_log, kind)
         await gen.stage()
         await gen.warm()
         gc.collect()
@@ -195,8 +203,10 @@ async def _drive(config: dict, mix: dict, seed: int, seconds: float,
         ctx.after = counters.snapshot(cluster.metrics.registry)
         ctx.memory_stats = _memory_stats()
         if profile is not None:
+            t = time.monotonic()
             await profile.stop()
             await stopper
+            out["trace_write_s"] = time.monotonic() - t
             lo = profile.mono_at_mark
             hi = profile.mono_at_end
             ctx.traced_pods = sum(
@@ -228,7 +238,8 @@ async def _drive(config: dict, mix: dict, seed: int, seconds: float,
         }
         out.update(
             bound=dict(cluster.bound), rebound=list(cluster.rebound),
-            readback=readback, lost=lost, created_all=list(gen.all_created))
+            readback=readback, lost=lost, created_all=list(gen.all_created),
+            specs_all=list(gen.all_specs), settled=list(gen.settled))
     finally:
         if stopper is not None and not stopper.done():
             stopper.cancel()
@@ -236,7 +247,9 @@ async def _drive(config: dict, mix: dict, seed: int, seconds: float,
             await profile.stop()
         await cluster.stop()
     if profile is not None:
+        t = time.monotonic()
         ctx.trace = profile.reduce(win.spans)
+        out["trace_reduce_s"] = time.monotonic() - t
         shutil.rmtree(profile.directory, ignore_errors=True)
     return out
 
@@ -253,8 +266,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     cell = manifest.cell(workload)
     config = manifest.config(cell)
     mix = manifest.traffic(cell)
+    model = manifest.deployment(config)
+    kind = None if Generator.defines(mix["kind"]) \
+        else manifest.kind(mix["kind"])
 
-    device = device_facts(int(cell["chips"]), require_chip)
+    chips = int(cell["chips"])
+    device = device_facts(chips, require_chip)
     from kubernetes_tpu.utils.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmark.lib.compile_log import CompileLog
@@ -262,13 +279,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     ctx = Context()
     ctx.config = config
+    ctx.model = model
     ctx.device_kind = device["kind"]
     ctx.compile_log = compile_log
     ctx.gc_log = GcLog()
     scratch = os.path.join(manifest.root, ".bench_scratch")
     try:
         run = asyncio.run(_drive(
-            config, mix, seed, seconds, trace, ctx, t_process,
+            model, mix, kind, chips, seed, seconds, trace, ctx, t_process,
             cluster_factory, scratch))
     finally:
         ctx.gc_log.close()
@@ -280,15 +298,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     device["memory_peak_bytes"] = int(peak)
 
     # -- the metrics of this run -------------------------------------------
-    model = reference.ClusterModel(config)
     metrics: dict[str, dict] = {}
     if not trace:
         quantities = dict(win.quantities, setup_s=run["setup_s"])
-        if win.packing_keys:
-            at = [model.node_index(run["bound"][k])
-                  for k in win.packing_keys if k in run["bound"]]
+        if win.packing_upto:
+            n = win.packing_upto
             quantities["frag_at_packing_pct"] = model.fragmentation(
-                np.array([i for i in at if i >= 0], dtype=np.int64))
+                *model.placed(run["created_all"][:n], run["specs_all"][:n],
+                              run["bound"]))
         table = dict(mix.get("end_to_end", {}), setup_s="setup_s")
         for m in manifest.end_to_end(cell):
             value = quantities.get(table.get(m["name"], ""))
@@ -324,16 +341,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         "host_speed_index_s": [round(run["host_speed_before"], 4),
                                round(run["host_speed_after"], 4)],
         "cache_hits": compile_log.cache_hits,
-        "cache_misses": compile_log.cache_misses}), file=stderr)
+        "cache_misses": compile_log.cache_misses,
+        # what a traced run spends after its window, outside every metric
+        "trace_write_s": round(run.get("trace_write_s", 0.0), 3),
+        "trace_reduce_s": round(run.get("trace_reduce_s", 0.0), 3)}),
+        file=stderr)
 
     # -- correct: the plain reference, once the program's state is freed ---
     gc.collect()
-    numbers = reference.check(
-        model,
-        created=run["created_all"], bound=run["bound"],
-        rebound=run["rebound"], readback=run["readback"],
+    numbers = model.check(
+        created=run["created_all"], specs=run["specs_all"],
+        bound=run["bound"], rebound=run["rebound"],
+        readback=run["readback"], settled=run["settled"],
         not_device_placed=sum(run["lost"].values()))
-    correct = reference.is_correct(numbers)
+    correct = is_correct(numbers)
     failed = win.unbound + int(run["lost"]["host_path_pods"]
                                + run["lost"]["host_fallback_pods"])
     result = {

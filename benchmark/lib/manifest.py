@@ -1,7 +1,11 @@
 """BENCHMARK.json and the files it names. A cell resolves, by the names
 in its entry, to `configs/<config>.json`'s file and
 `traffic/<traffic>.json`; a per-layer metric to `metrics/<name>.json`,
-which names a reader in `readers/`."""
+which names a reader in `readers/`. Code is found the same way: a
+configuration's `deployment` in `deployments/<name>.py` (its nodes, its
+pods, its plain reference and its control: lib/reference.py says what
+one states), and a mix's `kind` that lib/traffic.py does not define in
+`kinds/<kind>.py`."""
 
 from __future__ import annotations
 
@@ -58,11 +62,34 @@ class Manifest:
     def metric_file(self, name: str) -> dict:
         return load_json(self.bench_dir / "metrics" / f"{name}.json")
 
-    def reader(self, name: str):
-        """The `read(ctx, **args)` function of readers/<name>.py."""
-        path = self.bench_dir / "readers" / f"{name}.py"
+    def _module(self, directory: str, name: str):
+        """`<directory>/<name>.py` of the benchmark, loaded by name."""
+        path = self.bench_dir / directory / f"{name}.py"
+        if not path.is_file():
+            raise FileNotFoundError(
+                f"BENCHMARK.json's files name {name!r}, and there is no "
+                f"{path}")
         spec = importlib.util.spec_from_file_location(
-            f"benchmark_reader_{name}", path)
+            f"benchmark_{directory}_{name}", path)
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
-        return module.read
+        return module
+
+    def reader(self, name: str):
+        """The `read(ctx, **args)` function of readers/<name>.py."""
+        return self._module("readers", name).read
+
+    def deployment(self, config: dict):
+        """What this configuration means, built: the `Deployment` of
+        deployments/<config["deployment"]>.py, or the default one
+        (`reference.ClusterModel`) where it names none."""
+        from benchmark.lib.reference import ClusterModel
+        name = config.get("deployment")
+        if name is None:
+            return ClusterModel(config)
+        return self._module("deployments", name).Deployment(config)
+
+    def kind(self, name: str):
+        """kinds/<name>.py: `warm(gen)` and `window(gen, seconds,
+        on_start)` over lib/traffic.py's Generator."""
+        return self._module("kinds", name)

@@ -163,7 +163,10 @@ def reduce(trace: Trace, spans: list[tuple[str, float, float]] | None = None,
     lo, hi = window
     if hi <= lo:
         return None
-    chips = sorted(set(trace.ops) | set(trace.modules))
+    # the chips the cell used: a device the machine holds beside them
+    # has a plane, and nothing on it
+    chips = sorted(c for c in set(trace.ops) | set(trace.modules)
+                   if trace.ops.get(c) or trace.modules.get(c))
     busy = []
     merged_first: list = []
     op_seconds: dict[str, float] = defaultdict(float)

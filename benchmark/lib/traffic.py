@@ -23,6 +23,15 @@ open_loop — an open loop at a fixed `rate`. Arrival gaps are the
 
 Both report generic quantities; the mix's `end_to_end` table says which
 end-to-end metric of BENCHMARK.json is which quantity.
+
+A `kind` that is not defined here is `benchmark/kinds/<kind>.py`, found
+by name (lib/manifest.py): a module with `warm(gen)` and
+`window(gen, seconds, on_start) -> Window` over this Generator's
+services — `create_wave`, `settle`, `last_bound`, `send_open_loop`.
+
+What the nodes and the pods ARE is the deployment's to say
+(lib/reference.py): the generator asks it for the cluster to stage and,
+for each phase and list of names, for the arguments of each pod.
 """
 
 from __future__ import annotations
@@ -35,6 +44,7 @@ import time
 from benchmark.lib import counters
 from benchmark.lib.arrivals import poisson_timeline, stable_seed
 from benchmark.lib.percentiles import percentiles
+from benchmark.lib.reference import pod_key
 
 
 class Window:
@@ -44,7 +54,9 @@ class Window:
         self.start = 0.0          # first create of the window
         self.end = 0.0            # last binding the client saw
         self.created: list[str] = []      # keys whose create was acked
-        self.packing_keys: list[str] | None = None
+        #: packing is judged on the first this many of the generator's
+        #: `all_created` (None: not judged)
+        self.packing_upto: int | None = None
         self.quantities: dict[str, float] = {}
         #: client-clock series, milliseconds, for the per-layer readers
         self.series: dict[str, list[float]] = {}
@@ -54,80 +66,97 @@ class Window:
         self.unbound = 0
 
 
-def pod_key(name: str, template: dict) -> str:
-    return f"{template.get('namespace', 'default')}/{name}"
-
-
 class Generator:
-    def __init__(self, cluster, config: dict, mix: dict, seed: int,
-                 compile_log=None, gc_log=None):
+    def __init__(self, cluster, model, mix: dict, seed: int,
+                 compile_log=None, gc_log=None, kind=None):
         from kubernetes_tpu.api.types import make_node, make_pod
         self._make_node, self._make_pod = make_node, make_pod
         self.cluster = cluster
-        self.config = config
+        #: the deployment (lib/reference.py): the nodes and the pods
+        self.model = model
+        self.config = model.config
         self.mix = mix
+        #: the module of kinds/<kind>.py; None for a kind defined here
+        self.kind = kind
         self.seed = int(seed)
         self.compile_log = compile_log
         self.gc_log = gc_log
-        self.pod_template = config["pod_template"]
         self.width = int(mix.get("create_window", 512))
         self.barrier_s = float(mix.get("barrier_seconds", 60.0))
-        #: every key created so far, set-up included
+        #: every key created so far, set-up included, and beside each
+        #: the arguments its pod was made from
         self.all_created: list[str] = []
+        self.all_specs: list[dict] = []
+        #: lengths of `all_created` at which every pod created so far
+        #: had been seen bound (the end of each wave)
+        self.settled: list[int] = []
+
+    @staticmethod
+    def defines(kind: str) -> bool:
+        return hasattr(Generator, f"_window_{kind}")
 
     # -- staging (set-up) --------------------------------------------------
 
     async def stage(self) -> None:
-        """The configuration's nodes, then its init pods, bound."""
-        tmpl = self.config["node_template"]
-        n = int(self.config["nodes"])
-        for lo in range(0, n, self.width):
+        """The deployment's nodes, then its init pods, bound."""
+        staged = self.model.nodes()
+        for lo in range(0, len(staged), self.width):
             await asyncio.gather(*(
                 self.cluster.client.create("nodes", self._make_node(
-                    f"node-{i}", **copy.deepcopy(tmpl)))
-                for i in range(lo, min(lo + self.width, n))))
+                    name, **copy.deepcopy(kw)))
+                for name, kw in staged[lo:lo + self.width]))
         names = [f"init-{i}" for i in range(int(self.config["init_pods"]))]
-        await self._create_wave(names, None)
-        await self._settle(names)
+        await self.settle(await self.create_wave("init", names))
 
-    async def _create_wave(self, names: list[str], ack_ms: list | None,
-                           stop_at: float | None = None) -> list[str]:
+    # -- what every kind is made of ----------------------------------------
+
+    async def create_wave(self, phase: str, names: list[str],
+                          ack_ms: list | None = None,
+                          stop_at: float | None = None) -> list[str]:
         """Create pods in `width`-wide concurrent windows; returns the
-        names sent (all, unless `stop_at` passed between windows)."""
+        keys sent (all, unless `stop_at` passed between windows)."""
         sent: list[str] = []
         for lo in range(0, len(names), self.width):
             if stop_at is not None and time.monotonic() >= stop_at:
                 break
             part = names[lo:lo + self.width]
+            specs = self.model.pods(phase, part)
             t0 = time.monotonic()
+            pods = [self._make_pod(name, **copy.deepcopy(kw))
+                    for name, kw in zip(part, specs)]
             await asyncio.gather(*(
-                self.cluster.client.create("pods", self._make_pod(
-                    name, **copy.deepcopy(self.pod_template)))
-                for name in part))
+                self.cluster.client.create("pods", pod) for pod in pods))
             if ack_ms is not None:
                 ack_ms.append(1e3 * (time.monotonic() - t0))
-            sent += part
-        self.all_created += [pod_key(n, self.pod_template) for n in sent]
+            # a pod's key comes from the object that was created
+            sent += [pod_key(pod) for pod in pods]
+            self.all_specs += specs
+        self.all_created += sent
         return sent
 
-    async def _settle(self, names: list[str]) -> int:
+    async def settle(self, keys: list[str]) -> int:
         """Wait for these pods' bindings; returns how many never came."""
-        keys = [pod_key(n, self.pod_template) for n in names]
         await self.cluster.wait_bound(keys, time.monotonic() + self.barrier_s)
-        return sum(1 for k in keys if k not in self.cluster.bound)
+        left = sum(1 for k in keys if k not in self.cluster.bound)
+        if not left:
+            self.settled.append(len(self.all_created))
+        return left
 
-    def _last_bound(self, names: list[str], default: float) -> float:
+    def last_bound(self, keys: list[str], default: float) -> float:
         at = self.cluster.bound_at
-        seen = [at[k] for k in (pod_key(n, self.pod_template)
-                                for n in names) if k in at]
-        return max(seen, default=default)
+        return max((at[k] for k in keys if k in at), default=default)
 
-    # -- the two kinds -----------------------------------------------------
+    # -- the kinds ---------------------------------------------------------
 
     async def warm(self) -> None:
-        await getattr(self, f"_warm_{self.mix['kind']}")()
+        if self.kind is not None:
+            await self.kind.warm(self)
+        else:
+            await getattr(self, f"_warm_{self.mix['kind']}")()
 
     async def window(self, seconds: float, on_start=None) -> Window:
+        if self.kind is not None:
+            return await self.kind.window(self, float(seconds), on_start)
         return await getattr(self, f"_window_{self.mix['kind']}")(
             float(seconds), on_start)
 
@@ -150,8 +179,7 @@ class Generator:
         while w < int(self.mix.get("warm_waves", 1)) or (
                 need and more_chunks_needed()):
             names = [f"warm{w}-{i}" for i in range(size)]
-            await self._create_wave(names, None)
-            left = await self._settle(names)
+            left = await self.settle(await self.create_wave("warm", names))
             if left:
                 raise RuntimeError(f"warm-up wave {w}: {left} pods unbound")
             w += 1
@@ -160,8 +188,7 @@ class Generator:
         # than a full chunk does. Bursts of such sizes mint them here.
         for size in self.mix.get("warm_bursts", []):
             names = [f"burst{size}-{i}" for i in range(int(size))]
-            await self._create_wave(names, None)
-            left = await self._settle(names)
+            left = await self.settle(await self.create_wave("burst", names))
             if left:
                 raise RuntimeError(f"warm-up burst {size}: {left} unbound")
 
@@ -182,18 +209,18 @@ class Generator:
             snap0 = counters.snapshot(registry) if line_names else {}
             t0 = time.monotonic()
             names = [f"{tag}-w{k}-{i}" for i in range(size)]
-            sent = await self._create_wave(names, ack, stop_at)
+            sent = await self.create_wave("measured", names, ack, stop_at)
             t1 = time.monotonic()
             win.spans.append(("bench.create", t0, t1))
-            unbound = await self._settle(sent)
-            t2 = self._last_bound(sent, t1)
+            unbound = await self.settle(sent)
+            t2 = self.last_bound(sent, t1)
             win.spans.append(("bench.wait_bound", t1, time.monotonic()))
-            win.created += [pod_key(n, self.pod_template) for n in sent]
+            win.created += sent
             win.unbound += unbound
             if k == 0:
                 # packing is judged on the cluster as the first wave left
                 # it: the same pods in every run, whatever the speed
-                win.packing_keys = list(self.all_created)
+                win.packing_upto = len(self.all_created)
             wave = {"pods": len(sent) - unbound, "seconds": t2 - t0,
                     "create_seconds": t1 - t0}
             if line_names:
@@ -227,27 +254,30 @@ class Generator:
         random.Random(stable_seed("order", seed)).shuffle(gaps)
         return gaps
 
-    async def _send_open_loop(self, prefix: str, gaps: list[float],
-                              t0: float, until=None):
+    async def send_open_loop(self, phase: str, prefix: str,
+                             gaps: list[float], t0: float, until=None):
         """Send one create per gap on the absolute clock from t0. Returns
-        (names, due times, lateness ms, ack ms, create tasks). `until`
-        ends the sending early (warm-up)."""
-        names: list[str] = []
+        (keys, due times, lateness ms, ack ms). `until` ends the sending
+        early (warm-up)."""
+        names = [f"{prefix}-{i}" for i in range(len(gaps))]
+        specs = self.model.pods(phase, names)
+        keys: list[str] = []
         due: list[float] = []
         late_ms: list[float] = []
         ack_ms: list[float] = []
         tasks: set[asyncio.Task] = set()
         errors: list[BaseException] = []
 
-        async def create(name: str) -> None:
+        async def create(i: int) -> None:
             t = time.monotonic()
+            pod = self._make_pod(names[i], **copy.deepcopy(specs[i]))
             try:
-                await self.cluster.client.create("pods", self._make_pod(
-                    name, **copy.deepcopy(self.pod_template)))
+                await self.cluster.client.create("pods", pod)
             except Exception as e:  # counted: the pod was not acknowledged
                 errors.append(e)
                 return
             ack_ms.append(1e3 * (time.monotonic() - t))
+            keys[i] = pod_key(pod)
 
         offset = 0.0
         for i, gap in enumerate(gaps):
@@ -257,10 +287,10 @@ class Generator:
                 await asyncio.sleep(delay)
             if until is not None and until():
                 break
-            names.append(f"{prefix}-{i}")
+            keys.append("")
             due.append(t0 + offset)
             late_ms.append(1e3 * max(0.0, time.monotonic() - (t0 + offset)))
-            task = asyncio.ensure_future(create(names[-1]))
+            task = asyncio.ensure_future(create(i))
             tasks.add(task)
             task.add_done_callback(tasks.discard)
         if tasks:
@@ -268,8 +298,9 @@ class Generator:
         if errors:
             raise RuntimeError(
                 f"{len(errors)} creates failed, first: {errors[0]!r}")
-        self.all_created += [pod_key(n, self.pod_template) for n in names]
-        return names, due, late_ms, ack_ms
+        self.all_created += keys
+        self.all_specs += specs[:len(keys)]
+        return keys, due, late_ms, ack_ms
 
     async def _warm_open_loop(self) -> None:
         quiet = float(self.mix.get("warm_quiet_seconds", 4.0))
@@ -283,8 +314,9 @@ class Generator:
                 return now - t0 >= quiet
             return now - max(log.last_event(), t0) >= quiet
         gaps = self._gaps(cap, stable_seed("warm", self.seed))
-        names, *_ = await self._send_open_loop("warm", gaps, t0, until=done)
-        left = await self._settle(names)
+        keys, *_ = await self.send_open_loop(
+            "warm", "warm", gaps, t0, until=done)
+        left = await self.settle(keys)
         if left:
             raise RuntimeError(f"warm-up arrivals: {left} pods unbound")
 
@@ -294,25 +326,25 @@ class Generator:
         if on_start is not None:
             await on_start()
         win.start = time.monotonic()
-        names, due, late_ms, ack_ms = await self._send_open_loop(
-            f"s{self.seed:x}", gaps, win.start)
+        keys, due, late_ms, ack_ms = await self.send_open_loop(
+            "measured", f"s{self.seed:x}", gaps, win.start)
         t1 = time.monotonic()
         win.spans.append(("bench.arrival", win.start, t1))
-        win.unbound = await self._settle(names)
+        win.unbound = await self.settle(keys)
         win.spans.append(("bench.wait_bound", t1, time.monotonic()))
-        win.created = [pod_key(n, self.pod_template) for n in names]
-        win.end = self._last_bound(names, t1)
+        win.created = keys
+        win.end = self.last_bound(keys, t1)
         at = self.cluster.bound_at
         # a pod that never bound lies beyond every percentile
         latency = [1e3 * (at[k] - d) if k in at else float("inf")
                    for k, d in zip(win.created, due)]
         win.series.update(latency_ms=latency, gen_late_ms=late_ms,
                           create_ack_ms=ack_ms)
-        win.packing_keys = list(self.all_created)
+        win.packing_upto = len(self.all_created)
         p = percentiles(latency, (0.5, 0.95))
         win.quantities["latency_p50_ms"] = p[0.5]
         win.quantities["latency_p95_ms"] = p[0.95]
         span = win.end - win.start
         win.quantities["bound_per_s"] = \
-            (len(names) - win.unbound) / span if span > 0 else 0.0
+            (len(keys) - win.unbound) / span if span > 0 else 0.0
         return win

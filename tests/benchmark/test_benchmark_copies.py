@@ -99,7 +99,9 @@ def test_fragmentation_copy_equals_the_programs(seed):
     sched = _Sched()
     sched.cache = cache
     theirs = PerfRunner._fragmentation_occupied(sched)
-    ours = ClusterModel(config).fragmentation(np.array(placed))
+    model = ClusterModel(config)
+    ours = model.fragmentation(np.array(placed), model.request_rows(
+        [config["pod_template"]] * len(placed)))
     assert ours == pytest.approx(theirs, rel=1e-12)
     assert 0.0 < ours < 100.0
 
@@ -171,8 +173,8 @@ def test_roofline_reads_the_same_work_whatever_program_shapes_say():
     class Ctx:
         traced_pods = 10000
         device_kind = "TPU v5 lite"
-        config = {"nodes": 5000, "node_template": {"allocatable": {
-            "cpu": "8", "memory": "32Gi", "pods": "110"}}}
+        model = ClusterModel({"nodes": 5000, "node_template": {
+            "allocatable": {"cpu": "8", "memory": "32Gi", "pods": "110"}}})
     a, b = Ctx(), Ctx()
     a.trace = {"programs": {"jit__mask_solve_update":
                             {"seconds": 0.2, "runs": 10}}}
@@ -228,6 +230,23 @@ def test_recorded_trace_reduces_to_busy_idle_programs_and_gaps(trace):
 def test_a_window_with_no_device_operation_reduces_to_nothing(trace):
     assert trace_reduce.reduce(trace, [], window=(0.0, 0.001)) is None
     assert trace_reduce.reduce(trace_reduce.Trace(), []) is None
+
+
+def test_a_chip_the_cell_did_not_use_is_not_averaged_in(trace):
+    """A one-chip cell on a four-chip host: the other devices have
+    planes, and nothing on them."""
+    lo = trace.marker("bench.marker")
+    hi = max(e for _, _, e in trace.host)
+    alone = trace_reduce.reduce(trace, [], window=(lo, hi))
+    for chip in (1, 2, 3):
+        trace.ops[chip], trace.modules[chip] = [], []
+    try:
+        beside = trace_reduce.reduce(trace, [], window=(lo, hi))
+    finally:
+        for chip in (1, 2, 3):
+            del trace.ops[chip], trace.modules[chip]
+    assert beside["chips"] == 1 and beside["busy_s"] == alone["busy_s"]
+    assert beside["programs"] == alone["programs"]
 
 
 def test_gaps_go_to_the_innermost_covering_span():

@@ -1,5 +1,6 @@
 """BENCHMARK.json keeps to the contract's shape, and every name in it
-resolves to the files the harness looks for."""
+resolves to the files the harness looks for: data, and the code a
+configuration's deployment and a mix's kind name."""
 
 import json
 import re
@@ -12,6 +13,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from benchmark.lib.manifest import Manifest  # noqa: E402
+from benchmark.lib.traffic import Generator  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -99,9 +101,12 @@ def test_every_config_has_a_cell_and_a_file_of_its_own(doc, manifest):
         assert cfg["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
         for key in c["reduced"]:
             assert NAME.match(key) and key in cfg
-        for key in ("nodes", "init_pods", "wave_pods", "node_template",
-                    "pod_template", "guarantees", "assumed"):
+        for key in ("init_pods", "guarantees", "assumed"):
             assert key in cfg, (c["name"], key)
+        # the rest is what its deployment needs: the default one, which a
+        # configuration that names none gets, reads these three
+        if "deployment" not in cfg:
+            assert {"nodes", "node_template", "pod_template"} <= set(cfg)
     pairs = [(w["config"], w["traffic"]) for w in doc["workloads"]]
     assert len(pairs) == len(set(pairs))
     four = sum(1 for w in doc["workloads"] if w["chips"] == 4)
@@ -110,9 +115,15 @@ def test_every_config_has_a_cell_and_a_file_of_its_own(doc, manifest):
 
 def test_every_cell_resolves_to_its_files(doc, manifest):
     for w in doc["workloads"]:
-        assert manifest.config(w)["nodes"] > 0
+        model = manifest.deployment(manifest.config(w))
+        assert model.n_nodes == len(model.nodes()) > 0
+        assert all("allocatable" in kw for _, kw in model.nodes()[:3])
+        for phase in ("init", "warm", "burst", "measured"):
+            specs = model.pods(phase, ["a", "b"])
+            assert len(specs) == 2 and all("requests" in kw for kw in specs)
         mix = manifest.traffic(w)
-        assert mix["kind"] in ("closed_waves", "open_loop")
+        assert Generator.defines(mix["kind"]) \
+            or callable(manifest.kind(mix["kind"]).window)
         e2e = [m["name"] for m in manifest.end_to_end(w)]
         assert "setup_s" in e2e and len(e2e) >= 2
         # every end-to-end metric of the cell is a quantity of its mix
@@ -139,6 +150,19 @@ def test_every_per_layer_metric_has_a_file_and_a_reader(doc, manifest):
     # a layer is spelled one way
     layers = {m["layer"] for m in doc["per_layer"]}
     assert len({layer.lower() for layer in layers}) == len(layers)
+
+
+@pytest.mark.parametrize("config,nodes", [
+    ("sched-perf-5k", 5000), ("kwok-50k", 50000)])
+def test_the_problem_a_reader_asks_for_is_what_the_parent_read(
+        manifest, config, nodes):
+    """readers/roofline.py took the nodes from the configuration, counted
+    the keys of `node_template.allocatable` (the pod count is a plane)
+    and one class: the deployment states the same problem."""
+    cfg = manifest.config({"config": config})
+    assert manifest.deployment(cfg).problem() == {
+        "nodes": nodes, "resources": len(cfg["node_template"]["allocatable"]),
+        "classes": 1}
 
 
 def test_rooflines_are_named_and_united_as_shares(doc):
