@@ -540,11 +540,16 @@ class SchedulerMetrics:
         #: kind="host_fallback" (pod took a per-pod host plugin row),
         #: kind="host_path" (a scheduler WITH a backend placed the pod
         #: through the plugin-by-plugin host path),
+        #: kind="lone_batch" (a pod popped alone that the single-pod
+        #: fast path did not take paid a batch solve of one),
         #: kind="gang_overflow" (gangs beyond the solver's capacity
         #: degrade to Permit-barrier-only atomicity).
         self.backend_degradations = r.counter(
             "scheduler_tpu_backend_degradations_total",
             "TPU backend fallbacks to degraded modes", labels=("kind",))
+        # steady-state zero, and read as a number by whoever watches it:
+        # the series exists from the start, not from the first miss.
+        self.backend_degradations.inc(0, kind="spread_poisoned")
         #: Solve-side observability (the r8 50k profile's blind spot: the
         #: device solve runs in XLA's compute threads, invisible to a
         #: main-thread sampler). Per-chunk wall of the fused solve as the

@@ -1912,9 +1912,17 @@ class TPUBackend:
             sp_plugin = next((p for p in fwk.filter_plugins
                               if p.NAME == "PodTopologySpread"), None)
             if sp_plugin is not None:
-                self._build_spread_table(
-                    ctx, snapshot, ct,
-                    self._affinity_compiler(snapshot, ct), sp_plugin)
+                with self._span("solver.spread_table"):
+                    self._build_spread_table(
+                        ctx, snapshot, ct,
+                        self._affinity_compiler(snapshot, ct), sp_plugin)
+                    if self.tracer is not None:
+                        sp = ctx.spread
+                        self.tracer.annotate(
+                            templates=len(sp["tpl_cols"])
+                            + len(sp["static_rows"]),
+                            constraints=len(sp["cons"]),
+                            domains=len(sp.get("cid_onehot_host", ())))
                 # Last chunk with scan-GATED pods: contribute-only chunks
                 # after it can keep the multistart solver (their counts
                 # no longer influence any gating decision).

@@ -483,8 +483,13 @@ class Scheduler:
         # Extenders are per-pod HTTP webhooks whose round-trips dominate any
         # batch win, and their filter verdicts must precede assignment — so
         # configured extenders route pods through the (extender-aware) host
-        # path, exactly the reference's control flow.
-        if self.backend is not None and len(pods) > 1 and not self.extenders:
+        # path, exactly the reference's control flow. A dispatch of ONE
+        # pod rides the backend like any other batch: the serving tier
+        # has offered a plain lone pod to the single-pod fast path before
+        # it gets here, and what that declined (a constrained pod, a pod
+        # nothing fitted there) is placed by a batch of one — never
+        # plugin by plugin because it happened to be popped alone.
+        if self.backend is not None and not self.extenders:
             # Pods are batched per profile: each batch runs under its own
             # plugin set/weights (profiles are keyed by schedulerName), and
             # the TPUScorer gate selects the backend PER PROFILE
@@ -545,6 +550,9 @@ class Scheduler:
             for sname, group in by_profile.items():
                 if self.backend_profiles is None or \
                         sname in self.backend_profiles:
+                    if len(pods) == 1:
+                        self.metrics.backend_degradations.inc(
+                            kind="lone_batch")
                     await self._schedule_via_backend(group, snapshot)
                     tr.step(f"backend assign [{sname}] ({len(group)} pods)")
                     snapshot = self._snapshot()
@@ -797,11 +805,10 @@ class Scheduler:
     async def _schedule_host_path(self, pi: PodInfo, snapshot) -> None:
         if self.backend is not None:
             # A scheduler that HAS a device backend is placing this pod
-            # plugin by plugin: a one-pod dispatch the fast path
-            # declined, a profile outside backend_profiles, configured
-            # extenders, or the batch after a backend failure. By
-            # design — and counted, so a device run can show that no
-            # pod took it.
+            # plugin by plugin: a profile outside backend_profiles,
+            # configured extenders, or the batch after a backend
+            # failure. By design — and counted, so a device run can
+            # show that no pod took it.
             self.metrics.backend_degradations.inc(kind="host_path")
         fwk = self.profiles.get(pi.scheduler_name)
         if fwk is None:

@@ -30,7 +30,7 @@ cooperating layers:
 
 `KTPU_SERVING=0` is the kill switch: the scheduler's run loop degrades
 STRUCTURALLY to the pre-serving shape (plain schedule_batch, full
-used-state uploads, lone pods on the host path).
+used-state uploads, a lone pod as a batch of one).
 """
 
 from __future__ import annotations

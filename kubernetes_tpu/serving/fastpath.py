@@ -33,7 +33,8 @@ placement is representable in the resident planes —
   relative and belongs to the chunk prep;
 - no gang membership (Coscheduling atomicity needs the batch solver).
 
-Anything else falls through to the normal path (batch or host), which
+Anything else falls through to the batch path (a lone pod as a batch
+of one; the host path only where the backend cannot take the pod), which
 also owns diagnostics/preemption for no-fit pods — the fast path only
 takes the happy path, and a host verify (exact integer re-check)
 backstops the quantized device fit exactly like the batch verify.

@@ -160,12 +160,15 @@ class ServingTier:
                     return True
             elif len(pods) == 1 and await self._try_fast_path(pods[0]):
                 # The gates above choose between a serial drain and the
-                # batch pipeline, but a ONE-pod dispatch has no batch
-                # pipeline to fall to: Scheduler._schedule_pods places a
-                # lone pod plugin by plugin on the host, O(N·plugins) of
-                # Python against one device solve. On the chip at 250/s
-                # that silently put 164 of 4,986 trickle pods on the
-                # host scheduler (PR 21, chip_smoke.py).
+                # batch pipeline, but a ONE-pod dispatch has nothing to
+                # pipeline: the batch path would pay a whole padded
+                # chunk for it, so the single-pod solve comes first.
+                # What it declines (a constrained pod, a pod nothing
+                # fitted there) falls to the batch path below as a
+                # batch of one (backend_degradations{kind="lone_batch"})
+                # — never to the plugin-by-plugin host scheduler, which
+                # on the chip at 250/s once silently placed 164 of
+                # 4,986 trickle pods (PR 21, chip_smoke.py).
                 return True
         await self._schedule_batch_timed(pods)
         return True

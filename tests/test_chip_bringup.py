@@ -173,9 +173,11 @@ class TestDeviceLossIsVisible:
             assert await wait_for(bound, timeout=10.0)
             m = sched.metrics
             assert m.serving_fast_path_failures.value() >= 2  # warm + solve
-            # ...and the lone pod was placed plugin by plugin, which a
-            # scheduler with a backend now also counts.
-            assert m.backend_degradations.value(kind="host_path") == 1
+            # ...and the lone pod rode the batch path as a batch of
+            # one: a scheduler with a backend places no pod plugin by
+            # plugin because it was popped alone.
+            assert m.backend_degradations.value(kind="lone_batch") == 1
+            assert m.backend_degradations.value(kind="host_path") == 0
             await sched.stop()
             task.cancel()
             factory.stop()

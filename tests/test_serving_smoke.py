@@ -32,6 +32,8 @@ from kubernetes_tpu.serving.resident import ResidentPlanes
 from kubernetes_tpu.utils import locking
 from test_tpu_backend import default_fwk
 
+ZONE_LABEL = "topology.kubernetes.io/zone"
+
 
 @pytest.fixture(autouse=True)
 def _lock_check(monkeypatch):
@@ -373,17 +375,23 @@ class TestServingE2E:
         assert a_on == a_off
 
 
+def _outside_backend_profiles(sched):
+    sched.backend_profiles = {"some-other-scheduler"}
+
+
 class TestLonePodDispatch:
-    """A one-pod dispatch has no batch pipeline to fall to
-    (Scheduler._schedule_pods places a lone pod plugin by plugin on the
-    host), so when the cap/rate gates decline the serial drain it still
-    tries the fast path first; only a pod the fast path cannot take
-    reaches the host scheduler — and is counted there."""
+    """A one-pod dispatch has nothing to pipeline, so when the cap/rate
+    gates decline the serial drain it still tries the fast path first;
+    a pod the fast path cannot take rides the batch path as a batch of
+    one (counted: backend_degradations{kind="lone_batch"}); only a
+    shape the backend cannot take reaches the plugin-by-plugin host
+    scheduler — and is counted there."""
 
     @staticmethod
-    async def _lone_pods(pods):
+    async def _lone_pods(pods, configure=None):
         """Create `pods` one at a time, each bound before the next is
-        created, so every dispatch carries exactly one pod."""
+        created, so every dispatch carries exactly one pod. The nodes
+        lie in three zones; `configure(sched)` runs before the loop."""
         from conftest import start_scheduler
         from kubernetes_tpu.store import install_core_validation, \
             new_cluster_store
@@ -391,10 +399,13 @@ class TestLonePodDispatch:
         install_core_validation(store)
         for i in range(6):
             await store.create("nodes", make_node(
-                f"n{i}", allocatable={"cpu": "4", "memory": "16Gi",
-                                      "pods": "32"}))
+                f"n{i}", labels={ZONE_LABEL: f"z{i % 3}"},
+                allocatable={"cpu": "4", "memory": "16Gi",
+                             "pods": "32"}))
         sched, factory = await start_scheduler(
             store, backend=TPUBackend(max_batch=16, mesh=None))
+        if configure is not None:
+            configure(sched)
         run = asyncio.ensure_future(sched.run(batch_size=64))
         try:
             for pod in pods:
@@ -437,11 +448,32 @@ class TestLonePodDispatch:
             "backend_fallback_total": 0, "fast_path_failures_total": 0,
             "host_path_pods": 0}
 
-    def test_lone_ineligible_pod_reaches_the_host_path_and_is_counted(
-            self, gates_decline):
-        pods = [make_pod("ports", uid="u-port", host_ports=[8080],
-                         requests={"cpu": "100m"})]
-        m = asyncio.run(self._lone_pods(pods))
-        assert m.serving_fast_path_pods.value() == 0
-        assert m.backend_degradations.value(kind="host_path") == 1
+    @pytest.mark.parametrize("pod_kw, configure, fast, lone_batch, host", [
+        # a plain lone pod: the fast path, as before
+        pytest.param({}, None, 1, 0, 0, id="plain-fast-path"),
+        # what the fast path declines rides the backend as a batch of one
+        pytest.param({"host_ports": [8080]}, None, 0, 1, 0,
+                     id="host-port-batch-of-one"),
+        pytest.param(
+            {"labels": {"color": "blue"},
+             "topology_spread_constraints": [{
+                 "maxSkew": 1, "topologyKey": ZONE_LABEL,
+                 "whenUnsatisfiable": "DoNotSchedule",
+                 "labelSelector": {"matchLabels": {"color": "blue"}}}]},
+            None, 0, 1, 0, id="zone-spread-batch-of-one"),
+        # a shape the backend cannot take: its profile is not the
+        # backend's to serve
+        pytest.param({}, _outside_backend_profiles, 0, 0, 1,
+                     id="profile-outside-backend-host-path"),
+    ])
+    def test_lone_pod_takes_the_fast_path_then_a_batch_of_one(
+            self, gates_decline, pod_kw, configure, fast, lone_batch, host):
+        pods = [make_pod("lone", uid="u-lone",
+                         requests={"cpu": "100m"}, **pod_kw)]
+        m = asyncio.run(self._lone_pods(pods, configure))
+        assert m.serving_fast_path_pods.value() == fast
+        assert m.backend_degradations.value(kind="lone_batch") == lone_batch
+        assert m.backend_degradations.value(kind="host_path") == host
+        assert m.solve_duration.count() == lone_batch  # one chunk, or none
+        assert m.backend_degradations.value(kind="spread_poisoned") == 0
         assert m.serving_fast_path_failures.value() == 0
