@@ -664,6 +664,28 @@ class SchedulerMetrics:
             "topology_plane_rebuilds_total",
             "Rebuilds of the tensorized interconnect coordinate planes "
             "(mesh flags or node set moved; reuse does not count)")
+        #: How the backend's affinity compiler (label-signature counts
+        #: behind InterPodAffinity rows and the spread table) reached a
+        #: new snapshot: kind="delta" recounted the changed nodes' rows,
+        #: kind="full" walked every resident pod (first build, node set
+        #: or a node object changed, namespace relabel, no changed-log).
+        self.affinity_compiler_builds = r.counter(
+            "scheduler_tpu_affinity_compiler_builds_total",
+            "Affinity compiler builds by kind (full walk / delta advance)",
+            labels=("kind",))
+        self.affinity_rows_recounted = r.counter(
+            "scheduler_tpu_affinity_rows_recounted_total",
+            "Node rows of the label-signature table counted by those "
+            "builds (a full build counts every node)")
+        #: The spread table's part that reads nodes and templates only
+        #: (domain planes, device copies): planes="kept" reused the last
+        #: build's and took the domain counts alone, planes="built" made
+        #: and uploaded them (first table, other templates, or a compiler
+        #: built anew).
+        self.spread_table_builds = r.counter(
+            "scheduler_tpu_spread_table_builds_total",
+            "Spread table builds by what became of the node planes",
+            labels=("planes",))
         #: Sharded-control-plane observability (ROADMAP #5): per-shard
         #: host-prep rebuild counts (a shard increments only when its
         #: rows were actually rewritten — the incremental path's

@@ -48,7 +48,15 @@ def _seg_sum(values: np.ndarray, ids: np.ndarray, num: int) -> np.ndarray:
 
 
 class AffinityCompiler:
-    """Per-snapshot compiled state for batched affinity filtering.
+    """Compiled state for batched affinity filtering, kept ACROSS snapshots.
+
+    Built by one walk over the snapshot's resident pods, then `advance`d
+    from generation to generation by the scheduler cache's delta handles
+    (`set_epoch`, `spec_seq`, `changed_since` — the rule and the fall-back
+    of tensorize.ClusterTensors._init_delta): only the rows of the nodes
+    that changed are recounted, what reads node labels alone is kept, and
+    what was derived from pod counts is dropped. An advanced compiler
+    answers exactly as one built anew on the same snapshot would.
 
     `ns_resolver` (plugins.interpodaffinity.NamespaceResolver) resolves
     namespaceSelector terms against live Namespace labels; without one
@@ -58,17 +66,53 @@ class AffinityCompiler:
 
     def __init__(self, snapshot: Snapshot, n_pad: int, ns_resolver=None):
         self.ns_resolver = ns_resolver
-        self.snapshot = snapshot
+        #: the resolver's epoch the resolved namespace sets belong to
+        self.ns_epoch = self._resolver_epoch()
         self.n_pad = n_pad
         self.n_real = len(snapshot.nodes)
         self.sigs = LabelSigTable(snapshot, n_pad)
         self.topo = TopologyTable(snapshot.nodes, n_pad)
+        #: per-term-signature compiled masks; the "elig/" rows read node
+        #: labels and taints alone and survive an advance
+        self._mask_cache: dict[str, np.ndarray] = {}
+        self._node_pos: dict[str, int] | None = None
+        self._point_at(snapshot)
+        self._derive()
+
+    def _resolver_epoch(self) -> int:
+        return self.ns_resolver.epoch if self.ns_resolver is not None else -1
+
+    def _point_at(self, snapshot: Snapshot) -> None:
+        self.snapshot = snapshot
+        self.generation = snapshot.generation
+        self.set_epoch = snapshot.set_epoch
+        self.spec_seq = snapshot.spec_seq
+        self.topo.nodes = snapshot.nodes
+
+    def release(self) -> None:
+        """Let go of the snapshot pointed at, once the cache has moved on
+        (its replaced node clones would live as long as this compiler
+        waits for the next pod that needs it). The counts stay; `advance`
+        points at the next snapshot, and nothing is answered until then."""
+        self.snapshot = None
+        self.topo.nodes = ()
+
+    def at(self, snapshot: Snapshot) -> bool:
+        """Whether this is the compiler of `snapshot`, ready to answer."""
+        return self.snapshot is not None \
+            and self.generation == snapshot.generation \
+            and self.ns_epoch == self._resolver_epoch()
+
+    def _derive(self) -> None:
+        """From the snapshot pointed at, whose pod counts `self.sigs`
+        holds: rebuild the carriers of resident pods' own terms and drop
+        every cache derived from pod counts."""
+        snapshot, n_pad = self.snapshot, self.n_pad
         # Resident pods' required anti-affinity terms (symmetry source):
         # term signature -> (carrier-count vector over nodes, term, owner_ns).
         self.resident_anti: dict[str, tuple[np.ndarray, dict, str]] = {}
-        for n, ni in enumerate(snapshot.nodes):
-            if not ni.pods_with_required_anti_affinity:
-                continue
+        for ni in snapshot.have_pods_with_required_anti_affinity:
+            n = self._pos(ni)
             for pi in ni.pods_with_required_anti_affinity:
                 for term in pi.required_anti_affinity_terms:
                     key = repr((term, pi.namespace))
@@ -94,7 +138,8 @@ class AffinityCompiler:
                     np.zeros((n_pad,), dtype=np.float32), term, ns, is_hard)
             got[0][n] += w
 
-        for n, ni in enumerate(snapshot.nodes):
+        for ni in snapshot.have_pods_with_affinity:
+            n = self._pos(ni)
             for pi in ni.pods_with_affinity:
                 for t in pi.preferred_affinity_terms:
                     _carrier(t.get("podAffinityTerm") or {}, pi.namespace,
@@ -109,8 +154,8 @@ class AffinityCompiler:
         self._sym_match_cache: dict[tuple, bool] = {}
         #: per-(term,ns) per-node matching-count cache
         self._count_cache: dict[str, np.ndarray] = {}
-        #: per-term-signature compiled masks
-        self._mask_cache: dict[str, np.ndarray] = {}
+        self._mask_cache = {k: v for k, v in self._mask_cache.items()
+                            if k.startswith("elig/")}
         #: full-row caches keyed by pod CONTENT signature (namespace,
         #: labels, term list): template-stamped batches share one row —
         #: the per-pod O(N) row assembly was the 5k families' top host
@@ -122,6 +167,37 @@ class AffinityCompiler:
         #: plane class (ops/backend._prep_chunk).
         self._filter_row_cache: dict[tuple, np.ndarray] = {}
         self._score_row_cache: dict[tuple, np.ndarray] = {}
+
+    def _pos(self, ni) -> int:
+        """Snapshot position of a node that carries affinity terms (the
+        name map is made on first need: no cell's pods carry any)."""
+        if self._node_pos is None:
+            self._node_pos = {
+                n.name: i for i, n in enumerate(self.snapshot.nodes)}
+        return self._node_pos[ni.name]
+
+    def advance(self, snapshot: Snapshot, n_pad: int) -> int | None:
+        """Move to a later `snapshot` of the same node set by recounting
+        the rows its changed-node log names; returns how many. None = the
+        handles do not vouch for it (no handles, node set or a node object
+        changed, a namespace relabelled, log too short, node count
+        differs) and the caller builds anew."""
+        if self.set_epoch < 0 or snapshot.set_epoch != self.set_epoch \
+                or snapshot.spec_seq != self.spec_seq \
+                or n_pad != self.n_pad \
+                or len(snapshot.nodes) != self.n_real \
+                or snapshot.generation < self.generation \
+                or snapshot.changed_since is None \
+                or self.ns_epoch != self._resolver_epoch():
+            return None
+        changed = snapshot.changed_since(self.generation)
+        if changed is None:
+            return None
+        self._point_at(snapshot)
+        if changed:
+            self.sigs.recount(snapshot.nodes, changed)
+            self._derive()
+        return len(changed)
 
     # -- primitives --------------------------------------------------------
 

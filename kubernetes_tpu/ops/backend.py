@@ -1127,6 +1127,13 @@ class TPUBackend:
         # (snapshot generation, snapshot identity) — see _nrt_state.
         self._nrt_cache: tuple | None = None
         self._dra_cache: tuple | None = None
+        #: ops/affinity.AffinityCompiler, kept across generations (see
+        #: _affinity_compiler).
+        self._affinity = None
+        #: (compiler, template keys, planes) of the spread table's latest
+        #: build: everything in it that reads nodes and templates only,
+        #: device copies included (see _build_spread_table).
+        self._spread_planes: tuple | None = None
         # Fixed-shape placeholder device arrays for the fused program's
         # spread slots when use_spread=False (stable jit signature).
         self._spread_dummy_cache: dict[tuple, tuple] = {}
@@ -1157,7 +1164,12 @@ class TPUBackend:
             self._ct = ClusterTensors(
                 snapshot, resources=self._pinned_resources, prev=self._ct,
                 shards=self.control_shards)
-            self._affinity = None  # resident pods changed → recompile
+            # The affinity compiler is NOT dropped with the tensors: it
+            # is brought to the snapshot where a pod first needs it
+            # (_affinity_compiler), by the same delta handles. What goes
+            # here is its hold on the snapshot the cache has left behind.
+            if self._affinity is not None:
+                self._affinity.release()
             # Per-shard host-prep accounting (ROADMAP #5): only shards
             # whose rows this build rewrote count a rebuild — the
             # incremental delta path's observable witness.
@@ -1173,17 +1185,29 @@ class TPUBackend:
         return self._ct
 
     def _affinity_compiler(self, snapshot: Snapshot, ct: ClusterTensors):
+        """The affinity compiler AT `snapshot`. One compiler serves every
+        generation of a node set: it advances by the snapshot's
+        changed-node log (AffinityCompiler.advance) and is built anew —
+        one walk over every resident pod — only when the handles do not
+        vouch for that, or the namespace resolver moved. The registry says
+        which each build was, and how many node rows it counted."""
         resolver = getattr(self, "_ns_resolver", None)
-        epoch = resolver.epoch if resolver is not None else -1
-        cached = getattr(self, "_affinity", None)
-        if cached is not None and \
-                getattr(self, "_affinity_ns_epoch", -1) != epoch:
-            cached = None  # namespace relabel: resolved sets are stale
-        if cached is None:
+        cached = self._affinity
+        if cached is not None and cached.ns_resolver is not resolver:
+            cached = None  # another profile's resolver: other namespace sets
+        if cached is not None and cached.at(snapshot):
+            return cached
+        recounted = cached.advance(snapshot, ct.n_pad) \
+            if cached is not None else None
+        kind = "delta"
+        if recounted is None:
             from kubernetes_tpu.ops.affinity import AffinityCompiler
             cached = self._affinity = AffinityCompiler(
                 snapshot, ct.n_pad, ns_resolver=resolver)
-            self._affinity_ns_epoch = epoch
+            kind, recounted = "full", ct.n_real
+        if self.metrics is not None:
+            self.metrics.affinity_compiler_builds.inc(kind=kind)
+            self.metrics.affinity_rows_recounted.inc(recounted)
         return cached
 
     # -- NodeResourceTopologyMatch vectorization (BASELINE config #4) -----
@@ -1420,15 +1444,35 @@ class TPUBackend:
         the template's domain columns, and non-self-matching selectors
         ride the per-pod selfMatch term (`contributes`). Templates whose
         constraints have NO domains anywhere get a static row (reject
-        keyless nodes, fresh-pass the rest) instead of host fallback."""
+        keyless nodes, fresh-pass the rest) instead of host fallback.
+
+        What reads nodes and templates only (_spread_planes_for) is kept
+        with the compiler and reused while the same templates come in the
+        same order; the domain counts alone are taken per assign(), from
+        the compiler's counts at this snapshot, and uploaded."""
         from kubernetes_tpu.api.labels import from_label_selector
-        from kubernetes_tpu.ops.affinity import _seg_sum
 
         templates: dict[str, dict] = {}
+        # A template's thousand pods are a thousand equal objects, not
+        # one: what the template key reads is compared with the pod
+        # before (stamped batches come in runs), else looked up by one
+        # repr; the key's own sorts and reprs run once per distinct content.
+        seen: set[str] = set()
+        last = None
         for chunk in ctx.chunks:
             for pj in chunk:
                 if not pj.topology_spread_constraints:
                     continue
+                fields = (pj.topology_spread_constraints, pj.namespace,
+                          pj.node_selector, pj.affinity.get("nodeAffinity"),
+                          pj.tolerations)
+                if fields == last:
+                    continue
+                last = fields
+                raw = repr(fields)
+                if raw in seen:
+                    continue
+                seen.add(raw)
                 cs = plugin._constraints_for(pj, "DoNotSchedule")
                 if not cs:
                     continue
@@ -1441,6 +1485,46 @@ class TPUBackend:
                             c.get("labelSelector")) for c in cs],
                     }
 
+        # A compiler that ADVANCED vouches for the node set, every node
+        # object, n_pad and the namespace resolver's epoch; one built anew
+        # is another object, and the planes go with it.
+        last = self._spread_planes
+        kept = last is not None and last[0] is compiler \
+            and last[1] == tuple(templates)
+        if kept:
+            planes = last[2]
+        else:
+            planes = self._spread_planes_for(templates, ct, compiler)
+            self._spread_planes = (compiler, tuple(templates), planes)
+        if self.metrics is not None:
+            self.metrics.spread_table_builds.inc(
+                planes="kept" if kept else "built")
+        # Per-assign state beside the shared planes: the chained domain
+        # counts, and what chunk prep memoizes.
+        sp = ctx.spread = dict(planes)
+        sp["ineligible"] = set()
+        if not sp["cons"]:
+            return
+        # A domain's count is its constraint's matching pods summed over
+        # the domain's column of dom_onehot (its eligible nodes): whole
+        # numbers in float32, exact in any order of summation.
+        dom_onehot = sp["dom_onehot_host"]
+        counts0 = np.concatenate([
+            compiler.counts_for(c.get("labelSelector"), ns)
+            @ dom_onehot[:, lo:hi]
+            for c, ns, (lo, hi) in zip(sp["cons"], sp["con_ns"],
+                                       sp["con_cols"])])
+        # The table is built in _start BEFORE any chunk dispatches, so
+        # ctx.delta is empty here by construction — every same-assign
+        # placement is counted by the scan itself (sp_contrib).
+        sp["dev_counts"] = self._put(counts0)
+
+    def _spread_planes_for(self, templates: dict, ct, compiler) -> dict:
+        """The spread table but for its counts: the union constraint list
+        and every host and device plane that reads node labels, node
+        eligibility and the templates alone. Shared between assign()
+        calls and never written after this returns; the device copies are
+        plain (non-donated) inputs of the fused program."""
         cons: list[dict] = []      # union constraint list
         con_ns: list[tuple] = []   # resolved namespace set per constraint
         con_sels: list = []
@@ -1473,51 +1557,43 @@ class TPUBackend:
                 con_elig.append(elig)
             tpl_cols[key] = cols
 
-        dom_slices = [compiler.topo.domains(c["topologyKey"])
-                      for c in cons]
         if not cons:
-            ctx.spread = {"cons": [], "tpl_cols": {},
-                          "static_rows": static_rows, "ineligible": set()}
-            return
+            return {"cons": [], "tpl_cols": {}, "static_rows": static_rows}
 
         N = ct.n_pad
         C = len(cons)
-        D = 0
-        for cidx, (dom_ids, num) in enumerate(dom_slices):
+        # Per constraint: its domain ids and the domains that exist over
+        # the nodes that count (keyed and eligible), in column order.
+        con_domains = []
+        for cidx, c in enumerate(cons):
+            dom_ids, _ = compiler.topo.domains(c["topologyKey"])
             active = (dom_ids > 0) & con_elig[cidx]
-            D += len(np.unique(dom_ids[active]))
+            con_domains.append((dom_ids, np.unique(dom_ids[active])))
+        D = sum(len(existing) for _, existing in con_domains)
         dom_onehot = np.zeros((N, D), dtype=np.float32)
         cid_onehot = np.zeros((D, C), dtype=np.float32)
-        counts0 = np.zeros((D,), dtype=np.float32)
         has_key_nc = np.zeros((N, C), dtype=np.float32)
         min_ok = np.ones((C,), dtype=np.float32)
+        con_cols: list[tuple[int, int]] = []  # a constraint's domain columns
         g = 0
-        for cidx, (dom_ids, num) in enumerate(dom_slices):
-            counts = compiler.counts_for(
-                cons[cidx].get("labelSelector"), con_ns[cidx])
+        for cidx, (dom_ids, existing) in enumerate(con_domains):
             elig = con_elig[cidx]
-            active = (dom_ids > 0) & elig
-            d = _seg_sum(np.where(active, counts, 0.0), dom_ids, num)
             has_key_nc[:, cidx] = (dom_ids > 0).astype(np.float32)
-            existing = np.unique(dom_ids[active])
             md = int(cons[cidx].get("minDomains") or 0)
             if md and len(existing) < md:
                 min_ok[cidx] = 0.0  # minDomains deficit → global min = 0
+            con_cols.append((g, g + len(existing)))
             for k in existing:
                 # Domain membership over ELIGIBLE nodes only: placements
                 # on keyed-but-ineligible nodes neither count nor gate.
                 dom_onehot[(dom_ids == k) & elig, g] = 1.0
                 cid_onehot[g, cidx] = 1.0
-                counts0[g] = d[k]
                 g += 1
-        # The table is built in _start BEFORE any chunk dispatches, so
-        # ctx.delta is empty here by construction — every same-assign
-        # placement is counted by the scan itself (sp_contrib).
-        ctx.spread = {
+        return {
             "cons": cons, "con_ns": con_ns, "con_sels": con_sels,
+            "con_cols": con_cols,
             "tpl_cols": tpl_cols,
             "static_rows": static_rows,
-            "ineligible": set(),
             "dom_onehot_host": dom_onehot,
             "cid_onehot_host": cid_onehot,
             "dev_dom": self._put(dom_onehot, "nodes_mat"),
@@ -1526,7 +1602,6 @@ class TPUBackend:
                 [float(c.get("maxSkew", 1)) for c in cons], np.float32)),
             "dev_min_ok": self._put(min_ok),
             "dev_haskey": self._put(has_key_nc, "nodes_mat"),
-            "dev_counts": self._put(counts0),
         }
 
     def _process_spread_pods(self, spread_pods, pods, ctx, snapshot, ct,
@@ -3131,7 +3206,11 @@ class TPUBackend:
         k's accepted placements.
         """
         snapshot, fwk, ct = ctx.snapshot, ctx.fwk, ctx.ct
-        compiler = getattr(self, "_affinity", None)
+        # The snapshot's own compiler or none: a kept one that no pod of
+        # this assign() brought to ctx.snapshot counts another generation.
+        compiler = self._affinity
+        if compiler is not None and not compiler.at(snapshot):
+            compiler = None
         assignments = ctx.assignments
         diagnostics = ctx.diagnostics
         working = ctx.working

@@ -32,27 +32,64 @@ def _sig(pi: PodInfo) -> tuple:
 
 
 class LabelSigTable:
-    """Unique (namespace, labels) signatures of resident pods + per-node
-    counts, split by pod population (all pods / pods with required
-    anti-affinity terms need separate counting)."""
+    """Unique (namespace, labels) signatures of resident pods and how many
+    pods of each sit on each node: `node_sig_count` (n_pad, U).
+
+    Built by one walk over every resident pod, then ADVANCED: `recount`
+    rewrites only the rows of the nodes a later snapshot names as changed
+    (the scheduler cache's changed-node log) by the pods that came to or
+    left each, so keeping the table current costs one signature per pod
+    that moved, not one per resident pod. A new signature appends a column
+    (capacity doubles, so appends amortise); a signature whose last pod
+    left keeps its all-zero column. Counts are small integers in float32
+    and matching sums them per selector, so neither column order nor zero
+    columns move `node_sig_count @ match_vec` by a bit against a freshly
+    built table."""
 
     def __init__(self, snapshot: Snapshot, n_pad: int):
         self.sigs: dict[tuple, int] = {}
         self.sig_examples: list[PodInfo] = []   # one pod per signature
-        rows = []
-        for ni in snapshot.nodes:
-            counts: dict[int, int] = {}
-            for pi in ni.pods:
-                u = self._intern(pi)
-                counts[u] = counts.get(u, 0) + 1
-            rows.append(counts)
-        U = max(1, len(self.sigs))
-        self.node_sig_count = np.zeros((n_pad, U), dtype=np.float32)
-        for n, counts in enumerate(rows):
-            for u, c in counts.items():
-                self.node_sig_count[n, u] = c
+        #: (n_pad, capacity ≥ U) backing store; `node_sig_count` is its
+        #: first U columns.
+        self._buf = np.zeros((n_pad, 4), dtype=np.float32)
+        #: per node, the pod list its row was last counted from (the
+        #: cache REPLACES a changed node's clone: a list counted once is
+        #: never written again)
+        self._counted: list[list[PodInfo]] = [[]] * len(snapshot.nodes)
         #: selector-signature -> (U,) match vector cache
         self._match_cache: dict[str, np.ndarray] = {}
+        self.recount(snapshot.nodes, range(len(snapshot.nodes)))
+
+    @property
+    def node_sig_count(self) -> np.ndarray:
+        return self._buf[:, :max(1, len(self.sig_examples))]
+
+    def recount(self, nodes: Sequence[NodeInfo], rows) -> None:
+        """Bring the rows of node indices `rows` up to `nodes[i].pods`: add
+        the pods that came since the row was last counted, take off the
+        ones that went (a pod is its PodInfo object: an update replaces
+        it, and counts as one of each)."""
+        at_n: list[int] = []
+        at_u: list[int] = []
+        by: list[float] = []
+        for n in rows:
+            pods = nodes[n].pods
+            seen = self._counted[n]
+            self._counted[n] = pods
+            k = len(seen)
+            if len(pods) >= k and pods[:k] == seen:
+                came, gone = pods[k:], ()   # nothing left: the new tail
+            else:
+                had, have = set(seen), set(pods)
+                came = [pi for pi in pods if pi not in had]
+                gone = [pi for pi in seen if pi not in have]
+            for moved, step in ((came, 1.0), (gone, -1.0)):
+                for pi in moved:
+                    at_n.append(n)
+                    at_u.append(self._intern(pi))
+                    by.append(step)
+        if at_n:
+            np.add.at(self._buf, (at_n, at_u), by)
 
     def _intern(self, pi: PodInfo) -> int:
         s = _sig(pi)
@@ -60,6 +97,12 @@ class LabelSigTable:
         if u is None:
             u = self.sigs[s] = len(self.sig_examples)
             self.sig_examples.append(pi)
+            if u >= self._buf.shape[1]:
+                grown = np.zeros((self._buf.shape[0], 2 * u),
+                                 dtype=np.float32)
+                grown[:, :u] = self._buf
+                self._buf = grown
+            self._match_cache.clear()  # cached vectors are one short
         return u
 
     def match_vec(self, label_selector: Mapping | None,
@@ -81,10 +124,12 @@ class LabelSigTable:
 
 
 class TopologyTable:
-    """Per-topology-key dense domain ids (lazily built, cached)."""
+    """Per-topology-key dense domain ids (lazily built, cached). They read
+    node labels alone, so they outlive every snapshot of one node set whose
+    node objects did not change; `nodes` is then pointed at the newest."""
 
     def __init__(self, nodes: Sequence[NodeInfo], n_pad: int):
-        self._nodes = nodes
+        self.nodes = nodes
         self._n_pad = n_pad
         self._cache: dict[str, tuple[np.ndarray, int]] = {}
 
@@ -96,7 +141,7 @@ class TopologyTable:
         if got is None:
             ids = np.zeros((self._n_pad,), dtype=np.int32)
             interned: dict[str, int] = {}
-            for n, ni in enumerate(self._nodes):
+            for n, ni in enumerate(self.nodes):
                 v = ni.labels.get(topology_key)
                 if v is None:
                     continue

@@ -279,6 +279,65 @@ class TestBackendDegradationMetrics:
         run(body())
 
 
+class TestAffinityCompilerBuilds:
+    """How the backend's affinity compiler reached each snapshot is in the
+    registry: one full walk, then delta advances while the node set stands;
+    the spread table's node planes are built once and kept."""
+
+    def test_n_drains_are_one_full_build_and_the_rest_delta(self):
+        from kubernetes_tpu.metrics.registry import SchedulerMetrics
+        from kubernetes_tpu.ops import TPUBackend
+        from kubernetes_tpu.scheduler.cache import SchedulerCache
+        from kubernetes_tpu.scheduler.framework import Framework
+        from kubernetes_tpu.scheduler.plugins.registry import (
+            DEFAULT_SCORE_WEIGHTS,
+            build_plugins,
+        )
+        from kubernetes_tpu.scheduler.types import PodInfo
+        from kubernetes_tpu.utils.tracing import Tracer
+        zone = "topology.kubernetes.io/zone"
+        cache = SchedulerCache()
+        for i in range(12):
+            cache.add_node(make_node(f"n{i}", labels={zone: f"z{i % 3}"}))
+        backend = TPUBackend(max_batch=16, mesh=None)
+        backend.metrics = SchedulerMetrics()
+        backend.tracer = Tracer(enabled=True)
+        fwk = Framework(build_plugins(), DEFAULT_SCORE_WEIGHTS)
+        drains = 5
+        try:
+            for d in range(drains):
+                batch = [PodInfo(make_pod(
+                    f"d{d}-{j}", uid=f"d{d}-{j}", labels={"app": "a"},
+                    topology_spread_constraints=[{
+                        "maxSkew": 1, "topologyKey": zone,
+                        "whenUnsatisfiable": "DoNotSchedule",
+                        "labelSelector": {"matchLabels": {"app": "a"}}}]))
+                    for j in range(6)]
+                placed, _ = backend.assign(
+                    batch, cache.update_snapshot(), fwk)
+                for pi in batch:
+                    cache.assume_pod(pi, placed[pi.key])
+            spans = [s for s in backend.tracer.spans
+                     if s.name == "solver.spread_table"
+                     and getattr(s, "span_id", None)]
+        finally:
+            backend.tracer.enabled = False
+        m = backend.metrics
+        assert m.affinity_compiler_builds.value(kind="full") == 1
+        assert m.affinity_compiler_builds.value(kind="delta") == drains - 1
+        # a full build counts every node; each later one the (at most six)
+        # nodes the drain before it placed pods on
+        rows = m.affinity_rows_recounted.value()
+        assert 12 + (drains - 1) <= rows <= 12 + 6 * (drains - 1)
+        assert m.spread_table_builds.value(planes="built") == 1
+        assert m.spread_table_builds.value(planes="kept") == drains - 1
+        assert len(spans) == drains                     # once per assign()
+        text = m.registry.render()
+        assert 'scheduler_tpu_affinity_compiler_builds_total{kind="delta"} 4' \
+            in text
+        assert "scheduler_tpu_affinity_rows_recounted_total" in text
+
+
 class TestRequestTracing:
     """§5.1 OTel-style spans: one trace covers a pod's create → schedule
     → bind across the apiserver and scheduler, exportable to Perfetto."""
