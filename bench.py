@@ -34,8 +34,7 @@ REFERENCE_PODS_PER_SEC = 300.0
 def _provenance(args, details: list[dict]) -> dict:
     """Solve-backend provenance stamped into every headline/detail JSON:
     the jax platform, device kind and count and library versions the
-    run actually used, and whether the solve routed through the fused
-    Pallas kernel, the lax.scan reference, and the donated carry. With
+    run actually used, and whether the solve donates its carry. With
     --processes >= 2 it is the leader replica's (read off its status
     row into the detail): the parent never touches JAX, because the
     chip belongs to the one process that schedules."""
@@ -349,16 +348,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "(the default policy) routes drain-scale and "
                          "gang chunks only. The r20 fragmentation pair "
                          "sweeps greedy vs optimal on one preset")
-    ap.add_argument("--pallas", choices=["auto", "on", "off"],
-                    default=None,
-                    help="KTPU_PALLAS: 'off' and 'auto' (the default) run "
-                         "the lax.scan call graph — the fused Pallas "
-                         "wavefront kernel does not lower for the TPU "
-                         "(ops/pallas_kernel.resolve_mode quotes the "
-                         "compiler), so policy routes it off everywhere; "
-                         "'on' compiles the real kernel or fails the run, "
-                         "it never interprets. The headline JSON stamps "
-                         "the resolved mode")
     ap.add_argument("--churn", action="store_true",
                     help="ChurnDay mode (perf/churn): instead of one "
                          "bulk drain, sweep an OPEN-LOOP Poisson/burst/"
@@ -469,17 +458,8 @@ def prepare(args) -> tuple:
         os.environ["KTPU_SERVING"] = "0"
     if args.solve_mode is not None:
         os.environ["KTPU_SOLVE_MODE"] = args.solve_mode
-    if args.pallas is not None:
-        os.environ["KTPU_PALLAS"] = args.pallas
     if args.class_pad is not None:
-        if args.class_pad <= 0:
-            os.environ["KTPU_CLASS_PLANES"] = "0"
-        else:
-            # Force the planes ON too: an inherited KTPU_CLASS_PLANES=0
-            # (a leftover kill-switch export) must not silently turn the
-            # advertised override into a per-pod-fallback run.
-            os.environ["KTPU_CLASS_PLANES"] = "1"
-            os.environ["KTPU_CLASS_PAD"] = str(args.class_pad)
+        os.environ["KTPU_CLASS_PAD"] = str(args.class_pad)
 
     from kubernetes_tpu.perf.scheduler_perf import resolve_processes
     from kubernetes_tpu.utils.featuregate import DEFAULT_FEATURE_GATES

@@ -22,8 +22,7 @@ takes (bench.prepare / make_runner / run_template):
 3. **differential**: outside those runs, one direct TPUBackend.assign of
    a seeded 300-pod batch on a seeded heterogeneous 5,000-node snapshot
    under each solve route the router can pick on this platform (greedy
-   wave scan, Sinkhorn optimal; the Pallas kernel is routed off by
-   policy — ops/pallas_kernel.resolve_mode), compared with the
+   wave scan, Sinkhorn optimal), compared with the
    plugin-by-plugin host path: feasibility masks equal exactly, every
    placement feasible with no node over capacity. Whether assignments
    EQUAL the host oracle's is reported, not gated: f32 score ties may
@@ -151,7 +150,7 @@ def _run_facts(detail: dict, expect_scheduled: int) -> tuple[dict, list]:
             "host_fallback_pods", "backend_fallback_total",
             "fast_path_failures_total", "backend_attached",
             "solver_solve_chunks", "serving_fast_path_pods_total",
-            "solver_optimal_solves_total", "solver_pallas_solves_total",
+            "solver_optimal_solves_total",
             "solver_wave_commits_total", "solver_wave_replays_total",
             "resident_plane_refreshes_total")
     facts = {k: detail[k] for k in keys}
@@ -165,8 +164,6 @@ def _run_facts(detail: dict, expect_scheduled: int) -> tuple[dict, list]:
               "host_fallback_pods"):
         if detail[k]:
             bad.append(f"{k}={detail[k]}")
-    if detail["solve_provenance"].get("pallas_mode") == "interpret":
-        bad.append("pallas_mode=interpret")
     return facts, bad
 
 
@@ -433,7 +430,6 @@ def run(preset: str = "5k", trickle_s: float = 20.0,
         "versions": {k: prov[k] for k in (
             "jax_version", "jaxlib_version", "libtpu_version")},
         "compile_cache_dir": cache_dir,
-        "pallas_mode": prov["pallas_mode"],
         "carry_donation": prov["carry_donation"],
         "drain": drain, "trickle": trickle, "differential": diff,
         "compiles_total": len(log.compiles),

@@ -6,9 +6,9 @@ kill-switch degradation, jit-purity of everything the fused programs
 close over, lock discipline across the apiserver/informer/serving
 threads, and a sprawl of `KTPU_*` env reads. This package turns them
 into machine-checked invariants — the analog of the reference shipping
-`go vet` + race-detector gates on the scheduling cycle — so the Pallas
-kernel work can rewrite the hottest path with regressions caught at
-analysis time, not after a 200k-preset bench run.
+`go vet` + race-detector gates on the scheduling cycle — so a rewrite
+of the hottest path has its regressions caught at analysis time, not
+after a 200k-preset bench run.
 
 Four passes (each a module, each with its own finding codes):
 

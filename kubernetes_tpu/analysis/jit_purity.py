@@ -4,10 +4,11 @@ Entry points are discovered, not configured: every function decorated
 with `jax.jit` / `partial(jax.jit, ...)` / `jax.vmap` / `shard_map`,
 plus every named function passed as the first argument to `lax.scan`,
 `jax.vmap`, `lax.cond`, `jax.jit` or `shard_map`, inside the solve-path
-modules (ops/, parallel/, serving/fastpath). The pass then walks the
-intra-package call graph from those entries (bare-name calls resolve
-within the module; `alias.name(...)` calls resolve through the import
-table into sibling modules) and flags, inside any reachable function:
+modules (ops/, parallel/, serving/fastpath, serving/resident). The pass
+then walks the intra-package call graph from those entries (bare-name
+calls resolve within the module; `alias.name(...)` calls resolve through
+the import table into sibling modules) and flags, inside any reachable
+function:
 
 - JP101 host sync: `.item()` / `.tolist()` / `.block_until_ready()`,
   `np.asarray` / `np.array` / `jax.device_get` — a traced value forced
@@ -52,12 +53,11 @@ PASS_ID = "jit-purity"
 ENTRY_MODULE_SUFFIXES = (
     "kubernetes_tpu/ops/solver.py",
     "kubernetes_tpu/ops/kernels.py",
-    "kubernetes_tpu/ops/pallas_kernel.py",
     "kubernetes_tpu/ops/backend.py",
     "kubernetes_tpu/ops/affinity.py",
-    "kubernetes_tpu/parallel/sharded.py",
     "kubernetes_tpu/parallel/mesh.py",
     "kubernetes_tpu/serving/fastpath.py",
+    "kubernetes_tpu/serving/resident.py",
     "kubernetes_tpu/topology/device.py",
 )
 
@@ -66,8 +66,7 @@ _JIT_DECORATORS = ("jax.jit", "jit", "jax.vmap", "shard_map",
 _TRACE_WRAPPERS = ("lax.scan", "jax.lax.scan", "jax.vmap", "vmap",
                    "lax.cond", "jax.lax.cond", "jax.jit", "jit",
                    "shard_map", "lax.while_loop", "jax.lax.while_loop",
-                   "lax.fori_loop", "jax.checkpoint", "jax.remat",
-                   "pl.pallas_call", "pallas_call")
+                   "lax.fori_loop", "jax.checkpoint", "jax.remat")
 
 _HOST_SYNC_ATTRS = ("item", "tolist", "block_until_ready")
 _HOST_SYNC_CALLS = ("np.asarray", "numpy.asarray", "np.array",

@@ -575,10 +575,7 @@ class SchedulerMetrics:
         #: the gather pass then NEVER touched because the block's score
         #: upper bound provably lost to the (K+1)-th shortlist value
         #: (prune rate = pruned/scanned; 0 on chunks where the
-        #: exactness predicate forced the full-width prefilter). The
-        #: refresh histogram is the serving tier's incremental
-        #: per-block aggregate maintenance wall — O(changed blocks)
-        #: per snapshot refresh, same dirty set as the resident planes.
+        #: exactness predicate forced the full-width prefilter).
         self.solver_blocks_scanned = r.counter(
             "scheduler_tpu_solver_blocks_scanned_total",
             "(class, block) pairs walked by the block-bound prefilter "
@@ -587,10 +584,6 @@ class SchedulerMetrics:
             "scheduler_tpu_solver_blocks_pruned_total",
             "(class, block) pairs the bound scan proved losers — their "
             "columns skipped the chunk-start score pass")
-        self.solver_block_refresh = r.histogram(
-            "scheduler_tpu_solver_block_refresh_seconds",
-            "Wall time of one incremental block-aggregate refresh "
-            "(dirty blocks only) on the resident planes")
         #: Wavefront-solve observability (r18): the wave width the latest
         #: chunk solved at (1 = serial scan — kill switch or narrowed
         #: policy), pods committed speculatively, and pods that fell
@@ -625,22 +618,6 @@ class SchedulerMetrics:
         self.solver_sinkhorn_iterations = r.gauge(
             "solver_sinkhorn_iterations",
             "Sinkhorn iteration budget of the latest optimal-mode solve")
-        #: Pallas fused-kernel observability: chunks whose wavefront
-        #: solve ran the fused kernel (interpret or compiled), and
-        #: chunks where the router WANTED the kernel (KTPU_PALLAS
-        #: resolved on) but fell back to the lax.scan reference — the
-        #: reason label names the structural shape the kernel does not
-        #: fuse (spread/shortlist/optimal/wave_off/shape). A backend
-        #: that cannot lower the kernel is an error, not a fallback.
-        #: The kill switch (KTPU_PALLAS=off) and the auto default do
-        #: NOT count: off-by-policy is not a fallback.
-        self.solver_pallas_solves = r.counter(
-            "solver_pallas_solves_total",
-            "Chunks solved through the fused Pallas wavefront kernel")
-        self.solver_pallas_fallbacks = r.counter(
-            "solver_pallas_fallbacks_total",
-            "Chunks routed to the Pallas kernel that fell back to the "
-            "lax.scan reference", labels=("reason",))
         self.fragmentation_pct = r.gauge(
             "scheduler_fragmentation_pct",
             "Mean stranded-capacity fraction (pct) across occupied "

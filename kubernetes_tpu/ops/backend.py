@@ -33,7 +33,7 @@ is preserved:
   (and the r7 row-dictionary score wire is subsumed by it). Single-
   allowed-column host rows (NodeName, DRA allocated-claim pins) ride a
   sparse per-pod exception column instead of splitting a class. A chunk
-  with more classes than KTPU_CLASS_PAD — or KTPU_CLASS_PLANES=0 —
+  with more classes than KTPU_CLASS_PAD — or KTPU_CLASS_PAD=0 —
   degrades structurally to per-pod planes (C == P, identity index),
   counted as class_split_fallbacks.
 - The remaining per-pod host rows (NodePorts conflicts, volume plugins,
@@ -68,7 +68,7 @@ from kubernetes_tpu.api.labels import ns_contains
 from kubernetes_tpu.utils import flags
 from kubernetes_tpu.utils.locking import check_dispatch_seam
 from kubernetes_tpu.utils.jax_platform import cpu_requested
-from kubernetes_tpu.ops import kernels, pallas_kernel, solver
+from kubernetes_tpu.ops import kernels, solver
 from kubernetes_tpu.ops.tensorize import ClusterTensors, PodBatch
 from kubernetes_tpu.scheduler.framework import (
     CycleState,
@@ -128,7 +128,7 @@ def _shortlist_k_override() -> int | None:
 #: C ≪ chunk — a 1024-pod chunk at 50k nodes ships ~2 class rows
 #: (~25 KB) where the per-pod format shipped a 6.4 MB packed mask and
 #: materialized a 100+ MB score plane on device. A chunk with more
-#: classes than this cap — or KTPU_CLASS_PLANES=0 — falls back to
+#: classes than this cap — or KTPU_CLASS_PAD=0 — falls back to
 #: per-pod planes (C == P, identity index): structurally the pre-class
 #: dense format, bit-identical assignments, counted per pod as
 #: class_split_fallbacks.
@@ -138,8 +138,6 @@ DEFAULT_CLASS_PAD = 31
 def class_pad() -> int:
     """Effective class cap: 0 = class planes off (per-pod fallback).
     Read per assign() so tests/bench can flip the env knobs live."""
-    if not flags.get("KTPU_CLASS_PLANES"):
-        return 0
     return max(0, flags.get("KTPU_CLASS_PAD"))
 
 
@@ -154,7 +152,7 @@ def _class_rows_bucket(n_classes: int) -> int:
 
 class AdaptiveTuner:
     """Flagless solve routing: pipeline depth, shortlist, wavefront,
-    solve mode, Pallas and the serving tier's policy rows. `--chunk`
+    solve mode and the serving tier's policy rows. `--chunk`
     and KTPU_PIPELINE_DEPTH are overrides.
 
     The device is locally attached: `probe()` — the median wall of three
@@ -370,34 +368,6 @@ class AdaptiveTuner:
             return "greedy", False
         return ("optimal", False) if eligible else ("greedy", True)
 
-    def pallas_mode(self, wave_w: int, shortlist_k: int, spread: bool,
-                    solve_mode: str) -> tuple[str, str | None]:
-        """('off' | 'interpret' | 'compiled', fallback_reason) for one
-        chunk. The flag-and-platform table is
-        `pallas_kernel.resolve_mode` (its docstring says why `auto` is
-        off everywhere); this adds the structural gate. 'off' with
-        reason None is off BY POLICY and does not count as a fallback;
-        'off' with a reason is a chunk the flag WANTED on the kernel but
-        whose shape the kernel does not fuse (spread / shortlist /
-        optimal keep their scans; wave_off is the W<=1 serial shape) —
-        those are the `solver_pallas_fallbacks_total` rows. 'compiled'
-        is only ever answered for `on`, and is proven or refuted by the
-        fused program's compile at this chunk's own static shape: a
-        refusal propagates, it is never caught to reroute."""
-        mode = pallas_kernel.resolve_mode(
-            flags.get("KTPU_PALLAS"), jax.default_backend())
-        if mode == "off":
-            return "off", None
-        if solve_mode != "greedy":
-            return "off", "optimal"
-        if spread:
-            return "off", "spread"
-        if shortlist_k:
-            return "off", "shortlist"
-        if wave_w <= 1:
-            return "off", "wave_off"
-        return mode, None
-
     def wave_width(self, chunk: int) -> int:
         """Wavefront width for a chunk; 1 = degenerate one-member waves.
         The KTPU_WAVEFRONT kill switch is routed by the backend (it
@@ -484,10 +454,8 @@ class AdaptiveTuner:
         width/N combination where the selection could not even leave one
         block unselected (top_k needs M+1 distinct blocks; a fully-
         selected index prunes nothing). KTPU_BLOCK_WIDTH overrides the
-        width (0 disabling, like the KTPU_BLOCK_INDEX kill switch).
+        width (0 disables the index).
         """
-        if not flags.get("KTPU_BLOCK_INDEX"):
-            return 0
         override = flags.get("KTPU_BLOCK_WIDTH")
         bw = self.BLOCK_WIDTH if override is None else override
         if bw <= 0 or shortlist_k <= 0 or n_real < self.LARGE_N:
@@ -634,8 +602,7 @@ def _solve_program():
             _SOLVE_PROGRAM = partial(
                 jax.jit,
                 static_argnames=("strategy", "use_spread", "shortlist_k",
-                                 "wave_w", "solve_mode", "pallas",
-                                 "block_w"),
+                                 "wave_w", "solve_mode", "block_w"),
                 donate_argnums=(1,))(_mask_solve_update.__wrapped__)
     return _SOLVE_PROGRAM
 
@@ -649,17 +616,11 @@ def _donation_live() -> bool:
 def solve_provenance() -> dict:
     """Solve-backend provenance for bench/perf output: which jax
     platform, device kind and count, library versions and host core
-    count produced a number, and whether the wave solve routes
-    pallas/scan and donates its carry — so a CPU pre-flight row can
-    never be read as a chip row. `pallas_mode` is what the router
-    resolves for an eligible greedy wave chunk
-    (`pallas_kernel.resolve_mode`); per-chunk structural fallbacks can
-    still keep individual chunks on the scan (counted in
-    solver_pallas_fallbacks_total)."""
+    count produced a number, and whether the fused program donates
+    its carry — so a CPU pre-flight row can never be read as a chip
+    row."""
     import jaxlib
     import libtpu
-    raw = flags.get("KTPU_PALLAS")
-    resolved = pallas_kernel.resolve_mode(raw, jax.default_backend())
     return {
         "jax_platform": jax.default_backend(),
         "device_kind": jax.devices()[0].device_kind,
@@ -668,9 +629,6 @@ def solve_provenance() -> dict:
         "jaxlib_version": jaxlib.__version__,
         "libtpu_version": libtpu.__version__,
         "cpu_count": os.cpu_count(),
-        "solve_kernel": "scan" if resolved == "off" else "pallas",
-        "pallas_mode": resolved,
-        "pallas_flag": raw,
         "carry_donation": _donation_live(),
     }
 
@@ -692,7 +650,7 @@ def _signature(plugin_name: str, pi: PodInfo) -> str:
 
 @partial(jax.jit,
          static_argnames=("strategy", "use_spread", "shortlist_k",
-                          "wave_w", "solve_mode", "pallas", "block_w"))
+                          "wave_w", "solve_mode", "block_w"))
 def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                        cls_idx, exc_col,
                        taint_f_mat, taint_p_mat, class_mask, class_scores,
@@ -704,7 +662,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                        gang_required, sink_iters, sink_temp, n_real,
                        strategy: str, use_spread: bool, shortlist_k: int,
                        wave_w: int, solve_mode: str = "greedy",
-                       pallas: str = "off", block_w: int = 0):
+                       block_w: int = 0):
     """One fused device pass: plugin masks → scores → assignment → state.
 
     The used-state (used_q ‖ used_nz_q ‖ used_pods, packed into ONE (N,2R+1)
@@ -735,7 +693,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     rows; the scans gather `cls_idx[pod]` per step (ops/solver.py
     `rows=`), so no (P, N) array exists anywhere in the program. The
     per-pod degenerate form (C == P, cls_idx == arange, the
-    KTPU_CLASS_PLANES=0 kill switch / class-overflow fallback) runs the
+    KTPU_CLASS_PAD=0 kill switch / class-overflow fallback) runs the
     SAME program and is bit-identical by construction.
 
     shortlist_k > 0 switches the solve to the SHORTLIST-PRUNED scans
@@ -775,16 +733,6 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     presets never hit. wave_w == 0 is the KTPU_WAVEFRONT kill-switch
     shape: the pre-wavefront call graph, structurally.
 
-    `pallas` ("off" | "interpret" | "compiled", static — part of the
-    fused-program key like the other routing statics) swaps the
-    wavefront scan for the FUSED PALLAS KERNEL (ops/pallas_kernel.py):
-    one grid step per wave with the carry resident, same op sequence,
-    bit-identical assignments. It only affects the plain wave branch
-    (greedy, non-spread, no shortlist) — every other shape keeps its
-    scan, and the router (_dispatch_chunk_jit) records those as counted
-    structural fallbacks rather than passing "on" here. "off" traces
-    the r20 scan call graph verbatim — the KTPU_PALLAS kill switch.
-
     `used_pack` is DONATED on accelerator backends (the _solve_program
     variant): the chunk chain is its only consumer — each dispatch
     consumes the previous chunk's output (or the one-off seed _start
@@ -802,7 +750,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     lax.cond falls back to the full-width pass whenever the bound
     predicate cannot prove the gathered top-K global. `n_real` (traced)
     excludes bucket-padding columns from the aggregates. block_w == 0 is
-    the KTPU_BLOCK_INDEX kill-switch shape: the full-width r18/r21
+    the KTPU_BLOCK_WIDTH=0 kill-switch shape: the full-width r18/r21
     prefilter call graph, structurally.
 
     Returns (assign (P+5,) — the tail is [shortlist fallbacks, wave
@@ -888,7 +836,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
         # whenever its exactness predicate cannot prove the gathered
         # top-K global (solver.block_bound_prefilter). Static routing:
         # block_w is part of the fused-program key like wave_w, and 0
-        # (the KTPU_BLOCK_INDEX kill switch / small-N tuner decision /
+        # (the KTPU_BLOCK_WIDTH=0 kill switch / small-N tuner decision /
         # M+1 > B shape guard) traces the r18/r21 full-width call graph
         # verbatim.
         if block_w > 0:
@@ -976,23 +924,13 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                 gang_required, sc0, cls_idx, cand_s[cls_idx],
                 thresh_s[cls_idx], has_node, rows=cls_idx, exc=exc_col)
         elif wave_w > 1:
-            if pallas != "off":
-                assign, wave_com, wave_rep = \
-                    solver.multistart_greedy_assign_wave_pallas(
-                        req_q, req_nz_q, free_q, free_pods, used_nz_q,
-                        alloc_q, mask, static_scores, fit_col_w,
-                        bal_col_mask, shape_u, shape_s, w_fit, w_bal,
-                        strategy, wave_w, perms, gang_onehot,
-                        gang_required, rows=cls_idx, exc=exc_col,
-                        interpret=(pallas != "compiled"))
-            else:
-                assign, wave_com, wave_rep = \
-                    solver.multistart_greedy_assign_wave(
-                        req_q, req_nz_q, free_q, free_pods, used_nz_q,
-                        alloc_q, mask, static_scores, fit_col_w,
-                        bal_col_mask, shape_u, shape_s, w_fit, w_bal,
-                        strategy, wave_w, perms, gang_onehot,
-                        gang_required, rows=cls_idx, exc=exc_col)
+            assign, wave_com, wave_rep = \
+                solver.multistart_greedy_assign_wave(
+                    req_q, req_nz_q, free_q, free_pods, used_nz_q,
+                    alloc_q, mask, static_scores, fit_col_w,
+                    bal_col_mask, shape_u, shape_s, w_fit, w_bal,
+                    strategy, wave_w, perms, gang_onehot,
+                    gang_required, rows=cls_idx, exc=exc_col)
         else:
             assign = solver.multistart_greedy_assign(
                 req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
@@ -2553,7 +2491,7 @@ class TPUBackend:
         # exc vector so a pinned pod shares its template's class. Class
         # 0 is reserved EMPTY (padding pods, unknown resources,
         # conflicting pins). Overflowing the cap — or the
-        # KTPU_CLASS_PLANES=0 kill switch (cap 0) — falls back to
+        # KTPU_CLASS_PAD=0 kill switch (cap 0) — falls back to
         # per-pod planes (C == P, identity index): structurally the
         # pre-class dense format, bit-identical assignments.
         cap = ctx.class_pad
@@ -2756,9 +2694,9 @@ class TPUBackend:
         # Block-index width: the two-pass block-sparse prefilter rides
         # the shortlist (it prunes the prefilter's own O(C·N) pass), so
         # it activates only with it — the tuner's structural large-N
-        # row plus the KTPU_BLOCK_INDEX/KTPU_BLOCK_WIDTH knobs. 0 is
-        # the full-width prefilter, structurally (a static arg of the
-        # fused program, part of the chunk program key like W and K).
+        # row plus the KTPU_BLOCK_WIDTH knob. 0 is the full-width
+        # prefilter, structurally (a static arg of the fused program,
+        # part of the chunk program key like W and K).
         block_w = self._tuner.block_width(
             ct.n_pad, ct.n_real, shortlist_k) if shortlist_k else 0
 
@@ -2951,21 +2889,6 @@ class TPUBackend:
             prep["block_w"] = 0
         prep["solve_mode"] = solve_mode
         prep["optimal_fallback"] = opt_fallback
-        # Pallas routing (the KTPU_PALLAS policy row + structural shape
-        # gate): the kernel fuses only the plain greedy wave branch, and
-        # holds the whole (C,N) planes + (W,N) evaluation resident per
-        # grid step — a chunk above the kernel's working-set ceiling
-        # keeps the scan, counted under reason="shape".
-        pallas_mode, pallas_fall = self._tuner.pallas_mode(
-            prep["wave_w"], prep["shortlist_k"], use_spread, solve_mode)
-        if pallas_mode != "off":
-            shape_reason = pallas_kernel.unsupported_reason(
-                ct.n_pad, prep["dev_mask"].shape[0],
-                ct.alloc_q.shape[1], prep["wave_w"])
-            if shape_reason is not None:
-                pallas_mode, pallas_fall = "off", shape_reason
-        prep["pallas_mode"] = pallas_mode
-        prep["pallas_fallback"] = pallas_fall
         if use_spread:
             sp_args = (sp["dev_dom"], sp["dev_cid"], sp["dev_counts"],
                        sp["dev_skew"], sp["dev_min_ok"], sp["dev_haskey"],
@@ -2988,8 +2911,7 @@ class TPUBackend:
                 np.float32(flags.get("KTPU_SINKHORN_TEMP")),
                 np.int32(ct.n_real),
                 p["strategy"], use_spread, prep["shortlist_k"],
-                prep["wave_w"], solve_mode, pallas_mode,
-                prep["block_w"],
+                prep["wave_w"], solve_mode, prep["block_w"],
             )
         self._dev_used = used_pack2
         if use_spread:
@@ -3059,16 +2981,6 @@ class TPUBackend:
                     max(1, flags.get("KTPU_SINKHORN_ITERS")))
             elif run.get("optimal_fallback"):
                 self.metrics.solver_optimal_fallbacks.inc()
-            # Pallas accounting: solves count chunks whose wave solve
-            # ran the fused kernel; fallbacks count chunks the flag
-            # wanted on the kernel but that kept the scan, labeled by
-            # why. Off-by-policy (kill switch, auto-on-CPU) records
-            # neither — the zero-counter degrade the smoke test pins.
-            if run.get("pallas_mode") not in (None, "off"):
-                self.metrics.solver_pallas_solves.inc()
-            elif run.get("pallas_fallback"):
-                self.metrics.solver_pallas_fallbacks.inc(
-                    reason=run["pallas_fallback"])
             if ctx.ct.prep_shards > 1:
                 # Sharded-path solve accounting: the fused program spans
                 # every shard, so the wall is labeled with the shard

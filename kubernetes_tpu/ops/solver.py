@@ -43,7 +43,7 @@ pod EQUIVALENCE CLASSES (pods sharing request/toleration/host-row/score
 signatures — template batches have a handful) with `rows = class index
 per pod`, so no (P, N) plane exists on host or device; the legacy
 per-pod form is the degenerate `rows = arange(P)` (C == P), which is
-also the KTPU_CLASS_PLANES=0 kill-switch shape. Per-pod residuals that
+also the KTPU_CLASS_PAD=0 kill-switch shape. Per-pod residuals that
 would otherwise split a class — single-allowed-column host rows
 (NodeName, DRA allocated-claim pinning) — ride the sparse exception
 vector `exc`: (P,) int32, -1 = none, else the ONE global column the pod
@@ -1189,86 +1189,6 @@ def multistart_greedy_assign_wave(req_q, req_nz_q, free_q, free_pods,
         return a[inv], pois
 
     assigns, pois = jax.vmap(one)(perms)
-    any_pois = jnp.any(pois)
-
-    def full(_):
-        return _multistart_body(
-            req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
-            static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
-            w_fit, w_bal, strategy, perms, gang_onehot, gang_required,
-            rows, exc)
-
-    def take(_):
-        return _select_best(assigns, req_q, gang_onehot, gang_required)
-
-    assign = lax.cond(any_pois, full, take, None)
-    ncom = jnp.where(any_pois, jnp.int32(0), jnp.int32(P))
-    nrep = jnp.where(any_pois, jnp.int32(P), jnp.int32(0))
-    return assign, ncom, nrep
-
-
-@partial(jax.jit, static_argnames=("strategy", "wave_w", "interpret"))
-def greedy_assign_rescoring_wave_pallas(req_q, req_nz_q, free_q,
-                                        free_pods, used_nz_q, alloc_q,
-                                        mask, static_scores, fit_col_w,
-                                        bal_col_mask, shape_u, shape_s,
-                                        w_fit, w_bal, strategy: str,
-                                        wave_w: int, rows=None, exc=None,
-                                        *, interpret: bool):
-    """greedy_assign_rescoring_wave with the wave scan replaced by the
-    fused Pallas kernel (ops/pallas_kernel.py) — one grid step per wave,
-    carry resident, in-step serial replay of conflicted waves inside the
-    kernel. Same signature, same returns, assignments bit-identical to
-    the scan at every wave_w (the kernel body runs the identical op
-    sequence); the scan stays the semantic reference and the router's
-    fallback target. interpret=True runs the Pallas interpreter (the
-    CPU test mode); False compiles the real kernel, and a backend that
-    cannot lower it raises (see pallas_kernel.resolve_mode)."""
-    from kubernetes_tpu.ops import pallas_kernel  # local: import cycle
-
-    if rows is None:
-        rows = jnp.arange(req_q.shape[0], dtype=jnp.int32)
-    assign, ncom, nrep, _ = pallas_kernel.wave_solve(
-        req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
-        static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
-        w_fit, w_bal, strategy, wave_w, rows, exc, poison=False,
-        perms=None, interpret=interpret)
-    return assign[0], ncom[0], nrep[0]
-
-
-@partial(jax.jit, static_argnames=("strategy", "wave_w", "interpret"))
-def multistart_greedy_assign_wave_pallas(req_q, req_nz_q, free_q,
-                                         free_pods, used_nz_q, alloc_q,
-                                         mask, static_scores, fit_col_w,
-                                         bal_col_mask, shape_u, shape_s,
-                                         w_fit, w_bal, strategy: str,
-                                         wave_w: int, perms, gang_onehot,
-                                         gang_required, rows=None,
-                                         exc=None, *, interpret: bool):
-    """multistart_greedy_assign_wave with the K vmapped wave scans
-    replaced by ONE fused pallas_call whose grid major axis is the order
-    index k (each order owns its carry block). The poison contract, the
-    outer replay cond, and `_select_best` are byte-for-byte the scan
-    wrapper's — only the per-order speculation is fused — so the result
-    is bit-identical whenever the per-order speculative assigns are,
-    which the differential suite checks at every W."""
-    from kubernetes_tpu.ops import pallas_kernel  # local: import cycle
-
-    P = req_q.shape[0]
-    arange_p = jnp.arange(P, dtype=jnp.int32)
-    if rows is None:
-        rows = arange_p
-    assigns_p, _, _, pois = pallas_kernel.wave_solve(
-        req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
-        static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
-        w_fit, w_bal, strategy, wave_w, rows, exc, poison=True,
-        perms=perms, interpret=interpret)
-
-    def unperm(a, perm):
-        inv = jnp.zeros_like(perm).at[perm].set(arange_p)
-        return a[inv]
-
-    assigns = jax.vmap(unperm)(assigns_p, perms)
     any_pois = jnp.any(pois)
 
     def full(_):

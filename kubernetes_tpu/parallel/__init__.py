@@ -1,24 +1,10 @@
-"""Mesh sharding for the scheduling tensors (SURVEY §2.8 / §5.7)."""
+"""Device meshes for the scheduling tensors (SURVEY §2.8 / §5.7)."""
 
 from kubernetes_tpu.parallel.mesh import (
     NODES_AXIS,
-    PODS_AXIS,
     SLICE_AXIS,
     build_mesh,
-    build_mesh_2d,
     build_multislice_mesh,
-    pad_axis,
-)
-from kubernetes_tpu.parallel.sharded import (
-    sharded_greedy_assign,
-    sharded_greedy_assign_multislice,
-    sharded_masks_scores,
-    sharded_sinkhorn_plan,
 )
 
-__all__ = [
-    "NODES_AXIS", "PODS_AXIS", "SLICE_AXIS",
-    "build_mesh", "build_mesh_2d", "build_multislice_mesh", "pad_axis",
-    "sharded_greedy_assign", "sharded_greedy_assign_multislice",
-    "sharded_masks_scores", "sharded_sinkhorn_plan",
-]
+__all__ = ["NODES_AXIS", "SLICE_AXIS", "build_mesh", "build_multislice_mesh"]

@@ -3,28 +3,24 @@
 SURVEY §2.8/§5.7: the reference scales the Filter/Score fan-out with 16
 goroutines over the node list (framework/parallelize) and samples nodes
 (`percentageOfNodesToScore`) when clusters get big. The TPU design instead
-shards the `(P pods × N nodes)` problem matrix over a `jax.sharding.Mesh`:
+shards the node axis of the scheduling tensors over a `jax.sharding.Mesh`
+(`TPUBackend(mesh=...)`: `NamedSharding` on every node-axis array, the one
+fused program partitioned by XLA, which inserts the cross-shard reductions
+of the per-step argmax):
 
-- **nodes axis** across chips within a slice (ICI; the TP-like axis) — masks,
-  scores, and the solver's per-step argmax reduce across it with
-  `pmax`/`pmin` collectives;
-- **pods axis** across replicas (the DP-like axis) for the embarrassingly
-  parallel mask/score phase;
-- multi-slice DCN would add an outer axis to the same specs (the 50k-node
-  config #5 path); the code below is mesh-size-agnostic — 1 chip is just a
-  (1,)-shaped mesh (SURVEY §7 hard-part #6).
+- **nodes axis** across chips within a slice (ICI);
+- multi-slice DCN adds an outer **slice axis** to the same specs (the
+  50k-node config #5 path); the code below is mesh-size-agnostic — 1 chip
+  is just a (1,)-shaped mesh (SURVEY §7 hard-part #6).
 """
 
 from __future__ import annotations
-
-import math
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
 NODES_AXIS = "nodes"
-PODS_AXIS = "pods"
 SLICE_AXIS = "slice"
 
 
@@ -43,11 +39,11 @@ def build_multislice_mesh(n_slices: int,
 
     The outer `slice` axis maps to DCN (cross-slice traffic), the inner
     `nodes` axis to ICI within a slice; the cluster's node dimension is
-    sharded over BOTH (flattened slice-major), so collectives reduce
-    hierarchically: slice-local first (ICI), one scalar per slice across
-    DCN second. Under the real multi-slice runtime `jax.devices()` orders
-    devices slice-major so rows land on physical slices; on the virtual
-    CPU mesh the grouping is positional (what the dryrun proves)."""
+    sharded over BOTH (flattened slice-major) and XLA's partitioner
+    places the cross-shard reductions. Under the real multi-slice
+    runtime `jax.devices()` orders devices slice-major so rows land on
+    physical slices; on the virtual CPU mesh the grouping is positional
+    (what the dryrun proves)."""
     devs = jax.devices()
     if chips_per_slice is None:
         if len(devs) % n_slices:
@@ -67,34 +63,3 @@ def build_multislice_mesh(n_slices: int,
             f"NODE_PAD={NODE_PAD} (use a power-of-two shard count)")
     arr = np.array(devs[:total]).reshape(n_slices, chips_per_slice)
     return Mesh(arr, (SLICE_AXIS, NODES_AXIS))
-
-
-def build_mesh_2d(n_devices: int | None = None,
-                  pods_parallelism: int | None = None) -> Mesh:
-    """(pods × nodes) mesh for the mask/score phase. Factorization favors the
-    nodes axis (N ≫ P in every BASELINE config)."""
-    devs = jax.devices()
-    n = n_devices or len(devs)
-    if n > len(devs):
-        raise ValueError(f"requested {n} devices, have {len(devs)}")
-    if pods_parallelism is None:
-        pods_parallelism = 1
-        for f in range(int(math.isqrt(n)), 0, -1):
-            if n % f == 0:
-                pods_parallelism = f
-                break
-    assert n % pods_parallelism == 0
-    arr = np.array(devs[:n]).reshape(pods_parallelism, n // pods_parallelism)
-    return Mesh(arr, (PODS_AXIS, NODES_AXIS))
-
-
-def pad_axis(x: np.ndarray, multiple: int, axis: int,
-             fill=0) -> np.ndarray:
-    """Pad one axis up to a multiple so it divides the mesh axis evenly."""
-    size = x.shape[axis]
-    target = math.ceil(size / multiple) * multiple
-    if target == size:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, target - size)
-    return np.pad(x, widths, constant_values=fill)

@@ -166,14 +166,9 @@ class WorkloadResult:
         self.solver_wave_width = 0
         self.solver_wave_commits_total = 0
         self.solver_wave_replays_total = 0
-        #: Fused Pallas wavefront kernel accounting (r21): chunks solved
-        #: through ops/pallas_kernel.py vs chunks that requested the
-        #: kernel and fell back to the lax.scan reference, plus the
-        #: solve-backend provenance row (jax platform, device count,
-        #: resolved pallas mode, carry donation) stamped per family so a
-        #: CPU pre-flight row is never mistaken for a chip row.
-        self.solver_pallas_solves_total = 0
-        self.solver_pallas_fallbacks_total = 0
+        #: The solve-backend provenance row (jax platform, device count,
+        #: carry donation) stamped per family so a CPU pre-flight row is
+        #: never mistaken for a chip row.
         self.solve_provenance: dict = {}
         #: Device-loss accounting over the WHOLE run (warm-up included —
         #: a backend that failed there and recovered still failed):
@@ -361,9 +356,6 @@ class WorkloadResult:
                    + self.solver_wave_replays_total), 2)
             if (self.solver_wave_commits_total
                 + self.solver_wave_replays_total) else None,
-            "solver_pallas_solves_total": self.solver_pallas_solves_total,
-            "solver_pallas_fallbacks_total":
-                self.solver_pallas_fallbacks_total,
             "solve_provenance": self.solve_provenance,
             "host_path_pods": self.host_path_pods,
             "backend_fallback_total": self.backend_fallback_total,
@@ -1576,8 +1568,6 @@ class PerfRunner:
             metrics.solver_blocks_pruned.value(),
             metrics.solver_wave_commits.value(),
             metrics.solver_wave_replays.value(),
-            metrics.solver_pallas_solves.value(),
-            sum(metrics.solver_pallas_fallbacks._values.values()),
             metrics.prep_duration.sum(),
             metrics.plane_bytes.value(),
             metrics.class_split_fallbacks.value(),
@@ -1604,7 +1594,6 @@ class PerfRunner:
          solve_chunks_base, solve_s_base, sl_pods_base,
          sl_fall_base, blk_scan_base, blk_prune_base,
          wave_com_base, wave_rep_base,
-         pallas_base, pallas_fb_base,
          prep_s_base, plane_b_base, class_fb_base,
          shard_rb_base, shard_s_base, xshard_base,
          fast_base, coalesced_base, refresh_base, refresh_s_base,
@@ -1676,11 +1665,6 @@ class PerfRunner:
             metrics.solver_wave_commits.value() - wave_com_base)
         result.solver_wave_replays_total = int(
             metrics.solver_wave_replays.value() - wave_rep_base)
-        result.solver_pallas_solves_total = int(
-            metrics.solver_pallas_solves.value() - pallas_base)
-        result.solver_pallas_fallbacks_total = int(
-            sum(metrics.solver_pallas_fallbacks._values.values())
-            - pallas_fb_base)
         if self.backend is not None:
             from kubernetes_tpu.ops.backend import solve_provenance
             result.solve_provenance = solve_provenance()

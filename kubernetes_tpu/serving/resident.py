@@ -11,7 +11,7 @@ never an O(N) generation walk), the rows are re-quantized from the
 ClusterTensors arrays, and only they ship to the device — as a fused
 scatter inside the fast-path solve (`solver.solve_one_fresh`, one
 dispatch) or a standalone scatter for batch assigns
-(`parallel/sharded.resident_row_scatter`).
+(`resident_row_scatter` below).
 
 Refresh contract (what invalidates what — README "Online serving path"):
 
@@ -35,36 +35,33 @@ the returned delta (used_pack does it inline; the fast path fuses it
 into the solve and `adopt()`s the result). Un-adopted deltas persist
 in `_pending` and ride the next refresh — an exception between refresh
 and adopt can delay a row, never lose it.
-
-Block-index aggregates (the two-level node index, ops/solver
-`block_bound_prefilter`): the same dirty-row set additionally maintains
-per-block capacity interval planes — (amin_pos, amin, amax) over
-allocatable and (umin, umax) over scoring-used, each (B, R) int32 with
-B = ceil(n_real / block_w) — recomputed O(changed blocks · block_w)
-per refresh and mirrored to device as one packed (B, 5R) upload
-(`solver_block_refresh_seconds` records the wall). These planes are
-OBSERVABILITY + serving-side reuse state: the fused batch solve
-deliberately derives its block aggregates IN-PROGRAM from the live
-`used_pack` instead of consuming them — a mid-batch verify-reject folds
-used-state back DOWN, which would turn any maintained max/min stale in
-the unsafe direction, while the O(changed) maintenance here is exact at
-refresh boundaries (the parity test pins it against a from-scratch
-recompute).
 """
 
 from __future__ import annotations
 
+import functools
 import time
 
+import jax
 import numpy as np
-
-from kubernetes_tpu.utils import flags
 
 REBUILD_FRACTION = 0.25
 
-#: masked-out sentinel for block minima — mirrors ops/kernels._BLOCK_BIG
-#: so host-maintained planes equal the device kernels' bit-for-bit.
-_BLOCK_BIG = 2 ** 30
+@functools.lru_cache(maxsize=None)
+def resident_row_scatter(sharding=None):
+    """Jitted `pack.at[rows].set(vals)` for the resident used-state
+    pack, one program per sharding. Rows/vals are tiny (the cache's
+    dirty set — O(assumed pods) per cycle), so under a mesh they ride
+    replicated while the (N, 2R+1) pack stays sharded over the nodes
+    axis: `out_shardings` pins the result's sharding so the resident
+    array never silently de-shards across refreshes (a gathered pack
+    would re-pay the full-upload cost the scatter exists to avoid). On
+    a single device (sharding=None) it is a plain jitted scatter."""
+
+    def body(pack, rows, vals):
+        return pack.at[rows].set(vals)
+
+    return jax.jit(body, out_shardings=sharding)
 
 
 class ResidentPlanes:
@@ -81,20 +78,11 @@ class ResidentPlanes:
         #: observability (also mirrored into the metrics registry).
         self.full_rebuilds = 0
         self.row_refreshes = 0
-        #: block-index aggregate planes (see module docstring): host
-        #: dict of five (B, R) int32 planes + one packed device mirror.
-        self._blocks: dict[str, np.ndarray] | None = None
-        self._blocks_dev = None
-        self._block_w = 0
-        self._alloc_q: np.ndarray | None = None
 
     def invalidate(self) -> None:
         self._key = None
         self._dev = None
         self._pending.clear()
-        self._blocks = None
-        self._blocks_dev = None
-        self._alloc_q = None
 
     # -- refresh ------------------------------------------------------------
 
@@ -108,7 +96,6 @@ class ResidentPlanes:
         self._gen = ct.generation
         self._pending.clear()
         self.full_rebuilds += 1
-        self._rebuild_blocks(ct)
 
     def refresh(self, ct, snapshot=None):
         """Bring the host mirror up to `ct` and return the device delta:
@@ -158,7 +145,6 @@ class ResidentPlanes:
                     self._pack_np[idxs] = vals
                     self.row_refreshes += 1
                     out = self._pad_bucket(idxs, vals)
-                    self._refresh_blocks(ct, idxs)
                     worked = True
         if worked and self.metrics is not None:
             # No-op refreshes (nothing dirty) deliberately don't count:
@@ -196,9 +182,7 @@ class ResidentPlanes:
         """Apply a refresh() delta via the standalone scatter (a tiny
         program — per-bucket compiles are cheap, unlike the fused
         solve's) and adopt the result."""
-        from kubernetes_tpu.parallel.sharded import resident_row_scatter
         fn = resident_row_scatter(
-            self.backend.mesh,
             getattr(self.backend, "_sh_nodes_mat", None))
         self.adopt(fn(self._dev, delta[0], delta[1]))
 
@@ -210,108 +194,8 @@ class ResidentPlanes:
             self.apply_delta(delta)
         return self._dev
 
-    # -- block-index aggregates ---------------------------------------------
-
-    @staticmethod
-    def _block_width_from_flags() -> int:
-        """Resolve the maintained block width from the flag registry:
-        0 (index off) under the KTPU_BLOCK_INDEX kill switch, else the
-        KTPU_BLOCK_WIDTH override, else the tuner's default width."""
-        if not flags.get("KTPU_BLOCK_INDEX"):
-            return 0
-        override = flags.get("KTPU_BLOCK_WIDTH")
-        if override is not None:
-            return max(0, int(override))
-        from kubernetes_tpu.ops.backend import AdaptiveTuner
-        return AdaptiveTuner.BLOCK_WIDTH
-
-    def _rebuild_blocks(self, ct) -> None:
-        """Full recompute of the five (B, R) planes over the real rows.
-
-        Called from _rebuild (the node set / columns / pad changed, so
-        every block is dirty anyway). Sentinels match ops/kernels
-        .block_capacity_aggregates: minima fill with _BLOCK_BIG, maxima
-        with 0, and amin_pos additionally masks zero-alloc columns —
-        the device kernel folds the same values in the same dtype, so
-        the parity test can compare bit-for-bit.
-        """
-        bw = self._block_w = self._block_width_from_flags()
-        if not bw:
-            self._blocks = None
-            self._blocks_dev = None
-            self._alloc_q = None
-            return
-        n = ct.n_real
-        alloc = np.asarray(ct.alloc_q[:n], dtype=np.int32)
-        self._alloc_q = alloc.copy()
-        r = alloc.shape[1]
-        used_nz = self._pack_np[:n, r:2 * r]
-        b = -(-n // bw) if n else 0
-
-        def fold(x, fill):
-            pad = b * bw - n
-            if pad:
-                x = np.concatenate(
-                    [x, np.full((pad, r), fill, np.int32)])
-            return x.reshape(b, bw, r)
-
-        self._blocks = {
-            "amin_pos": fold(np.where(alloc > 0, alloc, _BLOCK_BIG),
-                             _BLOCK_BIG).min(axis=1),
-            "amin": fold(alloc, _BLOCK_BIG).min(axis=1),
-            "amax": fold(alloc, 0).max(axis=1),
-            "umin": fold(used_nz, _BLOCK_BIG).min(axis=1),
-            "umax": fold(used_nz, 0).max(axis=1),
-        } if b else {
-            k: np.zeros((0, r), np.int32)
-            for k in ("amin_pos", "amin", "amax", "umin", "umax")
-        }
-        self._upload_blocks()
-
-    def _refresh_blocks(self, ct, idxs: np.ndarray) -> None:
-        """Recompute only the blocks containing dirty rows — the
-        O(changed blocks · block_w) path the module docstring promises.
-        `idxs` are the already-filtered real dirty rows (< n_real)."""
-        if self._blocks is None or self._block_w <= 0:
-            return
-        t0 = time.perf_counter()
-        bw = self._block_w
-        n = self._alloc_q.shape[0]
-        r = self._alloc_q.shape[1]
-        # allocatable can move too (informer node updates ride the same
-        # dirty set) — re-snapshot those rows before aggregating.
-        self._alloc_q[idxs] = np.asarray(ct.alloc_q[idxs], dtype=np.int32)
-        for blk in np.unique(idxs // bw):
-            lo, hi = int(blk) * bw, min((int(blk) + 1) * bw, n)
-            alloc = self._alloc_q[lo:hi]
-            used_nz = self._pack_np[lo:hi, r:2 * r]
-            self._blocks["amin_pos"][blk] = np.where(
-                alloc > 0, alloc, _BLOCK_BIG).min(axis=0)
-            self._blocks["amin"][blk] = alloc.min(axis=0)
-            self._blocks["amax"][blk] = alloc.max(axis=0)
-            self._blocks["umin"][blk] = used_nz.min(axis=0)
-            self._blocks["umax"][blk] = used_nz.max(axis=0)
-        self._upload_blocks()
-        if self.metrics is not None:
-            self.metrics.solver_block_refresh.observe(
-                time.perf_counter() - t0)
-
-    def _upload_blocks(self) -> None:
-        """Mirror the host planes to device as one packed (B, 5R)
-        upload (small: ~20 B/block·resource, one transfer per refresh)."""
-        self._blocks_dev = self.backend._put(np.concatenate(
-            [self._blocks[k] for k in
-             ("amin_pos", "amin", "amax", "umin", "umax")],
-            axis=1).astype(np.int32))
-
     # -- test/debug hooks ---------------------------------------------------
 
     def host_mirror(self) -> np.ndarray | None:
         """The host copy of the resident pack (None before first use)."""
         return self._pack_np
-
-    def block_aggregates(self):
-        """(block_w, host planes dict, packed device mirror) — None
-        planes when the block index is off. The parity test recomputes
-        the planes from scratch off the host mirror and compares."""
-        return self._block_w, self._blocks, self._blocks_dev

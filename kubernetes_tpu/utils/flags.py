@@ -72,17 +72,6 @@ def _parse_wal_fsync(raw: str) -> str:
     return v
 
 
-def _parse_pallas(raw: str) -> str:
-    v = raw.strip().lower()
-    if v in ("auto", "on", "off", "interpret"):
-        return v
-    if v in _FALSE:          # the boolean spellings keep working
-        return "off"
-    if v in ("1", "true", "yes"):
-        return "on"
-    raise ValueError(raw)    # degrades to the default, per read()
-
-
 @dataclass(frozen=True)
 class Flag:
     name: str
@@ -116,34 +105,11 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
           "Online serving tier (admission window + resident planes + "
           "single-pod fast path). `0` degrades the dispatch loop "
           "structurally to the pre-serving shape.", kill_switch=True),
-    _flag("KTPU_CLASS_PLANES", True, _parse_bool,
-          "Class-dictionary (C,N) device planes. `0` falls back to "
-          "per-pod planes (C == P identity), bit-identical assignments.",
-          kill_switch=True),
     _flag("KTPU_WAVEFRONT", True, _parse_bool,
           "Speculative wavefront solve (W pods per scan step with exact "
           "conflict replay). `0` degrades structurally to the "
           "one-pod-per-step W=1 scans, bit-identical assignments.",
           kill_switch=True),
-    _flag("KTPU_PALLAS", "auto", _parse_pallas,
-          "Fused Pallas wavefront solve kernel (ops/pallas_kernel.py). "
-          "`off` is the kill switch — the exact r20 lax.scan call graph, "
-          "bit-identical assignments. `auto` (default) is off on every "
-          "platform by policy: the kernel does not lower for the TPU "
-          "(`pallas_kernel.resolve_mode` quotes the compiler). `on` "
-          "compiles the real kernel or fails the run — never "
-          "interpret; `interpret` runs the Pallas interpreter, a CPU "
-          "test mode that is refused on any other platform. "
-          "Structural fallbacks to the scan are counted in "
-          "`solver_pallas_fallbacks_total`.", kill_switch=True),
-    _flag("KTPU_BLOCK_INDEX", True, _parse_bool,
-          "Two-level block-sparse node index for the shortlist "
-          "prefilter: per-block aggregate planes + an O(C·B) bound scan "
-          "gate which node columns the chunk-start score pass touches, "
-          "exactly (a block whose score upper bound loses to the "
-          "(K+1)-th shortlist value cannot hold a top-K column). `0` "
-          "degrades structurally to the full-width r18/r21 prefilter "
-          "call graph, bit-identical assignments.", kill_switch=True),
     _flag("KTPU_WAVE_WIDTH", None, _parse_int,
           "Wavefront width override (pods evaluated per scan step). "
           "Unset = the AdaptiveTuner policy row picks W and shrinks it "
@@ -229,9 +195,11 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
           "15/10/2 shape) — shorter lease = faster failover detection "
           "at more lease-write traffic."),
     _flag("KTPU_CLASS_PAD", 31, _parse_int,
-          "Max real pod-equivalence classes per chunk before the "
-          "per-pod fallback (plane rows bucket to the next power of "
-          "two)."),
+          "Max real pod-equivalence classes per chunk of the "
+          "class-dictionary (C,N) device planes before the per-pod "
+          "fallback (plane rows bucket to the next power of two). `0` "
+          "turns class planes off: per-pod planes (C == P identity), "
+          "bit-identical assignments."),
     _flag("KTPU_PIPELINE_DEPTH", None, _parse_int,
           "Solve-pipeline depth override (chunks in flight ahead of "
           "the fetch). Unset = the AdaptiveTuner's table: 4, then 2 "
@@ -242,9 +210,12 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
           "fallback rate."),
     _flag("KTPU_BLOCK_WIDTH", None, _parse_int,
           "Block width override (node columns per block) for the "
-          "block-sparse index; `0` disables it like KTPU_BLOCK_INDEX=0. "
-          "Unset = the AdaptiveTuner's structural policy row picks the "
-          "width from the node count."),
+          "two-level block-sparse node index of the shortlist "
+          "prefilter: an O(C·B) bound scan gates which node columns the "
+          "chunk-start score pass touches, exactly. `0` disables it: "
+          "the full-width prefilter call graph, bit-identical "
+          "assignments. Unset = the AdaptiveTuner's structural policy "
+          "row picks the width from the node count."),
     _flag("KTPU_ADMISSION_WINDOW", None, _parse_ms,
           "Serving admission coalesce window in MILLISECONDS (pinned "
           "for sweeps; `0` = always dispatch immediately). Unset = the "
@@ -269,9 +240,9 @@ FLAGS: dict[str, Flag] = {f.name: f for f in (
     _flag("KTPU_TEST_PLATFORM", "cpu", _parse_str,
           "jax platform the test suite runs against (tests/conftest.py "
           "exports it as JAX_PLATFORMS before jax initializes). `tpu` "
-          "points a suite at the chip: the Pallas interpret-mode suites "
-          "and tests that need more devices than it has skip; PERF.md "
-          "records which suites have run there."),
+          "points a suite at the chip: tests that need more devices "
+          "than it has skip; PERF.md records which suites have run "
+          "there."),
 )}
 
 

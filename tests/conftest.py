@@ -5,9 +5,9 @@ exercised without TPU hardware (the chip runs chip_smoke.py and bench.py).
 
 JAX reads JAX_PLATFORMS/XLA_FLAGS when it initializes, so both are set here
 before anything imports it. KTPU_TEST_PLATFORM points a suite at real
-hardware instead: the Pallas interpret-mode suites skip themselves there,
-and so do tests that need more devices than the machine has (one process
-per chip also rules out the suites that spawn children). PERF.md records
+hardware instead: tests that need more devices than the machine has skip
+themselves there (one process per chip also rules out the suites that
+spawn children). PERF.md records
 which suites have run on the chip; tier-1 is the CPU run.
 """
 

@@ -2,14 +2,11 @@
 
 Pins: (a) the index is ACTIVE BY DEFAULT at large N — the AdaptiveTuner
 block-width row turns on structurally at n_real >= LARGE_N with the
-shortlist active, no flag needed; (b) the KTPU_BLOCK_INDEX=0 kill switch
+shortlist active, no flag needed; (b) the KTPU_BLOCK_WIDTH=0 kill switch
 degrades STRUCTURALLY (width 0 → the full-width r18/r21 prefilter call
-graph, not a masked no-op), as do KTPU_BLOCK_WIDTH=0 and every shape
-guard; (c) at small N the counters must not drift — zero blocks scanned
-or pruned when the policy row keeps the index off; (d) the resident
-serving planes' per-block aggregate maintenance stays exact across
-churn (incremental dirty-block refresh equals a from-scratch recompute,
-bit for bit, host and device). The heavy parity battery lives in
+graph, not a masked no-op), as does every shape guard; (c) at small N
+the counters must not drift — zero blocks scanned or pruned when the
+policy row keeps the index off. The heavy parity battery lives in
 test_block_index_solver.py; the perf numbers in bench (BASELINE).
 """
 
@@ -21,7 +18,6 @@ from kubernetes_tpu.metrics.registry import SchedulerMetrics
 from kubernetes_tpu.ops.backend import AdaptiveTuner, TPUBackend
 from kubernetes_tpu.scheduler.cache import SchedulerCache
 from kubernetes_tpu.scheduler.types import PodInfo
-from kubernetes_tpu.serving.resident import _BLOCK_BIG, ResidentPlanes
 from test_tpu_backend import default_fwk
 
 
@@ -45,7 +41,7 @@ class TestTunerPolicyRow:
         assert t.block_width(n, n, 0) == 0
 
     def test_kill_switch_structural(self, monkeypatch):
-        monkeypatch.setenv("KTPU_BLOCK_INDEX", "0")
+        monkeypatch.setenv("KTPU_BLOCK_WIDTH", "0")
         t = AdaptiveTuner()
         n = AdaptiveTuner.LARGE_N
         assert t.block_width(n, n, 1024) == 0
@@ -87,102 +83,3 @@ class TestCounterHygiene:
         b.assign(pods, snap, default_fwk())
         assert b.metrics.solver_blocks_scanned.value() == 0
         assert b.metrics.solver_blocks_pruned.value() == 0
-
-
-class TestResidentBlockAggregates:
-    def _cluster(self, n=40):
-        cache = SchedulerCache()
-        for i in range(n):
-            cache.add_node(make_node(
-                f"n{i}", allocatable={"cpu": "8", "memory": "32Gi",
-                                      "pods": "110"}))
-        return cache
-
-    def _recompute(self, res, ct, bw):
-        """From-scratch recompute of the five planes off the host
-        mirror — the oracle the incremental path must match."""
-        n = ct.n_real
-        alloc = np.asarray(ct.alloc_q[:n], dtype=np.int32)
-        r = alloc.shape[1]
-        used_nz = res.host_mirror()[:n, r:2 * r]
-        b = -(-n // bw)
-
-        def fold(x, fill):
-            pad = b * bw - n
-            if pad:
-                x = np.concatenate(
-                    [x, np.full((pad, r), fill, np.int32)])
-            return x.reshape(b, bw, r)
-
-        return {
-            "amin_pos": fold(np.where(alloc > 0, alloc, _BLOCK_BIG),
-                             _BLOCK_BIG).min(axis=1),
-            "amin": fold(alloc, _BLOCK_BIG).min(axis=1),
-            "amax": fold(alloc, 0).max(axis=1),
-            "umin": fold(used_nz, _BLOCK_BIG).min(axis=1),
-            "umax": fold(used_nz, 0).max(axis=1),
-        }
-
-    def test_incremental_refresh_matches_recompute(self, monkeypatch):
-        """Assume-driven churn dirties a few rows; the dirty-block
-        incremental path must leave every plane equal to a from-scratch
-        recompute — host AND the packed device mirror — and the refresh
-        histogram must see the work."""
-        monkeypatch.setenv("KTPU_BLOCK_WIDTH", "8")
-        cache = self._cluster()
-        b = TPUBackend(max_batch=16, mesh=None)
-        m = SchedulerMetrics()
-        res = ResidentPlanes(b, metrics=m)
-        ct = b._tensors(cache.update_snapshot())
-        res.used_pack(ct)
-        bw, planes, dev = res.block_aggregates()
-        assert bw == 8 and planes is not None
-        for key, want in self._recompute(res, ct, bw).items():
-            np.testing.assert_array_equal(planes[key], want, err_msg=key)
-        # churn: a handful of assumes across distinct blocks
-        for t, node in enumerate(("n3", "n3", "n17", "n30")):
-            cache.assume_pod(PodInfo(make_pod(
-                f"w{t}", uid=f"w{t}",
-                requests={"cpu": "500m", "memory": "1Gi"})), node)
-            ct = b._tensors(cache.update_snapshot())
-            res.used_pack(ct)
-        assert res.row_refreshes > 0  # the incremental path actually ran
-        bw, planes, dev = res.block_aggregates()
-        oracle = self._recompute(res, ct, bw)
-        for key, want in oracle.items():
-            np.testing.assert_array_equal(planes[key], want, err_msg=key)
-        np.testing.assert_array_equal(
-            np.asarray(dev),
-            np.concatenate([oracle[k] for k in
-                            ("amin_pos", "amin", "amax", "umin",
-                             "umax")], axis=1))
-        assert m.solver_block_refresh.count() > 0
-
-    def test_kill_switch_no_planes(self, monkeypatch):
-        """KTPU_BLOCK_INDEX=0: no planes maintained, no histogram
-        samples — the serving tier pays nothing for the index."""
-        monkeypatch.setenv("KTPU_BLOCK_INDEX", "0")
-        cache = self._cluster(12)
-        b = TPUBackend(max_batch=16, mesh=None)
-        m = SchedulerMetrics()
-        res = ResidentPlanes(b, metrics=m)
-        res.used_pack(b._tensors(cache.update_snapshot()))
-        bw, planes, dev = res.block_aggregates()
-        assert bw == 0 and planes is None and dev is None
-        assert m.solver_block_refresh.count() == 0
-
-    def test_full_rebuild_on_node_set_change(self, monkeypatch):
-        """A node add flips set_epoch → full rebuild path; the planes
-        must track the new B and stay exact."""
-        monkeypatch.setenv("KTPU_BLOCK_WIDTH", "8")
-        cache = self._cluster()
-        b = TPUBackend(max_batch=16, mesh=None)
-        res = ResidentPlanes(b)
-        res.used_pack(b._tensors(cache.update_snapshot()))
-        cache.add_node(make_node("extra-0"))
-        ct = b._tensors(cache.update_snapshot())
-        res.used_pack(ct)
-        bw, planes, _ = res.block_aggregates()
-        assert planes["amax"].shape[0] == -(-ct.n_real // bw)
-        for key, want in self._recompute(res, ct, bw).items():
-            np.testing.assert_array_equal(planes[key], want, err_msg=key)

@@ -3,7 +3,7 @@ shortlist prefilter (ops/solver.block_bound_prefilter vs
 kernels.chunk_start_scores + shortlist_prefilter), end to end through
 every scan variant that consumes the prefilter outputs.
 
-The contract under test is absolute (ISSUE 20 / the KTPU_BLOCK_INDEX
+The contract under test is absolute (ISSUE 20 / the KTPU_BLOCK_WIDTH
 knob's README section): the two-pass block-bound form is a pruning of
 the SAME argmax — assignments bit-identical to the full-width pass at
 every width (KTPU_BLOCK_WIDTH), strategy, and shard count, including
@@ -275,65 +275,7 @@ class TestScanParity:
 
 
 # ---------------------------------------------------------------------------
-# sharded path (8-virtual-device CPU mesh, conftest-forced)
-# ---------------------------------------------------------------------------
-
-class TestShardedParity:
-    @pytest.mark.parametrize("n_devices", [1, 4, 8])
-    @pytest.mark.parametrize("bw", [4, 8])
-    def test_matches_single_chip(self, n_devices, bw):
-        if len(jax.devices()) < n_devices:
-            pytest.skip("not enough devices")
-        from kubernetes_tpu.parallel import build_mesh
-        from kubernetes_tpu.parallel.sharded import sharded_greedy_assign
-        rng = np.random.default_rng(11)
-        d = synthetic(rng, P=12, N=64)
-        args = solver_args(d)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            *args, strategy="LeastAllocated"))
-        sharded = np.asarray(sharded_greedy_assign(
-            build_mesh(n_devices), *args, "LeastAllocated",
-            shortlist_k=3, block_w=bw))
-        np.testing.assert_array_equal(single, sharded)
-
-    def test_shard_local_width_clamp(self):
-        """A width whose M+1 > B at the LOCAL shard (global N is wide
-        enough, each shard's slice is not) must route to 0 — never a
-        shape error, never a wrong answer."""
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 devices")
-        from kubernetes_tpu.parallel import build_mesh
-        from kubernetes_tpu.parallel.sharded import sharded_greedy_assign
-        rng = np.random.default_rng(12)
-        d = synthetic(rng, P=12, N=64)  # 8 columns per shard
-        args = solver_args(d)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            *args, strategy="LeastAllocated"))
-        sharded = np.asarray(sharded_greedy_assign(
-            build_mesh(8), *args, "LeastAllocated",
-            shortlist_k=3, block_w=16))
-        np.testing.assert_array_equal(single, sharded)
-
-    def test_multislice(self):
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 devices")
-        from kubernetes_tpu.parallel import build_multislice_mesh
-        from kubernetes_tpu.parallel.sharded import (
-            sharded_greedy_assign_multislice,
-        )
-        rng = np.random.default_rng(13)
-        d = synthetic(rng, P=12, N=64)
-        args = solver_args(d)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            *args, strategy="LeastAllocated"))
-        ms = np.asarray(sharded_greedy_assign_multislice(
-            build_multislice_mesh(2, 4), *args, "LeastAllocated",
-            shortlist_k=4, block_w=4))
-        np.testing.assert_array_equal(single, ms)
-
-
-# ---------------------------------------------------------------------------
-# backend end to end: KTPU_BLOCK_INDEX on vs off must be bit-identical
+# backend end to end: the block index on vs off must be bit-identical
 # ---------------------------------------------------------------------------
 
 class TestBackendParity:
@@ -356,7 +298,7 @@ class TestBackendParity:
     @pytest.mark.parametrize("wavefront", [False, True])
     def test_forced_on_off_identical(self, monkeypatch, wavefront):
         """Forced-on (small LARGE_N, KTPU_BLOCK_WIDTH=16) vs the
-        KTPU_BLOCK_INDEX=0 kill switch: identical assignments, and the
+        KTPU_BLOCK_WIDTH=0 kill switch: identical assignments, and the
         forced run must actually scan blocks. The wavefront case pins
         the shortlist∩wave composition (the prefilter feeds the wave
         scan's candidates too)."""
@@ -369,10 +311,9 @@ class TestBackendParity:
         if wavefront:
             monkeypatch.setenv("KTPU_WAVEFRONT", "1")
             monkeypatch.setenv("KTPU_WAVE_WIDTH", "4")
-        monkeypatch.setenv("KTPU_BLOCK_INDEX", "0")
+        monkeypatch.setenv("KTPU_BLOCK_WIDTH", "0")
         off, _ = backend_mod.TPUBackend(
             max_batch=16, mesh=None).assign(pods, snap, fwk)
-        monkeypatch.setenv("KTPU_BLOCK_INDEX", "1")
         monkeypatch.setenv("KTPU_BLOCK_WIDTH", "16")
         monkeypatch.setattr(backend_mod.AdaptiveTuner, "LARGE_N", 1)
         b = backend_mod.TPUBackend(max_batch=16, mesh=None)

@@ -10,8 +10,6 @@
   either entry point, and a backend built before the process count was
   known is refused, not silently dropped;
 - a failed read of the children's counters still stops the children.
-
-(The Pallas policy table's rules live in tests/test_pallas_smoke.py.)
 """
 
 import asyncio

@@ -9,7 +9,7 @@ have carried, exceptions intersect exactly the single-column host rows
 they replace, and the shortlist's exactness bound covers the pinned-pod
 corner (a pin outside its class shortlist falls back to the full row).
 These tests run the same randomized workloads through both formats
-(KTPU_CLASS_PLANES=0 is the structural per-pod degrade) and require the
+(KTPU_CLASS_PAD=0 is the structural per-pod degrade) and require the
 assignment maps to be EQUAL, including the None (unschedulable) entries,
 across tight-capacity contention, affinity/score families, hard spread,
 the shortlist regime, control-plane shards {1, 4, 8}, and the two
@@ -39,14 +39,12 @@ ZONES = ("a", "b", "c")
 
 
 def _class_env(monkeypatch, on: bool, pad: int | None = None) -> None:
-    if on:
-        monkeypatch.delenv("KTPU_CLASS_PLANES", raising=False)
-        if pad is None:
-            monkeypatch.delenv("KTPU_CLASS_PAD", raising=False)
-        else:
-            monkeypatch.setenv("KTPU_CLASS_PAD", str(pad))
+    if not on:
+        monkeypatch.setenv("KTPU_CLASS_PAD", "0")
+    elif pad is None:
+        monkeypatch.delenv("KTPU_CLASS_PAD", raising=False)
     else:
-        monkeypatch.setenv("KTPU_CLASS_PLANES", "0")
+        monkeypatch.setenv("KTPU_CLASS_PAD", str(pad))
 
 
 def _assign(pods, snap, fwk, monkeypatch, on: bool, pad=None, chunk=32):
@@ -240,70 +238,6 @@ class TestBackendParity:
         assert m.plane_classes.value() == 1  # pins did NOT split classes
         for i in range(0, 32, 4):
             assert dense[pods[i].key] == f"n{100 + i}"
-
-
-class TestShardedSolverClassPlanes:
-    @pytest.mark.parametrize("shortlist_k", [0, 4])
-    def test_class_planes_match_per_pod_reference(self, shortlist_k):
-        """parallel/sharded.py's class form (rows/exc/row_req) against
-        the single-chip per-pod reference: pods gather class rows, the
-        exception column translates to shard-local coordinates (the
-        pinned column lives on a non-zero shard), and the shard-local
-        prefilter runs over C class rows."""
-        import numpy as np
-        import jax
-        import jax.numpy as jnp
-        from kubernetes_tpu.ops import solver
-        from kubernetes_tpu.parallel import build_mesh, sharded_greedy_assign
-
-        if len(jax.devices()) < 4:
-            pytest.skip("not enough devices")
-        rng = np.random.default_rng(23)
-        N, P, C, R = 32, 8, 2, 2
-        alloc_q = rng.integers(8_000, 32_000, size=(N, R)).astype(np.int32)
-        used_q = (alloc_q * 0.2).astype(np.int32)
-        free_pods = np.full((N,), 110, np.int32)
-        c_req = rng.integers(500, 4_000, size=(C, R)).astype(np.int32)
-        cls = (np.arange(P) % C).astype(np.int32)
-        req_q = c_req[cls]
-        mask_c = rng.random((C, N)) < 0.9
-        sc_c = rng.uniform(0, 5, size=(C, N)).astype(np.float32)
-        exc = np.full((P,), -1, np.int32)
-        exc[3] = 27   # pinned into the last shard of a 4-way mesh
-        exc[5] = 2
-        # Per-pod reference: gather class rows, fold pins into the mask.
-        mask_p = mask_c[cls].copy()
-        sc_p = sc_c[cls]
-        for i, e in enumerate(exc):
-            if e >= 0:
-                keep = mask_p[i, e]
-                mask_p[i, :] = False
-                mask_p[i, e] = keep
-        shape = (np.zeros((2,), np.float32), np.zeros((2,), np.float32))
-        col_w = np.ones((R,), np.float32)
-        col_m = np.ones((R,), np.bool_)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(free_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q),
-            jnp.asarray(mask_p), jnp.asarray(sc_p),
-            jnp.asarray(col_w), jnp.asarray(col_m),
-            jnp.asarray(shape[0]), jnp.asarray(shape[1]),
-            jnp.float32(1.0), jnp.float32(1.0),
-            strategy="LeastAllocated"))
-        sharded = np.asarray(sharded_greedy_assign(
-            build_mesh(4), jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(free_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q),
-            jnp.asarray(mask_c), jnp.asarray(sc_c),
-            jnp.asarray(col_w), jnp.asarray(col_m),
-            shape[0], shape[1], 1.0, 1.0, "LeastAllocated",
-            shortlist_k=shortlist_k, rows=cls, exc=exc,
-            row_req_q=c_req, row_req_nz_q=c_req))
-        np.testing.assert_array_equal(single, sharded)
-        assert sharded[3] in (27, -1)
-        if sharded[3] >= 0:
-            assert sharded[3] == 27
 
 
 async def _schedule_e2e(store, nodes, pods, batch: int = 64) -> dict:

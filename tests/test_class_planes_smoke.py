@@ -2,7 +2,7 @@
 
 Pins: (a) class planes ACTIVE by default — a template chunk ships ONE
 class row, a mixed chunk a handful, and the plane-byte/prep metrics
-flow; (b) the KTPU_CLASS_PLANES=0 kill switch degrading structurally to
+flow; (b) the KTPU_CLASS_PAD=0 kill switch degrading structurally to
 per-pod planes (C == P) with identical assignments; (c) the exception
 list carrying single-column host rows (NodeName pins) without splitting
 a class; (d) the KTPU_CLASS_PAD overflow fallback counting its pods.
@@ -45,12 +45,11 @@ def _backend(chunk=16):
 
 class TestClassPlaneKnobs:
     def test_default_cap_and_bucket(self, monkeypatch):
-        monkeypatch.delenv("KTPU_CLASS_PLANES", raising=False)
         monkeypatch.delenv("KTPU_CLASS_PAD", raising=False)
         assert class_pad() == 31
         monkeypatch.setenv("KTPU_CLASS_PAD", "7")
         assert class_pad() == 7
-        monkeypatch.setenv("KTPU_CLASS_PLANES", "0")
+        monkeypatch.setenv("KTPU_CLASS_PAD", "0")
         assert class_pad() == 0
         # Plane rows: power-of-two buckets with the reserved empty row 0.
         assert _class_rows_bucket(0) == 2
@@ -62,7 +61,7 @@ class TestClassPlaneKnobs:
 
 class TestActiveByDefault:
     def test_template_chunk_ships_one_class(self, monkeypatch):
-        monkeypatch.delenv("KTPU_CLASS_PLANES", raising=False)
+        monkeypatch.delenv("KTPU_CLASS_PAD", raising=False)
         from test_tpu_backend import default_fwk
         snap = _uniform_cluster(100)
         pods = _template_pods(35)  # partial last chunk: padding rides
@@ -81,10 +80,10 @@ class TestActiveByDefault:
         snap = _uniform_cluster(100)
         pods = _template_pods(32)
         fwk = default_fwk()
-        monkeypatch.delenv("KTPU_CLASS_PLANES", raising=False)
+        monkeypatch.delenv("KTPU_CLASS_PAD", raising=False)
         on = _backend(chunk=16)
         a_on, _ = on.assign(pods, snap, fwk)
-        monkeypatch.setenv("KTPU_CLASS_PLANES", "0")
+        monkeypatch.setenv("KTPU_CLASS_PAD", "0")
         off = _backend(chunk=16)
         a_off, _ = off.assign(pods, snap, fwk)
         assert a_on == a_off
@@ -99,7 +98,7 @@ class TestActiveByDefault:
         template (C == 1), lands exactly on the named node — exercised
         under the SHORTLIST regime so the pinned-pod bound-check
         fallback runs too (N=150 ≥ 4·(K+chunk))."""
-        monkeypatch.delenv("KTPU_CLASS_PLANES", raising=False)
+        monkeypatch.delenv("KTPU_CLASS_PAD", raising=False)
         from test_tpu_backend import default_fwk
         snap = _uniform_cluster(150)
         pods = _template_pods(16)
@@ -116,7 +115,6 @@ class TestActiveByDefault:
         assert m.solver_shortlist_pods.value() == len(pods)
 
     def test_overflow_fallback_counts_pods(self, monkeypatch):
-        monkeypatch.delenv("KTPU_CLASS_PLANES", raising=False)
         monkeypatch.setenv("KTPU_CLASS_PAD", "2")
         from test_tpu_backend import default_fwk
         snap = _uniform_cluster(60)

@@ -1,104 +1,12 @@
-"""Mesh-sharded path: differential vs the single-chip solver on the 8-device
-virtual CPU mesh (conftest forces xla_force_host_platform_device_count=8)."""
+"""Mesh-sharded path on the 8-device virtual CPU mesh (conftest forces
+xla_force_host_platform_device_count=8): the driver entry points and the
+backend on a (slice × nodes) mesh. The differentials against one device
+are tests/test_mesh_parity_plain.py and tests/test_mesh_parity_spread.py."""
 
 import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
-
-from kubernetes_tpu.ops import solver
-from kubernetes_tpu.parallel import (
-    build_mesh,
-    build_mesh_2d,
-    sharded_greedy_assign,
-    sharded_masks_scores,
-)
-
-
-def synthetic(P=12, N=64, R=2, seed=3):
-    rng = np.random.default_rng(seed)
-    alloc_q = rng.integers(4_000, 64_000, size=(N, R)).astype(np.int32)
-    used_q = (alloc_q * rng.uniform(0, 0.5, size=(N, R))).astype(np.int32)
-    alloc_pods = np.full((N,), 110, np.int32)
-    used_pods = rng.integers(0, 30, size=(N,)).astype(np.int32)
-    req_q = rng.integers(100, 9_000, size=(P, R)).astype(np.int32)
-    mask = rng.random((P, N)) < 0.9
-    static_sc = rng.uniform(0, 10, size=(P, N)).astype(np.float32)
-    col_w = np.ones((R,), np.float32)
-    col_mask = np.ones((R,), np.bool_)
-    return alloc_q, used_q, alloc_pods, used_pods, req_q, mask, static_sc, \
-        col_w, col_mask
-
-
-class TestShardedSolver:
-    @pytest.mark.parametrize("n_devices", [1, 2, 8])
-    def test_matches_single_chip(self, n_devices):
-        if len(jax.devices()) < n_devices:
-            pytest.skip("not enough devices")
-        (alloc_q, used_q, alloc_pods, used_pods, req_q, mask, static_sc,
-         col_w, col_mask) = synthetic()
-        single = np.asarray(solver.greedy_assign_rescoring(
-            jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(alloc_pods - used_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q), jnp.asarray(mask),
-            jnp.asarray(static_sc), jnp.asarray(col_w), jnp.asarray(col_mask),
-            jnp.zeros((2,), jnp.float32), jnp.zeros((2,), jnp.float32),
-            1.0, 1.0, strategy="LeastAllocated"))
-        mesh = build_mesh(n_devices)
-        sharded = np.asarray(sharded_greedy_assign(
-            mesh, jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(alloc_pods - used_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q), jnp.asarray(mask),
-            jnp.asarray(static_sc), jnp.asarray(col_w), jnp.asarray(col_mask),
-            np.zeros((2,), np.float32), np.zeros((2,), np.float32),
-            1.0, 1.0, "LeastAllocated"))
-        np.testing.assert_array_equal(single, sharded)
-
-    def test_capacity_never_overcommitted(self):
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 devices")
-        (alloc_q, used_q, alloc_pods, used_pods, req_q, mask, static_sc,
-         col_w, col_mask) = synthetic(P=40, N=16, seed=9)
-        mesh = build_mesh(8)
-        assign = np.asarray(sharded_greedy_assign(
-            mesh, jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(alloc_pods - used_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q), jnp.asarray(mask),
-            jnp.asarray(static_sc), jnp.asarray(col_w), jnp.asarray(col_mask),
-            np.zeros((2,), np.float32), np.zeros((2,), np.float32),
-            1.0, 1.0, "LeastAllocated"))
-        spent = np.zeros_like(alloc_q)
-        for i, n in enumerate(assign):
-            if n >= 0:
-                assert mask[i, n]
-                spent[n] += req_q[i]
-        assert (used_q + spent <= alloc_q).all()
-
-
-class TestMasksScores2D:
-    def test_2d_mesh_phase_runs(self):
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 devices")
-        mesh = build_mesh_2d(8)
-        P = 4 * mesh.shape["pods"]
-        N = 16 * mesh.shape["nodes"]
-        (alloc_q, used_q, alloc_pods, used_pods, req_q, _, _, col_w,
-         col_mask) = synthetic(P=P, N=N)
-        static_mask = np.ones((P, N), np.bool_)
-        taint = np.zeros((N, 1), np.bool_)
-        untol = np.zeros((P, 1), np.bool_)
-        host_scores = np.zeros((P, N), np.float32)
-        mask, feasible, static_sc = sharded_masks_scores(
-            mesh, jnp.asarray(alloc_q), jnp.asarray(used_q),
-            jnp.asarray(used_q), jnp.asarray(alloc_pods),
-            jnp.asarray(used_pods), jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(untol), jnp.asarray(untol), jnp.asarray(taint),
-            jnp.asarray(taint), jnp.asarray(static_mask),
-            jnp.asarray(host_scores), 3.0, True, "LeastAllocated")
-        assert np.asarray(mask).shape == (P, N)
-        assert np.asarray(static_sc).shape == (P, N)
-        assert np.isfinite(np.asarray(static_sc)[np.asarray(feasible)]).all()
 
 
 class TestGraftEntry:
@@ -118,60 +26,7 @@ class TestGraftEntry:
 
 
 class TestMultisliceSolver:
-    """Config #5: (slice × nodes) mesh with hierarchical ICI→DCN argmax."""
-
-    @pytest.mark.parametrize("shape", [(2, 4), (4, 2), (2, 2)])
-    def test_matches_single_chip(self, shape):
-        from kubernetes_tpu.parallel import (
-            build_multislice_mesh,
-            sharded_greedy_assign_multislice,
-        )
-        s, c = shape
-        if len(jax.devices()) < s * c:
-            pytest.skip("not enough devices")
-        (alloc_q, used_q, alloc_pods, used_pods, req_q, mask, static_sc,
-         col_w, col_mask) = synthetic(P=16, N=128, seed=7)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(alloc_pods - used_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q), jnp.asarray(mask),
-            jnp.asarray(static_sc), jnp.asarray(col_w), jnp.asarray(col_mask),
-            jnp.zeros((2,), jnp.float32), jnp.zeros((2,), jnp.float32),
-            1.0, 1.0, strategy="LeastAllocated"))
-        mesh = build_multislice_mesh(s, c)
-        ms = np.asarray(sharded_greedy_assign_multislice(
-            mesh, jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(alloc_pods - used_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q), jnp.asarray(mask),
-            jnp.asarray(static_sc), jnp.asarray(col_w), jnp.asarray(col_mask),
-            np.zeros((2,), np.float32), np.zeros((2,), np.float32),
-            1.0, 1.0, "LeastAllocated"))
-        np.testing.assert_array_equal(single, ms)
-
-    def test_50k_node_width(self):
-        """The 50k-node problem width (config #5) solves on the (2×4)
-        virtual multi-slice mesh: 51200 node rows, capacity respected."""
-        from kubernetes_tpu.parallel import (
-            build_multislice_mesh,
-            sharded_greedy_assign_multislice,
-        )
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 devices")
-        (alloc_q, used_q, alloc_pods, used_pods, req_q, mask, static_sc,
-         col_w, col_mask) = synthetic(P=32, N=51_200, seed=11)
-        mesh = build_multislice_mesh(2, 4)
-        assign = np.asarray(sharded_greedy_assign_multislice(
-            mesh, jnp.asarray(req_q), jnp.asarray(req_q),
-            jnp.asarray(alloc_q - used_q), jnp.asarray(alloc_pods - used_pods),
-            jnp.asarray(used_q), jnp.asarray(alloc_q), jnp.asarray(mask),
-            jnp.asarray(static_sc), jnp.asarray(col_w), jnp.asarray(col_mask),
-            np.zeros((2,), np.float32), np.zeros((2,), np.float32),
-            1.0, 1.0, "LeastAllocated"))
-        assert (assign >= 0).all()  # plenty of room at this width
-        spent = np.zeros_like(alloc_q)
-        for i, n in enumerate(assign):
-            spent[n] += req_q[i]
-        assert (used_q + spent <= alloc_q).all()
+    """Config #5: (slice × nodes) mesh."""
 
     def test_backend_on_multislice_mesh(self):
         """TPUBackend accepts a (slice × nodes) mesh: the fused program

@@ -131,30 +131,3 @@ class TestBackendSmoke:
         meshed, _ = TPUBackend(max_batch=16, mesh=build_mesh(1)).assign(
             pods, snap, fwk)
         assert plain == meshed
-
-
-class TestShardedDegrade:
-    def test_one_shard_mesh_matches_single_chip_solver(self):
-        import numpy as np
-        import jax.numpy as jnp
-        from kubernetes_tpu.ops import solver
-        from kubernetes_tpu.parallel import build_mesh, sharded_greedy_assign
-        rng = np.random.default_rng(5)
-        N, P, R = 32, 6, 2
-        alloc_q = rng.integers(8_000, 32_000, size=(N, R)).astype(np.int32)
-        used_q = (alloc_q * 0.2).astype(np.int32)
-        req_q = rng.integers(500, 4_000, size=(P, R)).astype(np.int32)
-        mask = np.ones((P, N), np.bool_)
-        sc = rng.uniform(0, 5, size=(P, N)).astype(np.float32)
-        args = [jnp.asarray(x) for x in (
-            req_q, req_q, alloc_q - used_q,
-            np.full((N,), 110, np.int32), used_q, alloc_q, mask, sc,
-            np.ones((R,), np.float32), np.ones((R,), np.bool_),
-            np.zeros((2,), np.float32), np.zeros((2,), np.float32))] \
-            + [jnp.float32(1.0), jnp.float32(1.0)]
-        single = np.asarray(solver.greedy_assign_rescoring(
-            *args, strategy="LeastAllocated"))
-        for k in (0, 4):
-            sharded = np.asarray(sharded_greedy_assign(
-                build_mesh(1), *args, "LeastAllocated", shortlist_k=k))
-            np.testing.assert_array_equal(single, sharded)

@@ -300,44 +300,6 @@ class TestSpreadParity:
 
 
 # ---------------------------------------------------------------------------
-# sharded path (8-virtual-device CPU mesh, conftest-forced)
-# ---------------------------------------------------------------------------
-
-class TestShardedParity:
-    @pytest.mark.parametrize("n_devices", [1, 2, 8])
-    @pytest.mark.parametrize("k", [3, 8])
-    def test_matches_single_chip(self, n_devices, k):
-        if len(jax.devices()) < n_devices:
-            pytest.skip("not enough devices")
-        from kubernetes_tpu.parallel import build_mesh, sharded_greedy_assign
-        rng = np.random.default_rng(11)
-        d = synthetic(rng, P=12, N=64)
-        args = solver_args(d)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            *args, strategy="LeastAllocated"))
-        sharded = np.asarray(sharded_greedy_assign(
-            build_mesh(n_devices), *args, "LeastAllocated", shortlist_k=k))
-        np.testing.assert_array_equal(single, sharded)
-
-    def test_multislice_with_shortlist(self):
-        if len(jax.devices()) < 8:
-            pytest.skip("needs 8 devices")
-        from kubernetes_tpu.parallel import build_multislice_mesh
-        from kubernetes_tpu.parallel.sharded import (
-            sharded_greedy_assign_multislice,
-        )
-        rng = np.random.default_rng(13)
-        d = synthetic(rng, P=12, N=64)
-        args = solver_args(d)
-        single = np.asarray(solver.greedy_assign_rescoring(
-            *args, strategy="LeastAllocated"))
-        ms = np.asarray(sharded_greedy_assign_multislice(
-            build_multislice_mesh(2, 4), *args, "LeastAllocated",
-            shortlist_k=4))
-        np.testing.assert_array_equal(single, ms)
-
-
-# ---------------------------------------------------------------------------
 # backend end to end: forced-on vs forced-off must agree, classes shared
 # ---------------------------------------------------------------------------
 

@@ -316,14 +316,14 @@ class TestFlagRegistry:
     def test_registry_contract(self):
         """Every flag: registered, documented, expected default — and
         NAMED here, which is what the FL304 'every flag has a test'
-        check greps for: KTPU_SERVING, KTPU_CLASS_PLANES,
-        KTPU_WAVEFRONT, KTPU_PALLAS, KTPU_WAVE_WIDTH, KTPU_SOLVE_MODE,
+        check greps for: KTPU_SERVING,
+        KTPU_WAVEFRONT, KTPU_WAVE_WIDTH, KTPU_SOLVE_MODE,
         KTPU_SINKHORN_ITERS, KTPU_SINKHORN_TEMP, KTPU_DESCHEDULER,
         KTPU_DESCHEDULER_BUDGET, KTPU_TOPOLOGY, KTPU_MESH_SHAPE,
         KTPU_WATCH_CACHE,
         KTPU_POLICY_INDEX, KTPU_SHARDS,
         KTPU_SHARD_THRESHOLD, KTPU_CLASS_PAD, KTPU_PIPELINE_DEPTH,
-        KTPU_SHORTLIST_K, KTPU_BLOCK_INDEX, KTPU_BLOCK_WIDTH,
+        KTPU_SHORTLIST_K, KTPU_BLOCK_WIDTH,
         KTPU_ADMISSION_WINDOW,
         KTPU_TRACE_THRESHOLD_MS, KTPU_DATA_DIR, KTPU_LOCK_CHECK,
         KTPU_DEBUG_FREEZE, KTPU_TEST_PLATFORM, KTPU_PROCESSES,
@@ -331,9 +331,7 @@ class TestFlagRegistry:
         from kubernetes_tpu.utils import flags
         expected_defaults = {
             "KTPU_SERVING": True,
-            "KTPU_CLASS_PLANES": True,
             "KTPU_WAVEFRONT": True,
-            "KTPU_PALLAS": "auto",
             "KTPU_WAVE_WIDTH": None,
             "KTPU_SOLVE_MODE": "auto",
             "KTPU_SINKHORN_ITERS": 24,
@@ -353,7 +351,6 @@ class TestFlagRegistry:
             "KTPU_CLASS_PAD": 31,
             "KTPU_PIPELINE_DEPTH": None,
             "KTPU_SHORTLIST_K": None,
-            "KTPU_BLOCK_INDEX": True,
             "KTPU_BLOCK_WIDTH": None,
             "KTPU_ADMISSION_WINDOW": None,
             "KTPU_TRACE_THRESHOLD_MS": None,
@@ -367,13 +364,12 @@ class TestFlagRegistry:
             assert flags.FLAGS[name].default == default, name
             assert flags.FLAGS[name].doc.strip(), name
         kills = {n for n, f in flags.FLAGS.items() if f.kill_switch}
-        assert kills == {"KTPU_SERVING", "KTPU_CLASS_PLANES",
-                         "KTPU_WAVEFRONT", "KTPU_PALLAS",
+        assert kills == {"KTPU_SERVING",
+                         "KTPU_WAVEFRONT",
                          "KTPU_SOLVE_MODE", "KTPU_TOPOLOGY",
                          "KTPU_WATCH_CACHE",
                          "KTPU_POLICY_INDEX", "KTPU_SHARDS",
-                         "KTPU_PROCESSES", "KTPU_WAL",
-                         "KTPU_BLOCK_INDEX"}
+                         "KTPU_PROCESSES", "KTPU_WAL"}
 
     def test_parse_behaviors(self, monkeypatch):
         from kubernetes_tpu.utils import flags
@@ -479,8 +475,8 @@ class TestMetricsLint:
     def test_block_index_counters_visible_to_pass(self):
         """Non-vacuity for the ISSUE 20 block-index metrics: the lint
         pass actually reaches the live registrations (the scanned /
-        pruned counters the KTPU_BLOCK_INDEX flag gates, plus the
-        resident refresh histogram) — and finds them clean. A rename
+        pruned counters the KTPU_BLOCK_WIDTH flag gates) — and finds
+        them clean. A rename
         that dropped the _total/_seconds suffixes, or a registration
         moved out of the scanned set, fails here instead of silently
         exempting the new names."""
@@ -490,8 +486,7 @@ class TestMetricsLint:
         names = {name for m in mods
                  for _k, name, _l, _ln in metrics_lint._registrations(m)}
         assert {"scheduler_tpu_solver_blocks_scanned_total",
-                "scheduler_tpu_solver_blocks_pruned_total",
-                "scheduler_tpu_solver_block_refresh_seconds"} <= names
+                "scheduler_tpu_solver_blocks_pruned_total"} <= names
         assert metrics_lint.run(mods) == []
 
     def test_real_registry_would_catch_ms_gauge(self, tmp_path):
@@ -616,10 +611,9 @@ class TestTierOneGate:
         assert "kubernetes_tpu/ops/kernels.py" in rels, \
             "call graph no longer reaches the kernels"
         # ...and the walk must reach the wave-step/replay bodies (new
-        # lax.scan / fori_loop callees nested under the entries) in both
-        # the single-device and the shard_map solvers — an emptied
-        # reachable set here would let host syncs into the wave bodies
-        # pass the gate forever.
+        # lax.scan / fori_loop callees nested under the entries) — an
+        # emptied reachable set here would let host syncs into the wave
+        # bodies pass the gate forever.
         solver_reach = {qn for rel, qn in reach
                         if rel == "kubernetes_tpu/ops/solver.py"}
         for qn in ("_rescoring_wave_scan.wave_step",
@@ -630,39 +624,17 @@ class TestTierOneGate:
             assert qn in solver_reach, \
                 f"purity walk no longer reaches {qn}"
         # The r20 optimal mode adds the Sinkhorn iteration body (a
-        # fori_loop callee under the jitted plan) in both the plain and
-        # the shard_map solvers — same anti-vacuity stake: a host sync
-        # inside the transport loop must stay visible to the gate.
+        # fori_loop callee under the jitted plan) — same anti-vacuity
+        # stake: a host sync inside the transport loop must stay visible
+        # to the gate.
         assert "sinkhorn_plan" in solver_entries, \
             "sinkhorn_plan not discovered as a jit entry"
         assert "sinkhorn_plan.step" in solver_reach, \
             "purity walk no longer reaches the Sinkhorn iteration body"
-        sharded_reach = {qn for rel, qn in reach
-                         if rel == "kubernetes_tpu/parallel/sharded.py"}
-        assert any(qn.endswith("_wave_body.wave_step")
-                   for qn in sharded_reach), \
-            "purity walk no longer reaches the sharded wave body"
-        assert any(qn.endswith("sink_run.step")
-                   for qn in sharded_reach), \
-            "purity walk no longer reaches the sharded Sinkhorn body"
-        # The r21 fused Pallas kernel: pl.pallas_call is a trace
-        # wrapper, so the nested kernel BODIES (the grid-step solve and
-        # the shard-local wave eval, including the in-kernel conflict
-        # replay fori_loop) are entry points in their own right — a
-        # host sync inside a kernel body fails at runtime on real
-        # lowering, so it must stay visible to the gate here.
-        pallas_entries = entry_map["kubernetes_tpu/ops/pallas_kernel.py"]
-        for fn in ("wave_solve._wave_step_kernel",
-                   "wave_eval._wave_eval_kernel"):
-            assert fn in pallas_entries, \
-                f"pallas kernel body {fn} not discovered"
-        pallas_reach = {qn for rel, qn in reach
-                        if rel == "kubernetes_tpu/ops/pallas_kernel.py"}
-        assert "wave_solve._wave_step_kernel.slow.body" in pallas_reach, \
-            "purity walk no longer reaches the in-kernel replay body"
-        # The pallas entry wrappers in ops/solver.py are jit entries too.
-        assert "greedy_assign_rescoring_wave_pallas" in solver_entries
-        assert "multistart_greedy_assign_wave_pallas" in solver_entries
+        # The resident planes' row scatter (the one jitted body outside
+        # ops/ that every batch assign runs) is an entry too.
+        assert "resident_row_scatter.body" in \
+            entry_map["kubernetes_tpu/serving/resident.py"]
         # ISSUE 20's block-sparse prefilter: the lax.cond branch bodies
         # (exact accept vs whole-chunk full-width fallback) are named
         # functions passed to a trace wrapper — entry points in their

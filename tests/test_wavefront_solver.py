@@ -560,54 +560,6 @@ class TestSpreadWaveParity:
         assert int(rep) == 0 and int(com) == p
 
 
-class TestShardedWaveParity:
-    @pytest.mark.parametrize("shards", [1, 4, 8])
-    def test_mesh_bit_identity(self, shards):
-        from kubernetes_tpu.parallel import build_mesh, \
-            sharded_greedy_assign
-        if len(jax.devices()) < shards:
-            pytest.skip("not enough devices")
-        rng = np.random.default_rng(700 + shards)
-        n, p, r = 64, 18, 2
-        args, _ = _problem(rng, n=n, p=p, r=r)
-        mesh = build_mesh(shards)
-        ref = np.asarray(solver.greedy_assign_rescoring(
-            strategy="LeastAllocated", **args))
-        pos = (args["req_q"], args["req_nz_q"], args["free_q"],
-               args["free_pods"], args["used_nz_q"], args["alloc_q"],
-               args["mask"], args["static_scores"], args["fit_col_w"],
-               args["bal_col_mask"], args["shape_u"], args["shape_s"],
-               args["w_fit"], args["w_bal"])
-        for w in (0, 1, 2, 8):
-            got = np.asarray(sharded_greedy_assign(
-                mesh, *pos, "LeastAllocated", wave_w=w))
-            np.testing.assert_array_equal(got, ref,
-                                          err_msg=f"shards={shards} W={w}")
-
-    def test_mesh_exceptions_global_coords(self):
-        """Pinned columns are GLOBAL node ids; owner-shard translation
-        must keep them exact across shard counts."""
-        from kubernetes_tpu.parallel import build_mesh, \
-            sharded_greedy_assign
-        rng = np.random.default_rng(800)
-        n, p, r = 64, 12, 2
-        args, _ = _problem(rng, n=n, p=p, r=r)
-        exc = np.full((p,), -1, np.int32)
-        exc[[1, 5, 9]] = [60, 3, 33]
-        ref = np.asarray(solver.greedy_assign_rescoring(
-            strategy="LeastAllocated", exc=jnp.asarray(exc), **args))
-        pos = (args["req_q"], args["req_nz_q"], args["free_q"],
-               args["free_pods"], args["used_nz_q"], args["alloc_q"],
-               args["mask"], args["static_scores"], args["fit_col_w"],
-               args["bal_col_mask"], args["shape_u"], args["shape_s"],
-               args["w_fit"], args["w_bal"])
-        for shards in _mesh_sizes():
-            got = np.asarray(sharded_greedy_assign(
-                build_mesh(shards), *pos, "LeastAllocated",
-                exc=jnp.asarray(exc), wave_w=4))
-            np.testing.assert_array_equal(got, ref)
-
-
 class TestBackendE2EParity:
     def test_backend_wave_vs_kill_switch(self):
         """End-to-end through TPUBackend: flagless wavefront assignments
