@@ -600,6 +600,19 @@ class SchedulerMetrics:
             "scheduler_tpu_solver_wave_replays_total",
             "Pods placed through the wavefront solve's exact serial "
             "replay")
+        #: Steps of the chunk scan a dispatched chunk runs, and those its
+        #: padding would have cost it: a chunk is padded to P pods, the
+        #: scan walks ceil(p_real / W) steps of the P / W (W = 1 for a
+        #: serial scan). Counted on the host from what the program is
+        #: handed — no device read-back. skipped / (run + skipped) is the
+        #: share of the padded scan that the traffic leaves unused: ~97%
+        #: for a trickled chunk of two pods, ~50% in a drain.
+        self.solver_scan_steps = r.counter(
+            "scheduler_tpu_solver_scan_steps_total",
+            "Chunk-scan steps by whether the chunk's real pods reached "
+            "them", labels=("kind",))
+        for kind in ("run", "skipped"):
+            self.solver_scan_steps.inc(0, kind=kind)
         #: Global-assignment observability (r20): chunks solved through
         #: the Sinkhorn transport plan + feasible rounding, chunks the
         #: tuner WANTED optimal but degraded to greedy (spread strategy

@@ -659,7 +659,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                        dom_onehot, cid_onehot, dom_counts, max_skew,
                        sp_min_ok, sp_haskey,
                        sp_applies, sp_contrib, perms, gang_onehot,
-                       gang_required, sink_iters, sink_temp, n_real,
+                       gang_required, sink_iters, sink_temp, n_real, p_real,
                        strategy: str, use_spread: bool, shortlist_k: int,
                        wave_w: int, solve_mode: str = "greedy",
                        block_w: int = 0):
@@ -752,6 +752,14 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     excludes bucket-padding columns from the aggregates. block_w == 0 is
     the KTPU_BLOCK_WIDTH=0 kill-switch shape: the full-width r18/r21
     prefilter call graph, structurally.
+
+    `p_real` (traced int32) is the chunk's real pod count. The chunk is
+    padded to P so that one program serves every chunk; the padding sits
+    at its end, places nothing and moves no state, so every scan stops
+    after `p_real` pods (ceil(p_real / W) waves) instead of walking the
+    padded width (ops/solver.py `_scan_real`). Traced, not static: a
+    trickled chunk of two pods and a full one run the same executable,
+    and every output keeps its padded shape and contents.
 
     Returns (assign (P+5,) — the tail is [shortlist fallbacks, wave
     commits, wave replays, blocks scanned, blocks pruned] riding the one
@@ -871,7 +879,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                     dom_onehot, cid_onehot, dom_counts, max_skew,
                     sp_min_ok, sp_haskey, sp_applies, sp_contrib,
                     sc0, cls_idx, cand_s[cls_idx], thresh_s[cls_idx],
-                    has_node, rows=cls_idx, exc=exc_col)
+                    has_node, rows=cls_idx, exc=exc_col, p_real=p_real)
         elif wave_w > 1:
             a0, dom_counts2, wave_com, wave_rep = \
                 solver.greedy_assign_rescoring_spread_wave(
@@ -880,7 +888,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                     shape_s, w_fit, w_bal, strategy, wave_w,
                     dom_onehot, cid_onehot, dom_counts, max_skew,
                     sp_min_ok, sp_haskey, sp_applies, sp_contrib,
-                    rows=cls_idx, exc=exc_col)
+                    rows=cls_idx, exc=exc_col, p_real=p_real)
         else:
             a0, dom_counts2 = solver.greedy_assign_rescoring_spread(
                 req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
@@ -888,7 +896,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                 w_fit, w_bal, strategy,
                 dom_onehot, cid_onehot, dom_counts, max_skew,
                 sp_min_ok, sp_haskey, sp_applies, sp_contrib,
-                rows=cls_idx, exc=exc_col)
+                rows=cls_idx, exc=exc_col, p_real=p_real)
         assign = solver.gang_filter(a0, gang_onehot, gang_required)
         # Gang-dropped pods bumped the chained counts in-scan (for the
         # constraints they CONTRIBUTE to) — fold them back out so later
@@ -915,14 +923,16 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                     mask, static_scores, fit_col_w, bal_col_mask, shape_u,
                     shape_s, w_fit, w_bal, strategy, wave_w, perms,
                     gang_onehot, gang_required, cls_idx, cand_s, sl_val,
-                    thresh_s, has_node, rows=cls_idx, exc=exc_col)
+                    thresh_s, has_node, rows=cls_idx, exc=exc_col,
+                    p_real=p_real)
         elif shortlist_k:
             assign, nfall = solver.multistart_greedy_assign_shortlist(
                 req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
                 mask, static_scores, fit_col_w, bal_col_mask, shape_u,
                 shape_s, w_fit, w_bal, strategy, perms, gang_onehot,
                 gang_required, sc0, cls_idx, cand_s[cls_idx],
-                thresh_s[cls_idx], has_node, rows=cls_idx, exc=exc_col)
+                thresh_s[cls_idx], has_node, rows=cls_idx, exc=exc_col,
+                p_real=p_real)
         elif wave_w > 1:
             assign, wave_com, wave_rep = \
                 solver.multistart_greedy_assign_wave(
@@ -930,13 +940,14 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                     alloc_q, mask, static_scores, fit_col_w,
                     bal_col_mask, shape_u, shape_s, w_fit, w_bal,
                     strategy, wave_w, perms, gang_onehot,
-                    gang_required, rows=cls_idx, exc=exc_col)
+                    gang_required, rows=cls_idx, exc=exc_col,
+                    p_real=p_real)
         else:
             assign = solver.multistart_greedy_assign(
                 req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
                 static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
                 w_fit, w_bal, strategy, perms, gang_onehot, gang_required,
-                rows=cls_idx, exc=exc_col)
+                rows=cls_idx, exc=exc_col, p_real=p_real)
 
     # Post-assignment state update (scatter-add of assigned requests).
     # Padding/unassigned rows scatter to a dummy row (index N, dropped).
@@ -2909,13 +2920,20 @@ class TPUBackend:
                 prep["dev_perms"], *self._gang_args(prep, batch),
                 np.int32(max(1, flags.get("KTPU_SINKHORN_ITERS"))),
                 np.float32(flags.get("KTPU_SINKHORN_TEMP")),
-                np.int32(ct.n_real),
+                np.int32(ct.n_real), np.int32(batch.p_real),
                 p["strategy"], use_spread, prep["shortlist_k"],
                 prep["wave_w"], solve_mode, prep["block_w"],
             )
         self._dev_used = used_pack2
         if use_spread:
             sp["dev_counts"] = dom_counts2
+        if self.metrics is not None:
+            # The scan's trip count as the program was just handed it.
+            w = max(prep["wave_w"], 1)
+            run = -(-batch.p_real // w)
+            self.metrics.solver_scan_steps.inc(run, kind="run")
+            self.metrics.solver_scan_steps.inc(
+                batch.req_q.shape[0] // w - run, kind="skipped")
         # Start the device→host copy now; the fetch in _finalize_chunk then
         # overlaps the next chunk's solve (and, in assign_async, bind tasks).
         assign_d.copy_to_host_async()

@@ -34,7 +34,10 @@ length drops P → P/W in the low-conflict regime. See
 `greedy_assign_rescoring_wave` for the speculation/replay contract.
 
 Both are shape-static, jit-compiled once per (P, N, R) signature, and emit
-`(P,) int32` node indices with -1 = unschedulable-this-cycle.
+`(P,) int32` node indices with -1 = unschedulable-this-cycle. P is the
+PADDED chunk width; how many of its steps run follows the chunk's real pod
+count `p_real`, a traced scalar every chunk scan takes (`_scan_real`): a
+trickled chunk of two pods runs one wave step, not the 32 of 1,024 pods.
 
 Class-dictionary planes: every scan reads `mask`/`static_scores` as
 CLOSED-OVER planes addressed per step through `rows` — a (P,) row index
@@ -59,6 +62,60 @@ import jax.numpy as jnp
 from jax import lax
 
 NEG_INF = -jnp.inf
+
+
+def _scan_real(step, carry0, xs, n_steps=None, y_shape=()):
+    """`lax.scan(step, carry0, xs)` over the first `n_steps` steps only,
+    for a step whose stacked output is one int32 array of node indices
+    (`y_shape` a step: () per pod, (W,) per wave).
+
+    Every chunk scan below runs over a chunk padded to a fixed width, and
+    a padded step places nothing and moves no state (its mask row is
+    all-False) — it only costs a full-width pass. `n_steps` (traced int32 scalar: the chunk's real
+    steps; None = all of them) bounds the loop instead, so the trip count
+    follows the chunk's real pods while every shape stays the padded one:
+    the stacked outputs are preallocated at full length holding -1, which
+    is what a padded step writes.
+
+    The predicate reads the step index and `n_steps` alone. Callers under
+    the multistart vmap close `n_steps` over from OUTSIDE the vmapped
+    function (every order keeps padding in place, so all orders share one
+    real prefix): the predicate then stays unbatched and the loop a loop.
+    A batched predicate would lower to a select that runs every step.
+    """
+    length = jax.tree.leaves(xs)[0].shape[0]
+    if n_steps is None:
+        n_steps = length
+    steps = jnp.arange(length, dtype=jnp.int32).reshape(
+        (length,) + (1,) * len(y_shape))
+    # Tie the initial carry to the inputs (a select between a value and
+    # itself: nothing at run time). Under the multistart vmap the carry
+    # is then batched from the start, as it is after the first step
+    # anyway, and JAX's batching rule for `while` finds its fixpoint in
+    # one pass over the body in place of two — the W-unrolled wave step
+    # is thousands of equations, and the extra pass read as +10–17% of a
+    # cell's set-up on the chip's host (PERF.md, PR 31).
+    lead = jax.tree.leaves(xs)[0]
+    never = lead[(0,) * lead.ndim] != lead[(0,) * lead.ndim]
+    carry0, ys0 = jax.tree.map(
+        lambda c: jnp.where(never, c, c),
+        (carry0, jnp.full((length,) + y_shape, -1, jnp.int32)))
+
+    def body(state):
+        i, carry, ys = state
+        carry, y = step(carry, jax.tree.map(
+            lambda x: lax.dynamic_index_in_dim(
+                x, i, 0, keepdims=False, allow_negative_indices=False), xs))
+        # Row i of the few-KB output by a select, not by a
+        # dynamic-update-slice: under the multistart vmap that would
+        # batch into a scatter — a bounds check, a read and a select
+        # before the write, three more kernels a step on the TPU.
+        return i + 1, carry, jnp.where(steps == i, y, ys)
+
+    _, carry, ys = lax.while_loop(
+        lambda state: state[0] < n_steps, body,
+        (jnp.int32(0), carry0, ys0))
+    return carry, ys
 
 
 @jax.jit
@@ -96,7 +153,7 @@ def greedy_assign(req_q, free_q, free_pods, mask, scores):
 def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
                             alloc_q, mask, static_scores, fit_col_w,
                             bal_col_mask, shape_u, shape_s, w_fit, w_bal,
-                            strategy: str, rows=None, exc=None):
+                            strategy: str, rows=None, exc=None, p_real=None):
     """Sequential-equivalent greedy with **live re-scoring**.
 
     The capacity-dependent score plugins (NodeResourcesFit strategies,
@@ -111,6 +168,8 @@ def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
     planes addressed through `rows` (see module docstring); with
     rows=None the planes are per-pod (C == P, row = pod). `exc` is the
     optional (P,) single-allowed-column restriction (-1 = none).
+    `p_real` (traced int32, None = P) is the chunk's real pod count: the
+    scan stops there (`_scan_real`), as in every chunk scan below.
     """
     from kubernetes_tpu.ops import kernels  # local to avoid import cycle
 
@@ -148,8 +207,8 @@ def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
 
     xs = (req_q, req_nz_q, rows) if exc is None \
         else (req_q, req_nz_q, rows, exc)
-    (_, _, _), assign = lax.scan(
-        step, (free_q, free_pods, used_nz_q), xs)
+    (_, _, _), assign = _scan_real(
+        step, (free_q, free_pods, used_nz_q), xs, p_real)
     return assign
 
 
@@ -161,7 +220,7 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
                                    dom_onehot, cid_onehot, dom_counts,
                                    max_skew, min_ok, has_key_nc,
                                    applies, contributes, rows=None,
-                                   exc=None):
+                                   exc=None, p_real=None):
     """greedy_assign_rescoring + PodTopologySpread hard constraints INSIDE
     the scan (sequential-equivalent, like capacity).
 
@@ -273,8 +332,8 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
 
     xs = (req_q, req_nz_q, rows, applies, contributes) if exc is None \
         else (req_q, req_nz_q, rows, applies, contributes, exc)
-    (_, _, _, dom_counts2), assign = lax.scan(
-        step, (free_q, free_pods, used_nz_q, dom_counts), xs)
+    (_, _, _, dom_counts2), assign = _scan_real(
+        step, (free_q, free_pods, used_nz_q, dom_counts), xs, p_real)
     return assign, dom_counts2
 
 
@@ -283,7 +342,8 @@ def multistart_greedy_assign(req_q, req_nz_q, free_q, free_pods, used_nz_q,
                              alloc_q, mask, static_scores, fit_col_w,
                              bal_col_mask, shape_u, shape_s, w_fit, w_bal,
                              strategy: str, perms, gang_onehot,
-                             gang_required, rows=None, exc=None):
+                             gang_required, rows=None, exc=None,
+                             p_real=None):
     """K permuted greedy scans in parallel + gang all-or-nothing.
 
     Sequential greedy in queue order is the oracle, but it strands capacity
@@ -307,20 +367,23 @@ def multistart_greedy_assign(req_q, req_nz_q, free_q, free_pods, used_nz_q,
     return _multistart_body(
         req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
         static_scores, fit_col_w, bal_col_mask, shape_u, shape_s, w_fit,
-        w_bal, strategy, perms, gang_onehot, gang_required, rows, exc)
+        w_bal, strategy, perms, gang_onehot, gang_required, rows, exc,
+        p_real)
 
 
 def _multistart_body(req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
                      mask, static_scores, fit_col_w, bal_col_mask, shape_u,
                      shape_s, w_fit, w_bal, strategy, perms, gang_onehot,
-                     gang_required, rows=None, exc=None):
+                     gang_required, rows=None, exc=None, p_real=None):
     """Traceable multistart core — also the shortlist path's whole-chunk
     fallback branch (see multistart_greedy_assign_shortlist).
 
     Only the small per-pod vectors permute; the (C, N) planes stay
     closed-over and each order addresses them through `rows[perm]` —
     permuting the planes themselves would materialize one (P, N) copy
-    per order, exactly what the class-dictionary format removes."""
+    per order, exactly what the class-dictionary format removes.
+    `p_real` is closed over from outside the vmapped `one`: every order
+    keeps padding in place, so all scans stop at one unbatched count."""
     P = req_q.shape[0]
     arange_p = jnp.arange(P, dtype=jnp.int32)
     if rows is None:
@@ -331,7 +394,8 @@ def _multistart_body(req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q,
             req_q[perm], req_nz_q[perm], free_q, free_pods, used_nz_q,
             alloc_q, mask, static_scores, fit_col_w,
             bal_col_mask, shape_u, shape_s, w_fit, w_bal, strategy,
-            rows=rows[perm], exc=None if exc is None else exc[perm])
+            rows=rows[perm], exc=None if exc is None else exc[perm],
+            p_real=p_real)
         inv = jnp.zeros_like(perm).at[perm].set(arange_p)
         return a[inv]
 
@@ -577,7 +641,7 @@ def _shortlist_scan(req_q, req_nz_q, rows, free_q, free_pods, used_nz_q,
                     alloc_q, mask, static_scores, fit_col_w, bal_col_mask,
                     shape_u, shape_s, w_fit, w_bal, strategy: str,
                     sc0, sl_class, sl_cand, sl_thresh, has_node,
-                    inline_fallback: bool, exc=None):
+                    inline_fallback: bool, exc=None, p_real=None):
     """The narrow sequential-equivalent scan: per pod, re-score only the
     pod's K shortlist columns plus every node already debited this chunk,
     and prove the winner exact against the prefilter threshold.
@@ -703,7 +767,8 @@ def _shortlist_scan(req_q, req_nz_q, rows, free_q, free_pods, used_nz_q,
     xs = (req_q, req_nz_q, rows, sl_cand, sl_thresh, sl_class, has_node)
     if exc is not None:
         xs = xs + (exc,)
-    (_, _, _, _, _, _, nfall, pois), assign = lax.scan(step, carry0, xs)
+    (_, _, _, _, _, _, nfall, pois), assign = _scan_real(
+        step, carry0, xs, p_real)
     return assign, nfall, pois
 
 
@@ -714,7 +779,8 @@ def greedy_assign_rescoring_shortlist(req_q, req_nz_q, free_q, free_pods,
                                       shape_u, shape_s, w_fit, w_bal,
                                       strategy: str,
                                       sc0, sl_class, sl_cand, sl_thresh,
-                                      has_node, rows=None, exc=None):
+                                      has_node, rows=None, exc=None,
+                                      p_real=None):
     """greedy_assign_rescoring, shortlist-pruned: bit-identical assignments
     at O(P·(K+P)) with per-step inline fallback to the full N-wide row
     (the lax.cond executes one branch — fallbacks cost O(N) only when
@@ -725,7 +791,7 @@ def greedy_assign_rescoring_shortlist(req_q, req_nz_q, free_q, free_pods,
         req_q, req_nz_q, rows, free_q, free_pods, used_nz_q, alloc_q, mask,
         static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
         w_fit, w_bal, strategy, sc0, sl_class, sl_cand, sl_thresh,
-        has_node, inline_fallback=True, exc=exc)
+        has_node, inline_fallback=True, exc=exc, p_real=p_real)
     return assign, nfall
 
 
@@ -737,7 +803,8 @@ def multistart_greedy_assign_shortlist(req_q, req_nz_q, free_q, free_pods,
                                        w_fit, w_bal, strategy: str, perms,
                                        gang_onehot, gang_required,
                                        sc0, sl_class, sl_cand, sl_thresh,
-                                       has_node, rows=None, exc=None):
+                                       has_node, rows=None, exc=None,
+                                       p_real=None):
     """multistart_greedy_assign, shortlist-pruned.
 
     The K permuted scans run vmapped, so a per-step lax.cond would lower
@@ -765,7 +832,7 @@ def multistart_greedy_assign_shortlist(req_q, req_nz_q, free_q, free_pods,
             bal_col_mask, shape_u, shape_s, w_fit, w_bal, strategy,
             sc0, sl_class[perm], sl_cand[perm], sl_thresh[perm],
             has_node[perm], inline_fallback=False,
-            exc=None if exc is None else exc[perm])
+            exc=None if exc is None else exc[perm], p_real=p_real)
         inv = jnp.zeros_like(perm).at[perm].set(arange_p)
         return a[inv], pois
 
@@ -777,7 +844,7 @@ def multistart_greedy_assign_shortlist(req_q, req_nz_q, free_q, free_pods,
             req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
             static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
             w_fit, w_bal, strategy, perms, gang_onehot, gang_required,
-            rows, exc)
+            rows, exc, p_real)
 
     def take(_):
         return _select_best(assigns, req_q, gang_onehot, gang_required)
@@ -793,7 +860,8 @@ def greedy_assign_rescoring_spread_shortlist(
         w_fit, w_bal, strategy: str,
         dom_onehot, cid_onehot, dom_counts, max_skew, min_ok, has_key_nc,
         applies, contributes,
-        sc0, sl_class, sl_cand, sl_thresh, has_node, rows=None, exc=None):
+        sc0, sl_class, sl_cand, sl_thresh, has_node, rows=None, exc=None,
+        p_real=None):
     """greedy_assign_rescoring_spread, shortlist-pruned (identity order,
     inline per-step fallback like the non-spread scan).
 
@@ -907,8 +975,8 @@ def greedy_assign_rescoring_spread_shortlist(
           sl_cand, sl_thresh, sl_class, has_node)
     if exc is not None:
         xs = xs + (exc,)
-    (_, _, _, dom_counts2, _, _, _, nfall), assign = lax.scan(
-        step, carry0, xs)
+    (_, _, _, dom_counts2, _, _, _, nfall), assign = _scan_real(
+        step, carry0, xs, p_real)
     return assign, dom_counts2, nfall
 
 
@@ -960,6 +1028,25 @@ def _wave_split(wave_w: int, arrays):
         out.append(a.reshape((-1, wave_w) + a.shape[1:]))
     real = (jnp.arange(p + pad, dtype=jnp.int32) < p).reshape(-1, wave_w)
     return out, real, p + pad
+
+
+def _wave_live(p_real, p: int, wave_w: int, n_waves: int):
+    """What the chunk's real pod count `p_real` (traced int32; None = p)
+    means to a wave scan over `p` pods in `n_waves` waves of `wave_w`.
+    Padding sits at the chunk's end, so real members are a prefix.
+
+    Returns (steps, live, idle): `steps` — the waves that hold a real
+    pod, the scan's trip count; `live` (n_waves,) — real members of each
+    wave, the serial replay's trip count; `idle` — the members of the
+    waves the scan skips. A wave of padding commits whole without placing
+    anything, so the commit counter gets `idle` added back and reads what
+    the full-length scan reads."""
+    p_real = jnp.int32(p) if p_real is None else p_real
+    steps = (p_real + (wave_w - 1)) // wave_w
+    live = jnp.clip(
+        p_real - wave_w * jnp.arange(n_waves, dtype=jnp.int32), 0, wave_w)
+    idle = p - jnp.minimum(steps * wave_w, p)
+    return steps, live, idle
 
 
 def _wave_spec_picks(masked, node_of, nbig, wave_w: int):
@@ -1038,7 +1125,7 @@ def _rescoring_wave_scan(req_q, req_nz_q, free_q, free_pods, used_nz_q,
                          alloc_q, mask, static_scores, fit_col_w,
                          bal_col_mask, shape_u, shape_s, w_fit, w_bal,
                          strategy: str, wave_w: int, rows, exc,
-                         poison: bool):
+                         poison: bool, p_real=None):
     """Traceable wavefront core of greedy_assign_rescoring.
 
     poison=False: conflicted waves take the in-step serial replay branch
@@ -1057,10 +1144,11 @@ def _rescoring_wave_scan(req_q, req_nz_q, free_q, free_pods, used_nz_q,
     ex = jnp.full((p,), -1, jnp.int32) if exc is None else exc
     (req_w, req_nz_w, rows_w, ex_w), real_w, _ = _wave_split(
         W, (req_q, req_nz_q, rows, ex))
+    steps, live_w, idle = _wave_live(p_real, p, W, real_w.shape[0])
 
     def wave_step(carry, inp):
         free_q, free_pods, used_nz, ncom, nrep, pois = carry
-        req, req_nz, row, e, real = inp
+        req, req_nz, row, e, real, live = inp
         m = mask[row]                                          # (W,N)
         m = m & ((e < 0)[:, None] | (iota_n[None, :] == e[:, None]))
         m = m & real[:, None]
@@ -1124,7 +1212,7 @@ def _rescoring_wave_scan(req_q, req_nz_q, free_q, free_pods, used_nz_q,
                 return (fq, fp, unz, out.at[w].set(idx))
 
             fq, fp, unz, out = lax.fori_loop(
-                0, W, body, (fq, fp, unz, jnp.full((W,), -1, jnp.int32)))
+                0, live, body, (fq, fp, unz, jnp.full((W,), -1, jnp.int32)))
             return (fq, fp, unz, nc, nr + nreal, po), out
 
         return lax.cond(jnp.any(conflict), slow, fast,
@@ -1132,9 +1220,10 @@ def _rescoring_wave_scan(req_q, req_nz_q, free_q, free_pods, used_nz_q,
 
     carry0 = (free_q, free_pods, used_nz_q, jnp.int32(0), jnp.int32(0),
               jnp.bool_(False))
-    (_, _, _, ncom, nrep, pois), out = lax.scan(
-        wave_step, carry0, (req_w, req_nz_w, rows_w, ex_w, real_w))
-    return out.reshape(-1)[:p], ncom, nrep, pois
+    (_, _, _, ncom, nrep, pois), out = _scan_real(
+        wave_step, carry0, (req_w, req_nz_w, rows_w, ex_w, real_w, live_w),
+        steps, (W,))
+    return out.reshape(-1)[:p], ncom + idle, nrep, pois
 
 
 @partial(jax.jit, static_argnames=("strategy", "wave_w"))
@@ -1142,7 +1231,7 @@ def greedy_assign_rescoring_wave(req_q, req_nz_q, free_q, free_pods,
                                  used_nz_q, alloc_q, mask, static_scores,
                                  fit_col_w, bal_col_mask, shape_u, shape_s,
                                  w_fit, w_bal, strategy: str, wave_w: int,
-                                 rows=None, exc=None):
+                                 rows=None, exc=None, p_real=None):
     """greedy_assign_rescoring, W pods per scan step (see the wavefront
     section comment for the speculation/replay contract). Assignments are
     bit-identical to the W=1 scan at every wave_w; wave_w=1 runs the
@@ -1154,7 +1243,8 @@ def greedy_assign_rescoring_wave(req_q, req_nz_q, free_q, free_pods,
     assign, ncom, nrep, _ = _rescoring_wave_scan(
         req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
         static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
-        w_fit, w_bal, strategy, wave_w, rows, exc, poison=False)
+        w_fit, w_bal, strategy, wave_w, rows, exc, poison=False,
+        p_real=p_real)
     return assign, ncom, nrep
 
 
@@ -1164,7 +1254,7 @@ def multistart_greedy_assign_wave(req_q, req_nz_q, free_q, free_pods,
                                   fit_col_w, bal_col_mask, shape_u, shape_s,
                                   w_fit, w_bal, strategy: str, wave_w: int,
                                   perms, gang_onehot, gang_required,
-                                  rows=None, exc=None):
+                                  rows=None, exc=None, p_real=None):
     """multistart_greedy_assign with wavefront scans under the vmap.
 
     The K permuted scans run vmapped, so the per-wave replay cond would
@@ -1184,7 +1274,7 @@ def multistart_greedy_assign_wave(req_q, req_nz_q, free_q, free_pods,
             req_q[perm], req_nz_q[perm], free_q, free_pods, used_nz_q,
             alloc_q, mask, static_scores, fit_col_w, bal_col_mask,
             shape_u, shape_s, w_fit, w_bal, strategy, wave_w, rows[perm],
-            None if exc is None else exc[perm], poison=True)
+            None if exc is None else exc[perm], poison=True, p_real=p_real)
         inv = jnp.zeros_like(perm).at[perm].set(arange_p)
         return a[inv], pois
 
@@ -1196,7 +1286,7 @@ def multistart_greedy_assign_wave(req_q, req_nz_q, free_q, free_pods,
             req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
             static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
             w_fit, w_bal, strategy, perms, gang_onehot, gang_required,
-            rows, exc)
+            rows, exc, p_real)
 
     def take(_):
         return _select_best(assigns, req_q, gang_onehot, gang_required)
@@ -1217,7 +1307,7 @@ def greedy_assign_rescoring_spread_wave(req_q, req_nz_q, free_q, free_pods,
                                         dom_onehot, cid_onehot, dom_counts,
                                         max_skew, min_ok, has_key_nc,
                                         applies, contributes, rows=None,
-                                        exc=None):
+                                        exc=None, p_real=None):
     """greedy_assign_rescoring_spread, W pods per scan step with per-wave
     domain-count updates.
 
@@ -1247,6 +1337,7 @@ def greedy_assign_rescoring_spread_wave(req_q, req_nz_q, free_q, free_pods,
     ex = jnp.full((p,), -1, jnp.int32) if exc is None else exc
     (req_w, req_nz_w, rows_w, app_w, con_w, ex_w), real_w, _ = _wave_split(
         W, (req_q, req_nz_q, rows, applies, contributes, ex))
+    steps, live_w, idle = _wave_live(p_real, p, W, real_w.shape[0])
 
     def spread_gate(dcounts, contrib, app):
         """(W,N) DoNotSchedule gate at the given counts — the serial
@@ -1268,7 +1359,7 @@ def greedy_assign_rescoring_spread_wave(req_q, req_nz_q, free_q, free_pods,
 
     def wave_step(carry, inp):
         free_q, free_pods, used_nz, dcounts, ncom, nrep = carry
-        req, req_nz, row, app, contrib, e, real = inp
+        req, req_nz, row, app, contrib, e, real, live = inp
         m = mask[row]
         m = m & ((e < 0)[:, None] | (iota_n[None, :] == e[:, None]))
         m = m & real[:, None]
@@ -1343,7 +1434,7 @@ def greedy_assign_rescoring_spread_wave(req_q, req_nz_q, free_q, free_pods,
                 return (fq, fp, unz, dc, out.at[w].set(idx))
 
             fq, fp, unz, dc, out = lax.fori_loop(
-                0, W, body,
+                0, live, body,
                 (fq, fp, unz, dc, jnp.full((W,), -1, jnp.int32)))
             return (fq, fp, unz, dc, nc, nr + nreal), out
 
@@ -1352,10 +1443,11 @@ def greedy_assign_rescoring_spread_wave(req_q, req_nz_q, free_q, free_pods,
 
     carry0 = (free_q, free_pods, used_nz_q, dom_counts,
               jnp.int32(0), jnp.int32(0))
-    (_, _, _, dom_counts2, ncom, nrep), out = lax.scan(
+    (_, _, _, dom_counts2, ncom, nrep), out = _scan_real(
         wave_step, carry0,
-        (req_w, req_nz_w, rows_w, app_w, con_w, ex_w, real_w))
-    return out.reshape(-1)[:p], dom_counts2, ncom, nrep
+        (req_w, req_nz_w, rows_w, app_w, con_w, ex_w, real_w, live_w),
+        steps, (W,))
+    return out.reshape(-1)[:p], dom_counts2, ncom + idle, nrep
 
 
 def _per_row(table, idx, n_rows: int, wave_w: int):
@@ -1379,7 +1471,7 @@ def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
                          bal_col_mask, shape_u, shape_s, w_fit, w_bal,
                          strategy: str, wave_w: int,
                          sl_class, sl_cand, sl_val, sl_thresh, has_node,
-                         poison: bool, exc=None):
+                         poison: bool, exc=None, p_real=None):
     """_shortlist_scan with W pods per wave step.
 
     The wave evaluates each member's candidate set (its top-K shortlist ∪
@@ -1442,12 +1534,13 @@ def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
     ex = jnp.full((p,), -1, jnp.int32) if exc is None else exc
     (req_w, req_nz_w, rows_w, cls_w, hn_w, ex_w), real_w, p_pad = \
         _wave_split(W, (req_q, req_nz_q, rows, sl_class, has_node, ex))
+    steps, live_w, idle = _wave_live(p_real, p, W, real_w.shape[0])
     mstat = jnp.where(mask, static_scores, NEG_INF)             # (C,N)
 
     def wave_step(carry, inp):
         (free_q, free_pods, used_nz, tidx, kstep, nfall, ncom, nrep,
          pois) = carry
-        req, req_nz, row, cls, hn, e, real = inp
+        req, req_nz, row, cls, hn, e, real, live = inp
         # Touched half: live, against node state gathered once.
         ti = jnp.minimum(tidx, n - 1)                           # (P_pad,)
         al_t, unz_t = alloc_q[ti], used_nz[ti]
@@ -1543,7 +1636,7 @@ def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
                 return (fq, fp, unz, tix, out.at[w].set(idx))
 
             fq, fp, unz, tix, out = lax.fori_loop(
-                0, W, body,
+                0, live, body,
                 (fq, fp, unz, tix, jnp.full((W,), -1, jnp.int32)))
             return (fq, fp, unz, tix, ks + W, nf + nreal, nc,
                     nr + nreal, po), out
@@ -1557,10 +1650,11 @@ def _shortlist_wave_scan(req_q, req_nz_q, rows, free_q, free_pods,
               jnp.full((p_pad,), n, jnp.int32),
               jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0),
               jnp.bool_(False))
-    (_, _, _, _, _, nfall, ncom, nrep, pois), out = lax.scan(
+    (_, _, _, _, _, nfall, ncom, nrep, pois), out = _scan_real(
         wave_step, carry0,
-        (req_w, req_nz_w, rows_w, cls_w, hn_w, ex_w, real_w))
-    return out.reshape(-1)[:p], nfall, ncom, nrep, pois
+        (req_w, req_nz_w, rows_w, cls_w, hn_w, ex_w, real_w, live_w),
+        steps, (W,))
+    return out.reshape(-1)[:p], nfall, ncom + idle, nrep, pois
 
 
 @partial(jax.jit, static_argnames=("strategy", "wave_w"))
@@ -1572,7 +1666,7 @@ def greedy_assign_rescoring_shortlist_wave(req_q, req_nz_q, free_q,
                                            wave_w: int,
                                            sl_class, sl_cand, sl_val,
                                            sl_thresh, has_node, rows=None,
-                                           exc=None):
+                                           exc=None, p_real=None):
     """greedy_assign_rescoring_shortlist with wavefront waves: exact via
     the in-step serial replay (full N-wide rows, counted as fallbacks).
     The shortlist arrives as CLASS tables (sl_cand/sl_val (S,K),
@@ -1585,7 +1679,7 @@ def greedy_assign_rescoring_shortlist_wave(req_q, req_nz_q, free_q,
         req_q, req_nz_q, rows, free_q, free_pods, used_nz_q, alloc_q,
         mask, static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
         w_fit, w_bal, strategy, wave_w, sl_class, sl_cand, sl_val,
-        sl_thresh, has_node, poison=False, exc=exc)
+        sl_thresh, has_node, poison=False, exc=exc, p_real=p_real)
     return assign, nfall, ncom, nrep
 
 
@@ -1599,7 +1693,7 @@ def multistart_greedy_assign_shortlist_wave(req_q, req_nz_q, free_q,
                                             gang_onehot, gang_required,
                                             sl_class, sl_cand, sl_val,
                                             sl_thresh, has_node, rows=None,
-                                            exc=None):
+                                            exc=None, p_real=None):
     """multistart_greedy_assign_shortlist with wavefront waves under the
     vmap: each order runs speculation-only and poisons on its first wave
     conflict OR failed bound check; one outer lax.cond reruns the whole
@@ -1620,7 +1714,8 @@ def multistart_greedy_assign_shortlist_wave(req_q, req_nz_q, free_q,
             used_nz_q, alloc_q, mask, static_scores, fit_col_w,
             bal_col_mask, shape_u, shape_s, w_fit, w_bal, strategy, wave_w,
             sl_class[perm], sl_cand, sl_val, sl_thresh, has_node[perm],
-            poison=True, exc=None if exc is None else exc[perm])
+            poison=True, exc=None if exc is None else exc[perm],
+            p_real=p_real)
         inv = jnp.zeros_like(perm).at[perm].set(arange_p)
         return a[inv], pois
 
@@ -1632,7 +1727,7 @@ def multistart_greedy_assign_shortlist_wave(req_q, req_nz_q, free_q,
             req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
             static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
             w_fit, w_bal, strategy, perms, gang_onehot, gang_required,
-            rows, exc)
+            rows, exc, p_real)
 
     def take(_):
         return _select_best(assigns, req_q, gang_onehot, gang_required)

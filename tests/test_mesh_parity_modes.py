@@ -10,11 +10,13 @@ from kubernetes_tpu.scheduler.types import PodInfo
 from mesh_parity import (
     GREEDY,
     MESHES,
+    RAGGED_MESHES,
     Case,
     check_parity,
     gang_fwk,
     gang_pods,
     hetero,
+    ragged,
     ran,
     template_pods,
     uniform_cluster,
@@ -112,3 +114,10 @@ ROUTES = {f.__name__: f for f in (
 @pytest.mark.parametrize("route", list(ROUTES))
 def test_mesh_matches_one_device(route, mesh, monkeypatch):
     check_parity(route, ROUTES[route](), mesh, monkeypatch)
+
+
+@pytest.mark.parametrize("mesh", list(RAGGED_MESHES))
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_ragged_chunk_matches_one_device(route, mesh, monkeypatch):
+    check_parity("ragged:" + route, ragged(ROUTES[route]()), mesh,
+                 monkeypatch)

@@ -12,9 +12,11 @@ from kubernetes_tpu.scheduler.types import PodInfo
 from mesh_parity import (
     GREEDY,
     MESHES,
+    RAGGED_MESHES,
     Case,
     check_parity,
     hetero,
+    ragged,
     ran,
     template_pods,
 )
@@ -105,3 +107,10 @@ ROUTES = {f.__name__: f for f in (
 @pytest.mark.parametrize("route", list(ROUTES))
 def test_mesh_matches_one_device(route, mesh, monkeypatch):
     check_parity(route, ROUTES[route](), mesh, monkeypatch)
+
+
+@pytest.mark.parametrize("mesh", list(RAGGED_MESHES))
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_ragged_chunk_matches_one_device(route, mesh, monkeypatch):
+    check_parity("ragged:" + route, ragged(ROUTES[route]()), mesh,
+                 monkeypatch)

@@ -16,10 +16,12 @@ from mesh_parity import (
     GREEDY,
     MESHES,
     MESHES_1D,
+    RAGGED_MESHES,
     Case,
     assert_within_allocatable,
     check_parity,
     mesh_of,
+    ragged,
     ran,
     spread_pods,
     uniform_cluster,
@@ -58,6 +60,13 @@ ROUTES = {f.__name__: f for f in (
 @pytest.mark.parametrize("route", list(ROUTES))
 def test_mesh_matches_one_device(route, mesh, monkeypatch):
     check_parity(route, ROUTES[route](), mesh, monkeypatch)
+
+
+@pytest.mark.parametrize("mesh", list(RAGGED_MESHES))
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_ragged_chunk_matches_one_device(route, mesh, monkeypatch):
+    check_parity("ragged:" + route, ragged(ROUTES[route]()), mesh,
+                 monkeypatch)
 
 
 @pytest.mark.parametrize("mesh", MESHES_1D)

@@ -338,6 +338,57 @@ class TestAffinityCompilerBuilds:
         assert "scheduler_tpu_affinity_rows_recounted_total" in text
 
 
+class TestScanStepCounter:
+    """The chunk scan's trip count follows the chunk's real pods, and
+    the registry says how many of the padded scan's steps that saved —
+    counted on the host from what the program is handed."""
+
+    def test_series_exist_at_zero_from_registration(self):
+        from kubernetes_tpu.metrics.registry import SchedulerMetrics
+        text = SchedulerMetrics().registry.render()
+        for kind in ("run", "skipped"):
+            assert 'scheduler_tpu_solver_scan_steps_total{kind="%s"} 0' \
+                % kind in text
+
+    def test_three_pods_at_w32_run_one_wave_of_32(self, monkeypatch):
+        from kubernetes_tpu.metrics.registry import SchedulerMetrics
+        from kubernetes_tpu.ops import TPUBackend
+        from kubernetes_tpu.scheduler.cache import SchedulerCache
+        from kubernetes_tpu.scheduler.framework import Framework
+        from kubernetes_tpu.scheduler.plugins.registry import (
+            DEFAULT_SCORE_WEIGHTS,
+            build_plugins,
+        )
+        from kubernetes_tpu.scheduler.types import PodInfo
+        for k in ("KTPU_WAVEFRONT", "KTPU_WAVE_WIDTH", "KTPU_SOLVE_MODE"):
+            monkeypatch.delenv(k, raising=False)
+        cache = SchedulerCache()
+        for i in range(12):
+            cache.add_node(make_node(f"n{i}"))
+        backend = TPUBackend(max_batch=None, mesh=None)   # P = 1,024
+        backend.metrics = SchedulerMetrics()
+        statics = []
+        inner = TPUBackend._dispatch_chunk_jit
+
+        def recording(self, prep, ctx):
+            out = inner(self, prep, ctx)
+            statics.append((out["batch"].req_q.shape[0], out["wave_w"]))
+            return out
+
+        monkeypatch.setattr(TPUBackend, "_dispatch_chunk_jit", recording)
+        pods = [PodInfo(make_pod(f"p{i}", uid=f"p{i}",
+                                 requests={"cpu": "100m"}))
+                for i in range(3)]
+        placed, _ = backend.assign(
+            pods, cache.update_snapshot(),
+            Framework(build_plugins(), DEFAULT_SCORE_WEIGHTS))
+        assert all(placed.values())
+        assert statics == [(1024, 32)]
+        steps = backend.metrics.solver_scan_steps
+        assert steps.value(kind="run") == 1
+        assert steps.value(kind="skipped") == 31
+
+
 class TestRequestTracing:
     """§5.1 OTel-style spans: one trace covers a pod's create → schedule
     → bind across the apiserver and scheduler, exportable to Perfetto."""

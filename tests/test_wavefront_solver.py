@@ -401,12 +401,17 @@ def _sub_jaxprs(eqn):
                 yield x
 
 
-def _scans_of_length(jaxpr, length):
+def _loops_stacking(jaxpr, shape):
+    """The `while` loops under `jaxpr` that carry a stacked output whose
+    trailing dimensions are `shape` — how `solver._scan_real` appears in
+    a trace: (steps, W) for a wave scan, whatever a vmap put in front."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan" and eqn.params["length"] == length:
+        if eqn.primitive.name == "while" and any(
+                v.aval.shape[-len(shape):] == shape
+                for v in eqn.params["body_jaxpr"].jaxpr.outvars):
             yield eqn
         for sub in _sub_jaxprs(eqn):
-            yield from _scans_of_length(sub, length)
+            yield from _loops_stacking(sub, shape)
 
 
 def _gathered_index_rows(jaxpr):
@@ -460,11 +465,12 @@ class TestShortlistWaveGatherBudget:
         closed = jax.make_jaxpr(
             lambda kw: solver.multistart_greedy_assign_shortlist_wave(
                 strategy="LeastAllocated", wave_w=w, **kw))(args)
-        # the wave scan is the one of P/W steps (the whole-chunk rerun
-        # behind the poison cond scans P steps)
-        wave_scans = list(_scans_of_length(closed.jaxpr, p // w))
+        # the wave scan is the loop that stacks (P/W, W) picks (the
+        # whole-chunk rerun behind the poison cond stacks (P,))
+        wave_scans = list(_loops_stacking(closed.jaxpr, (p // w, w)))
         assert len(wave_scans) == 1
-        rows = _gathered_index_rows(wave_scans[0].params["jaxpr"].jaxpr)
+        rows = _gathered_index_rows(
+            wave_scans[0].params["body_jaxpr"].jaxpr)
         per_order = rows / orders
         assert 0 < per_order <= budget, \
             f"{per_order:.0f} gathered index rows a wave step and " \
