@@ -667,6 +667,24 @@ class SchedulerMetrics:
             "scheduler_tpu_affinity_rows_recounted_total",
             "Node rows of the label-signature table counted by those "
             "builds (a full build counts every node)")
+        self.affinity_carriers_walked = r.counter(
+            "scheduler_tpu_affinity_carriers_walked_total",
+            "Resident pods carrying affinity terms that those builds "
+            "walked to rebuild the carriers of residents' own terms (a "
+            "pod on both of a node's lists counts twice)")
+        #: Placements of the device solve that the host verify took back
+        #: (the pod requeues), by the check that rejected: the solve does
+        #: not see what pods of the same assign() did to each other —
+        #: plugin="NodeResourcesFit" | "NodePorts" | "InterPodAffinity"
+        #: (a term, or its symmetry, against a pod placed earlier in the
+        #: batch) | "other" (the full host re-check of a stateful plugin).
+        self.verify_rejects = r.counter(
+            "scheduler_tpu_verify_rejects_total",
+            "Device-solve placements rejected by the host verify",
+            labels=("plugin",))
+        for plugin in ("NodeResourcesFit", "NodePorts", "InterPodAffinity",
+                       "other"):
+            self.verify_rejects.inc(0, plugin=plugin)
         #: The spread table's part that reads nodes and templates only
         #: (domain planes, device copies): planes="kept" reused the last
         #: build's and took the domain counts alone, planes="built" made
@@ -697,6 +715,12 @@ class SchedulerMetrics:
         self.prep_duration = r.histogram(
             "scheduler_tpu_prep_seconds",
             "Host-side chunk prep wall time (rows, classes, uploads)")
+        #: the part of that prep spent in the affinity compiler for the
+        #: chunk's InterPodAffinity rows (span solver.affinity_rows)
+        self.affinity_rows_duration = r.histogram(
+            "scheduler_tpu_affinity_rows_seconds",
+            "Host time per chunk in the affinity compiler: reaching the "
+            "snapshot and the chunk's InterPodAffinity filter rows")
         self.plane_classes = r.gauge(
             "scheduler_tpu_plane_classes_per_chunk",
             "Pod equivalence classes behind the latest chunk's planes")

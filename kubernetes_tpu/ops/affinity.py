@@ -15,8 +15,11 @@ signatures (ops/labelsets.py):
 
 Numpy, deliberately: U (label signatures) and T (unique terms) are tiny for
 template-derived workloads, so per-term cost is a (N×U) matvec — far below
-one device dispatch. The resulting (P,N) mask feeds the XLA solver; parity
-with the host plugin is differential-tested (tests/test_affinity_tensor.py).
+one device dispatch. A pod's result is one (N,) row, cached per pod content
+signature; the backend interns the distinct rows of a chunk and ships them
+as rows of its (C, N/8) class mask plane (ops/backend._prep_chunk), never a
+(P, N) mask. Parity with the host plugin is differential-tested
+(tests/test_affinity_tensor.py).
 
 namespaceSelector terms COMPILE like everything else: the term's
 effective namespace set resolves at table-build time
@@ -76,6 +79,11 @@ class AffinityCompiler:
         #: labels and taints alone and survive an advance
         self._mask_cache: dict[str, np.ndarray] = {}
         self._node_pos: dict[str, int] | None = None
+        #: how this compiler reached the snapshot it points at ("full":
+        #: built anew, "delta": advanced, "kept": asked again at the same
+        #: snapshot), and the resident pods that step's `_derive` walked
+        self.reached = "full"
+        self.walked = 0
         self._point_at(snapshot)
         self._derive()
 
@@ -108,11 +116,14 @@ class AffinityCompiler:
         holds: rebuild the carriers of resident pods' own terms and drop
         every cache derived from pod counts."""
         snapshot, n_pad = self.snapshot, self.n_pad
+        # a pod on both of the snapshot's lists is walked, and counted, twice
+        walked = 0
         # Resident pods' required anti-affinity terms (symmetry source):
         # term signature -> (carrier-count vector over nodes, term, owner_ns).
         self.resident_anti: dict[str, tuple[np.ndarray, dict, str]] = {}
         for ni in snapshot.have_pods_with_required_anti_affinity:
             n = self._pos(ni)
+            walked += len(ni.pods_with_required_anti_affinity)
             for pi in ni.pods_with_required_anti_affinity:
                 for term in pi.required_anti_affinity_terms:
                     key = repr((term, pi.namespace))
@@ -140,6 +151,7 @@ class AffinityCompiler:
 
         for ni in snapshot.have_pods_with_affinity:
             n = self._pos(ni)
+            walked += len(ni.pods_with_affinity)
             for pi in ni.pods_with_affinity:
                 for t in pi.preferred_affinity_terms:
                     _carrier(t.get("podAffinityTerm") or {}, pi.namespace,
@@ -150,6 +162,7 @@ class AffinityCompiler:
                 for t in pi.required_affinity_terms:
                     # hardPodAffinityWeight multiplies at score_row time.
                     _carrier(t, pi.namespace, n, 1.0, is_hard=True)
+        self.walked = walked
         #: per-pending-pod-signature symmetry-match cache
         self._sym_match_cache: dict[tuple, bool] = {}
         #: per-(term,ns) per-node matching-count cache
@@ -170,7 +183,8 @@ class AffinityCompiler:
 
     def _pos(self, ni) -> int:
         """Snapshot position of a node that carries affinity terms (the
-        name map is made on first need: no cell's pods carry any)."""
+        name map is made on first need: where no resident pod carries a
+        term, never)."""
         if self._node_pos is None:
             self._node_pos = {
                 n.name: i for i, n in enumerate(self.snapshot.nodes)}
@@ -194,6 +208,7 @@ class AffinityCompiler:
         if changed is None:
             return None
         self._point_at(snapshot)
+        self.reached, self.walked = "delta", 0
         if changed:
             self.sigs.recount(snapshot.nodes, changed)
             self._derive()

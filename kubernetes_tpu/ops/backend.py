@@ -333,15 +333,22 @@ class AdaptiveTuner:
         self.wave_replays += replays
 
     def solve_mode(self, p_real: int, has_gang: bool, spread: bool,
-                   class_mode: bool) -> tuple[str, bool]:
+                   class_mode: bool, exclusive: bool = False
+                   ) -> tuple[str, bool]:
         """('greedy' | 'optimal', structural_fallback) for one chunk —
         the KTPU_SOLVE_MODE policy row. 'greedy' pins the r18 scan call
         graph (the kill switch). Optimal requires class planes (the
-        (C,N) cost matrix IS the class dictionary) and a non-spread
-        chunk (the spread scan's non-monotone domain gating has no
-        transport relaxation); an ineligible chunk degrades structurally
-        to greedy with the fallback bit set so
-        solver_optimal_fallbacks_total records it. Under 'auto' the
+        (C,N) cost matrix IS the class dictionary), a non-spread chunk
+        (the spread scan's non-monotone domain gating has no transport
+        relaxation) and a chunk that is not `exclusive` (a pod of it
+        carries a required anti-affinity term, so pods of the chunk may
+        exclude each other, which no column capacity states: a plan
+        sends a whole hostname group to a handful of nodes, the host
+        verify keeps one pod on each and requeues the rest; the greedy
+        scan debits a node as it takes it and spreads the group); an
+        ineligible chunk degrades structurally to greedy with the
+        fallback bit set so solver_optimal_fallbacks_total records it.
+        Under 'auto' the
         optimal mode engages for gang chunks and for chunks of at least
         OPTIMAL_MIN_PODS real pods (drain/rollout waves) — EXCEPT at
         the structural large-N row (n_nodes >= LARGE_N, the same signal
@@ -359,7 +366,7 @@ class AdaptiveTuner:
         raw = flags.get("KTPU_SOLVE_MODE")
         if raw == "greedy":
             return "greedy", False
-        eligible = class_mode and not spread
+        eligible = class_mode and not spread and not exclusive
         if raw == "optimal":
             return ("optimal", False) if eligible else ("greedy", True)
         if not (has_gang or p_real >= self.OPTIMAL_MIN_PODS):
@@ -1145,19 +1152,57 @@ class TPUBackend:
         if cached is not None and cached.ns_resolver is not resolver:
             cached = None  # another profile's resolver: other namespace sets
         if cached is not None and cached.at(snapshot):
+            cached.reached, cached.walked = "kept", 0
             return cached
         recounted = cached.advance(snapshot, ct.n_pad) \
             if cached is not None else None
-        kind = "delta"
         if recounted is None:
             from kubernetes_tpu.ops.affinity import AffinityCompiler
             cached = self._affinity = AffinityCompiler(
                 snapshot, ct.n_pad, ns_resolver=resolver)
-            kind, recounted = "full", ct.n_real
+            recounted = ct.n_real
         if self.metrics is not None:
-            self.metrics.affinity_compiler_builds.inc(kind=kind)
+            self.metrics.affinity_compiler_builds.inc(kind=cached.reached)
             self.metrics.affinity_rows_recounted.inc(recounted)
+            self.metrics.affinity_carriers_walked.inc(cached.walked)
         return cached
+
+    def _affinity_rows(self, plugin, pods: list[PodInfo], skip: set[int],
+                       snapshot: Snapshot, ct: ClusterTensors
+                       ) -> dict[int, tuple[np.ndarray, list[int]]]:
+        """The tensorized InterPodAffinity rows (ops/affinity.py) of a
+        chunk's gated pods, grouped by row identity: `filter_row` hands
+        every pod of one content signature the same cached object, so
+        {id(row): (row, chunk indices of its pods)}. Whatever this spends
+        in the compiler — reaching the snapshot (build, or advance and
+        `_derive`) and the rows themselves — is the span
+        `solver.affinity_rows`, and its wall the histogram
+        `scheduler_tpu_affinity_rows_seconds`: the span's own clock reads
+        where tracing is on, two reads of that clock where it is off."""
+        gate = _FILTER_ACTIVE["InterPodAffinity"]
+        gated = [(i, pi) for i, pi in enumerate(pods)
+                 if i not in skip and gate(plugin, pi, snapshot)]
+        groups: dict[int, tuple[np.ndarray, list[int]]] = {}
+        if not gated:
+            return groups
+        with self._span("solver.affinity_rows") as sp:
+            t0 = time.monotonic() if sp is None else 0.0
+            compiler = self._affinity_compiler(snapshot, ct)
+            for i, pi in gated:
+                row_full = compiler.filter_row(pi)
+                grp = groups.get(id(row_full))
+                if grp is None:
+                    grp = groups[id(row_full)] = (row_full, [])
+                grp[1].append(i)
+            if sp is not None:
+                sp.attrs.update(
+                    build=compiler.reached, carriers=compiler.walked,
+                    terms=len(compiler.resident_anti), rows=len(groups))
+        if self.metrics is not None:
+            self.metrics.affinity_rows_duration.observe(
+                sp.end - sp.start if sp is not None
+                else time.monotonic() - t0)
+        return groups
 
     # -- NodeResourceTopologyMatch vectorization (BASELINE config #4) -----
 
@@ -2138,7 +2183,7 @@ class TPUBackend:
         #: shared-row groups for the tensorized InterPodAffinity rows:
         #: template batches produce ONE row object per signature, so the
         #: per-pod O(N) mask AND collapses to one vectorized write per
-        #: distinct row (id-keyed — filter_row returns cached objects).
+        #: distinct row (see _affinity_rows).
         ipa_groups: dict[int, tuple[np.ndarray, list[int]]] = {}
         compiler = None
 
@@ -2153,25 +2198,18 @@ class TPUBackend:
                         plugin, pi, snapshot, ct)
                     if not all_true:
                         apply_row(plugin.NAME, i, row)
+            elif plugin.NAME == "InterPodAffinity":
+                # Tensorized path (ops/affinity.py): dense per-term masks
+                # over interned label signatures instead of O(N) host
+                # plugin calls per pod, applied once per distinct row below.
+                ipa_groups = self._affinity_rows(
+                    plugin, pods, unknown_res, snapshot, ct)
             else:
                 gate = _FILTER_ACTIVE.get(plugin.NAME)
                 for i, pi in enumerate(pods):
                     if i in unknown_res:
                         continue
                     if gate is not None and not gate(plugin, pi, snapshot):
-                        continue
-                    if plugin.NAME == "InterPodAffinity":
-                        # Tensorized path (ops/affinity.py): dense per-term
-                        # masks over interned label signatures instead of
-                        # O(N) host plugin calls per pod. Rows group by
-                        # identity for one vectorized apply below.
-                        if compiler is None:
-                            compiler = self._affinity_compiler(snapshot, ct)
-                        row_full = compiler.filter_row(pi)
-                        grp = ipa_groups.get(id(row_full))
-                        if grp is None:
-                            grp = ipa_groups[id(row_full)] = (row_full, [])
-                        grp[1].append(i)
                         continue
                     if plugin.NAME == "NodeResourceTopologyMatch":
                         # Vectorized zone-alignment rows from batch-start
@@ -2890,7 +2928,9 @@ class TPUBackend:
             batch.p_real,
             has_gang=prep["gang_onehot"] is not None,
             spread=use_spread,
-            class_mode=prep.get("class_mode", False))
+            class_mode=prep.get("class_mode", False),
+            exclusive=any(pi.has_required_anti_affinity
+                          for pi in prep["pods"]))
         if solve_mode == "optimal":
             prep["shortlist_k"] = 0
             prep["wave_w"] = 0
@@ -3181,6 +3221,12 @@ class TPUBackend:
         ).with_plugin("NodePorts")
 
         rejects: list[tuple[int, int]] = []
+
+        def reject(i: int, idx: int, plugin: str) -> None:
+            rejects.append((i, idx))
+            if self.metrics is not None:
+                self.metrics.verify_rejects.inc(plugin=plugin)
+
         for i, pi in enumerate(pods):
             idx = int(assign[i])
             if idx < 0:
@@ -3190,7 +3236,7 @@ class TPUBackend:
             if insufficient_resources(pi, ni):
                 assignments[pi.key] = None
                 diagnostics[pi.key] = {ni.name: contention}
-                rejects.append((i, idx))
+                reject(i, idx, "NodeResourcesFit")
                 continue
             if pi.host_ports and any(
                     (ip == "0.0.0.0" or uip == "0.0.0.0" or ip == uip)
@@ -3199,7 +3245,7 @@ class TPUBackend:
                     for (uip, uproto, uport) in ni.used_ports):
                 assignments[pi.key] = None
                 diagnostics[pi.key] = {ni.name: port_conflict}
-                rejects.append((i, idx))
+                reject(i, idx, "NodePorts")
                 continue
             if full_check_batch:
                 # Non-IPA stateful plugins in play → full host re-check.
@@ -3219,14 +3265,14 @@ class TPUBackend:
                 if not st.is_success():
                     assignments[pi.key] = None
                     diagnostics[pi.key] = {ni.name: st}
-                    rejects.append((i, idx))
+                    reject(i, idx, "other")
                     continue
             elif delta_has_terms or pi.has_affinity_constraints:
                 if not _delta_affinity_ok(pi, ni, delta, ct, compiler,
                                           sel_cache, ctx.delta_idx):
                     assignments[pi.key] = None
                     diagnostics[pi.key] = {ni.name: affinity_conflict}
-                    rejects.append((i, idx))
+                    reject(i, idx, "InterPodAffinity")
                     continue
             assignments[pi.key] = ni.name
             ni.add_pod(pi)

@@ -74,6 +74,8 @@ class SchedulingQueue:
         self._gated: dict[str, PodInfo] = {}
         self._cond = asyncio.Condition()
         self._closed = False
+        #: standing by (hold/release): pops wait, everything else goes on
+        self._held = False
         # moveRequestCycle bookkeeping: event hints per plugin.
         self._hints: dict[str, list[tuple[str, HintFn]]] = {}
         self._in_flight: set[str] = set()
@@ -161,13 +163,13 @@ class SchedulingQueue:
         return batch[0] if batch else None
 
     async def pop_batch(self, max_pods: int) -> list[PodInfo]:
-        """Drain up to max_pods from activeQ; blocks until ≥1 available.
-        Flushes due backoff pods first so a ready backoff pod can't be
-        starved by an empty activeQ."""
+        """Drain up to max_pods from activeQ; blocks until ≥1 available
+        and the queue is not held. Flushes due backoff pods first so a
+        ready backoff pod can't be starved by an empty activeQ."""
         async with self._cond:
             while True:
                 self._flush_backoff_locked()
-                if self._active or self._closed:
+                if (self._active and not self._held) or self._closed:
                     break
                 # Wake when the earliest backoff pod becomes ready.
                 timeout = None
@@ -177,7 +179,7 @@ class SchedulingQueue:
                     await asyncio.wait_for(self._cond.wait(), timeout)
                 except asyncio.TimeoutError:
                     continue
-            if self._closed and not self._active:
+            if self._closed and (self._held or not self._active):
                 return []
             return self._drain_locked(max_pods)
 
@@ -203,7 +205,7 @@ class SchedulingQueue:
         holds."""
         async with self._cond:
             self._flush_backoff_locked()
-            if self._closed:
+            if self._closed or self._held:
                 return []
             return self._drain_locked(max_pods)
 
@@ -339,6 +341,18 @@ class SchedulingQueue:
     async def close(self) -> None:
         async with self._cond:
             self._closed = True
+            self._cond.notify_all()
+
+    async def hold(self) -> None:
+        """Stand by: until `release`, no pop hands out a pod — a pop
+        that is already waiting keeps waiting. Adds, moves, backoff and
+        the flushers go on, so pods pile up in activeQ."""
+        async with self._cond:
+            self._held = True
+
+    async def release(self) -> None:
+        async with self._cond:
+            self._held = False
             self._cond.notify_all()
 
     # -- introspection (metrics: scheduler_pending_pods{queue=...}) --------

@@ -1068,6 +1068,20 @@ class Scheduler:
                 flusher.cancel()
                 janitor.cancel()
 
+    async def hold(self) -> None:
+        """Stand by while pods become pending, as a standby replica does
+        before it wins the lease: until `release`, `run()` pops nothing
+        and starts no attempt. Informers, queue adds, flushers and
+        binding cycles already in flight go on; `stop()` ends a held
+        run. The gate is the queue's pop, where both the plain loop and
+        the serving tier wait between attempts — that is where `run()`
+        is parked when a hold comes."""
+        await self.queue.hold()
+
+    async def release(self) -> None:
+        """End a `hold`: the next pop takes the whole backlog."""
+        await self.queue.release()
+
     async def run_with_leader_election(self, elector,
                                        batch_size: int = 1) -> None:
         """Leader-elected run (cmd/kube-scheduler app/server.go `Run`):
