@@ -670,8 +670,19 @@ class SchedulerMetrics:
         self.affinity_carriers_walked = r.counter(
             "scheduler_tpu_affinity_carriers_walked_total",
             "Resident pods carrying affinity terms that those builds "
-            "walked to rebuild the carriers of residents' own terms (a "
-            "pod on both of a node's lists counts twice)")
+            "looked at to bring the carriers of residents' own terms to "
+            "the snapshot: the two term-carrying lists of every node a "
+            "build read (a delta reads the changed nodes, a full build "
+            "all; a pod on both of a node's lists counts twice)")
+        self.affinity_carriers_moved = r.counter(
+            "scheduler_tpu_affinity_carriers_moved_total",
+            "Of those, the ones whose terms a build added to the carriers "
+            "(dir=\"came\": new on a node's list since it was last read) "
+            "or took off (dir=\"gone\"); the binding's update replaces a "
+            "pod's object and counts as one of each",
+            labels=("dir",))
+        for direction in ("came", "gone"):
+            self.affinity_carriers_moved.inc(0, dir=direction)
         #: Placements of the device solve that the host verify took back
         #: (the pod requeues), by the check that rejected: the solve does
         #: not see what pods of the same assign() did to each other —

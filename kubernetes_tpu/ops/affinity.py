@@ -37,7 +37,11 @@ from __future__ import annotations
 import numpy as np
 
 from kubernetes_tpu.api.labels import from_label_selector, ns_contains
-from kubernetes_tpu.ops.labelsets import LabelSigTable, TopologyTable
+from kubernetes_tpu.ops.labelsets import (
+    LabelSigTable,
+    TopologyTable,
+    came_and_gone,
+)
 from kubernetes_tpu.scheduler.plugins.interpodaffinity import (
     resolve_term_namespaces as _term_ns,
 )
@@ -50,16 +54,49 @@ def _seg_sum(values: np.ndarray, ids: np.ndarray, num: int) -> np.ndarray:
     return out
 
 
+class _Carriers(dict):
+    """The residents that carry each term of their own, by node: term
+    signature -> (carrier vector over nodes, term, owner_ns[, is_hard]).
+    A key lives while some resident carries its term, so the keys are
+    those of a table built anew from the residents there are."""
+
+    def __init__(self, n_pad: int):
+        super().__init__()
+        self._n_pad = n_pad
+        #: key -> how many resident terms carry it
+        self._carrying: dict[str, int] = {}
+
+    def move(self, key: str, held: tuple, n: int, weight: float,
+             step: int) -> None:
+        """One resident term more (`step` 1) or fewer (-1) at node `n`."""
+        got = self.get(key)
+        if got is None:
+            got = self[key] = (
+                np.zeros((self._n_pad,), dtype=np.float32), *held)
+            self._carrying[key] = 0
+        got[0][n] += step * weight
+        self._carrying[key] += step
+        if not self._carrying[key]:
+            del self[key], self._carrying[key]
+
+
 class AffinityCompiler:
     """Compiled state for batched affinity filtering, kept ACROSS snapshots.
 
     Built by one walk over the snapshot's resident pods, then `advance`d
     from generation to generation by the scheduler cache's delta handles
     (`set_epoch`, `spec_seq`, `changed_since` — the rule and the fall-back
-    of tensorize.ClusterTensors._init_delta): only the rows of the nodes
-    that changed are recounted, what reads node labels alone is kept, and
-    what was derived from pod counts is dropped. An advanced compiler
-    answers exactly as one built anew on the same snapshot would.
+    of tensorize.ClusterTensors._init_delta). Only the nodes that changed
+    are read again: their label-signature rows are recounted, and the
+    carriers of residents' own terms (`resident_anti`, `resident_score`)
+    are moved by the pods that came to or left those nodes' two
+    term-carrying lists — both by `labelsets.came_and_gone`. What reads
+    node labels alone is kept too; only what was derived from pod COUNTS
+    (matching counts, term masks, whole rows) is dropped. An advanced
+    compiler answers exactly as one built anew on the same snapshot
+    would: carrier vectors hold sums of small integers in float32, so
+    neither the order pods arrived in nor the order of the keys moves a
+    row by a bit.
 
     `ns_resolver` (plugins.interpodaffinity.NamespaceResolver) resolves
     namespaceSelector terms against live Namespace labels; without one
@@ -78,14 +115,29 @@ class AffinityCompiler:
         #: per-term-signature compiled masks; the "elig/" rows read node
         #: labels and taints alone and survive an advance
         self._mask_cache: dict[str, np.ndarray] = {}
-        self._node_pos: dict[str, int] | None = None
+        #: Resident pods' required anti-affinity terms (symmetry source):
+        #: term signature -> (carrier-count vector over nodes, term,
+        #: owner_ns).
+        self.resident_anti = _Carriers(n_pad)
+        #: Resident pods' PREFERRED terms + required-affinity terms (score
+        #: symmetry sources — scoring.go's second loop): term signature →
+        #: (weight-summed carrier vector over nodes, term, owner_ns,
+        #: is_hard). Preferred anti-affinity carriers get negative
+        #: weights; hardPodAffinityWeight multiplies at score_row time.
+        self.resident_score = _Carriers(n_pad)
+        #: per node, the list of term-carrying pods each of the two was
+        #: last read from
+        self._read_anti: list[list[PodInfo]] = [[]] * self.n_real
+        self._read_score: list[list[PodInfo]] = [[]] * self.n_real
         #: how this compiler reached the snapshot it points at ("full":
         #: built anew, "delta": advanced, "kept": asked again at the same
-        #: snapshot), and the resident pods that step's `_derive` walked
+        #: snapshot); the resident term-carrying pods that step looked at
+        #: (the lengths of the two lists of every node it read: a pod on
+        #: both counts twice), and how many of them came and went
         self.reached = "full"
-        self.walked = 0
         self._point_at(snapshot)
-        self._derive()
+        self._reread(range(self.n_real))
+        self._drop_counted()
 
     def _resolver_epoch(self) -> int:
         return self.ns_resolver.epoch if self.ns_resolver is not None else -1
@@ -111,58 +163,58 @@ class AffinityCompiler:
             and self.generation == snapshot.generation \
             and self.ns_epoch == self._resolver_epoch()
 
-    def _derive(self) -> None:
-        """From the snapshot pointed at, whose pod counts `self.sigs`
-        holds: rebuild the carriers of resident pods' own terms and drop
-        every cache derived from pod counts."""
-        snapshot, n_pad = self.snapshot, self.n_pad
-        # a pod on both of the snapshot's lists is walked, and counted, twice
-        walked = 0
-        # Resident pods' required anti-affinity terms (symmetry source):
-        # term signature -> (carrier-count vector over nodes, term, owner_ns).
-        self.resident_anti: dict[str, tuple[np.ndarray, dict, str]] = {}
-        for ni in snapshot.have_pods_with_required_anti_affinity:
-            n = self._pos(ni)
-            walked += len(ni.pods_with_required_anti_affinity)
-            for pi in ni.pods_with_required_anti_affinity:
-                for term in pi.required_anti_affinity_terms:
-                    key = repr((term, pi.namespace))
-                    got = self.resident_anti.get(key)
-                    if got is None:
-                        vec = np.zeros((n_pad,), dtype=np.float32)
-                        self.resident_anti[key] = (vec, term, pi.namespace)
-                        got = self.resident_anti[key]
-                    got[0][n] += 1.0
-        # Resident pods' PREFERRED terms + required-affinity terms (score
-        # symmetry sources — scoring.go's second loop): term signature →
-        # (weight-summed carrier vector over nodes, term, owner_ns).
-        # Preferred anti-affinity carriers get negative weights.
-        self.resident_score: dict[
-            str, tuple[np.ndarray, dict, str, bool]] = {}
+    def kept(self) -> None:
+        """Asked again at the snapshot pointed at: nothing was read."""
+        self.reached = "kept"
+        self.walked = self.came = self.gone = 0
 
-        def _carrier(term: dict, ns: str, n: int, w: float,
-                     is_hard: bool = False) -> None:
-            key = repr((term, ns, is_hard))
-            got = self.resident_score.get(key)
-            if got is None:
-                got = self.resident_score[key] = (
-                    np.zeros((n_pad,), dtype=np.float32), term, ns, is_hard)
-            got[0][n] += w
+    def _reread(self, rows) -> None:
+        """Bring the carriers of residents' own terms at node indices
+        `rows` up to the two term-carrying lists of the snapshot's nodes:
+        add the terms of the pods that came since each list was last
+        read, take off those of the ones that went."""
+        nodes = self.snapshot.nodes
+        walked = came_n = gone_n = 0
+        for read, attr, move in (
+                (self._read_anti, "pods_with_required_anti_affinity",
+                 self._move_anti),
+                (self._read_score, "pods_with_affinity", self._move_score)):
+            for n in rows:
+                now = getattr(nodes[n], attr)
+                seen = read[n]
+                if not now and not seen:
+                    continue
+                read[n] = now
+                walked += len(now)
+                came, gone = came_and_gone(seen, now)
+                came_n += len(came)
+                gone_n += len(gone)
+                for pi in came:
+                    move(pi, n, 1)
+                for pi in gone:
+                    move(pi, n, -1)
+        self.walked, self.came, self.gone = walked, came_n, gone_n
 
-        for ni in snapshot.have_pods_with_affinity:
-            n = self._pos(ni)
-            walked += len(ni.pods_with_affinity)
-            for pi in ni.pods_with_affinity:
-                for t in pi.preferred_affinity_terms:
-                    _carrier(t.get("podAffinityTerm") or {}, pi.namespace,
-                             n, float(t.get("weight", 1)))
-                for t in pi.preferred_anti_affinity_terms:
-                    _carrier(t.get("podAffinityTerm") or {}, pi.namespace,
-                             n, -float(t.get("weight", 1)))
-                for t in pi.required_affinity_terms:
-                    # hardPodAffinityWeight multiplies at score_row time.
-                    _carrier(t, pi.namespace, n, 1.0, is_hard=True)
-        self.walked = walked
+    def _move_anti(self, pi: PodInfo, n: int, step: int) -> None:
+        ns = pi.namespace
+        for term in pi.required_anti_affinity_terms:
+            self.resident_anti.move(
+                repr((term, ns)), (term, ns), n, 1.0, step)
+
+    def _move_score(self, pi: PodInfo, n: int, step: int) -> None:
+        ns, carriers = pi.namespace, self.resident_score
+        for sign, preferred in ((1.0, pi.preferred_affinity_terms),
+                                (-1.0, pi.preferred_anti_affinity_terms)):
+            for t in preferred:
+                term = t.get("podAffinityTerm") or {}
+                carriers.move(repr((term, ns, False)), (term, ns, False), n,
+                              sign * float(t.get("weight", 1)), step)
+        for term in pi.required_affinity_terms:
+            carriers.move(repr((term, ns, True)), (term, ns, True), n,
+                          1.0, step)
+
+    def _drop_counted(self) -> None:
+        """Drop every cache derived from pod counts."""
         #: per-pending-pod-signature symmetry-match cache
         self._sym_match_cache: dict[tuple, bool] = {}
         #: per-(term,ns) per-node matching-count cache
@@ -181,21 +233,12 @@ class AffinityCompiler:
         self._filter_row_cache: dict[tuple, np.ndarray] = {}
         self._score_row_cache: dict[tuple, np.ndarray] = {}
 
-    def _pos(self, ni) -> int:
-        """Snapshot position of a node that carries affinity terms (the
-        name map is made on first need: where no resident pod carries a
-        term, never)."""
-        if self._node_pos is None:
-            self._node_pos = {
-                n.name: i for i, n in enumerate(self.snapshot.nodes)}
-        return self._node_pos[ni.name]
-
     def advance(self, snapshot: Snapshot, n_pad: int) -> int | None:
-        """Move to a later `snapshot` of the same node set by recounting
-        the rows its changed-node log names; returns how many. None = the
-        handles do not vouch for it (no handles, node set or a node object
-        changed, a namespace relabelled, log too short, node count
-        differs) and the caller builds anew."""
+        """Move to a later `snapshot` of the same node set by reading
+        again the nodes its changed-node log names; returns how many.
+        None = the handles do not vouch for it (no handles, node set or a
+        node object changed, a namespace relabelled, log too short, node
+        count differs) and the caller builds anew."""
         if self.set_epoch < 0 or snapshot.set_epoch != self.set_epoch \
                 or snapshot.spec_seq != self.spec_seq \
                 or n_pad != self.n_pad \
@@ -208,10 +251,11 @@ class AffinityCompiler:
         if changed is None:
             return None
         self._point_at(snapshot)
-        self.reached, self.walked = "delta", 0
+        self.reached = "delta"
+        self._reread(changed)
         if changed:
             self.sigs.recount(snapshot.nodes, changed)
-            self._derive()
+            self._drop_counted()
         return len(changed)
 
     # -- primitives --------------------------------------------------------

@@ -1143,16 +1143,19 @@ class TPUBackend:
     def _affinity_compiler(self, snapshot: Snapshot, ct: ClusterTensors):
         """The affinity compiler AT `snapshot`. One compiler serves every
         generation of a node set: it advances by the snapshot's
-        changed-node log (AffinityCompiler.advance) and is built anew —
-        one walk over every resident pod — only when the handles do not
-        vouch for that, or the namespace resolver moved. The registry says
-        which each build was, and how many node rows it counted."""
+        changed-node log (AffinityCompiler.advance: the changed nodes'
+        rows recounted, the carriers of residents' own terms moved by the
+        pods that came to and left those nodes) and is built anew — one
+        walk over every resident pod — only when the handles do not vouch
+        for that, or the namespace resolver moved. The registry says which
+        each build was, how many node rows it counted, how many
+        term-carrying residents it looked at and how many of them moved."""
         resolver = getattr(self, "_ns_resolver", None)
         cached = self._affinity
         if cached is not None and cached.ns_resolver is not resolver:
             cached = None  # another profile's resolver: other namespace sets
         if cached is not None and cached.at(snapshot):
-            cached.reached, cached.walked = "kept", 0
+            cached.kept()
             return cached
         recounted = cached.advance(snapshot, ct.n_pad) \
             if cached is not None else None
@@ -1165,6 +1168,8 @@ class TPUBackend:
             self.metrics.affinity_compiler_builds.inc(kind=cached.reached)
             self.metrics.affinity_rows_recounted.inc(recounted)
             self.metrics.affinity_carriers_walked.inc(cached.walked)
+            self.metrics.affinity_carriers_moved.inc(cached.came, dir="came")
+            self.metrics.affinity_carriers_moved.inc(cached.gone, dir="gone")
         return cached
 
     def _affinity_rows(self, plugin, pods: list[PodInfo], skip: set[int],
@@ -1174,8 +1179,8 @@ class TPUBackend:
         chunk's gated pods, grouped by row identity: `filter_row` hands
         every pod of one content signature the same cached object, so
         {id(row): (row, chunk indices of its pods)}. Whatever this spends
-        in the compiler — reaching the snapshot (build, or advance and
-        `_derive`) and the rows themselves — is the span
+        in the compiler — reaching the snapshot (build, or advance by
+        the changed nodes) and the rows themselves — is the span
         `solver.affinity_rows`, and its wall the histogram
         `scheduler_tpu_affinity_rows_seconds`: the span's own clock reads
         where tracing is on, two reads of that clock where it is off."""
@@ -1197,6 +1202,7 @@ class TPUBackend:
             if sp is not None:
                 sp.attrs.update(
                     build=compiler.reached, carriers=compiler.walked,
+                    came=compiler.came, gone=compiler.gone,
                     terms=len(compiler.resident_anti), rows=len(groups))
         if self.metrics is not None:
             self.metrics.affinity_rows_duration.observe(
@@ -1312,22 +1318,16 @@ class TPUBackend:
         return np.where(constrained & np.isfinite(best),
                         np.maximum(best, 0.0), 0.0)
 
-    def _ipa_score_relevant(self, pi: PodInfo, snapshot: Snapshot) -> bool:
+    @staticmethod
+    def _ipa_score_relevant(pi: PodInfo, compiler) -> bool:
         """InterPodAffinity Score is nonzero only if the pod has preferred
         terms, or some resident pod contributes symmetry weight (preferred
-        terms, or required affinity terms × hardPodAffinityWeight)."""
-        if pi.preferred_affinity_terms or pi.preferred_anti_affinity_terms:
-            return True
-        cached = getattr(self, "_ipa_resident_relevant", None)
-        if cached is not None and cached[0] == snapshot.generation:
-            return cached[1]
-        relevant = any(
-            e.preferred_affinity_terms or e.preferred_anti_affinity_terms
-            or e.required_affinity_terms
-            for ni in snapshot.have_pods_with_affinity
-            for e in ni.pods_with_affinity)
-        self._ipa_resident_relevant = (snapshot.generation, relevant)
-        return relevant
+        terms, or required affinity terms × hardPodAffinityWeight): the
+        compiler at the snapshot keeps those by node
+        (AffinityCompiler.resident_score), so nothing is walked to ask."""
+        return bool(pi.preferred_affinity_terms
+                    or pi.preferred_anti_affinity_terms
+                    or compiler.resident_score)
 
     # -- host rows -----------------------------------------------------------
 
@@ -2466,7 +2466,9 @@ class TPUBackend:
                         got[2].append(i)
                         continue
                     if name == "InterPodAffinity":
-                        if not self._ipa_score_relevant(pi, snapshot):
+                        if compiler is None:
+                            compiler = self._affinity_compiler(snapshot, ct)
+                        if not self._ipa_score_relevant(pi, compiler):
                             # No preferred terms anywhere and no
                             # hard-affinity symmetry sources → every score
                             # is 0; skip the O(N × residents) walk.
@@ -2482,9 +2484,6 @@ class TPUBackend:
                               repr(pi.preferred_anti_affinity_terms))
                         got = norm_memo.get(nk)
                         if got is None:
-                            if compiler is None:
-                                compiler = self._affinity_compiler(
-                                    snapshot, ct)
                             feas = feasible_idx(i)
                             feas_mask = np.zeros(
                                 (ct.n_pad,), dtype=np.bool_)

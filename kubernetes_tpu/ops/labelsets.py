@@ -31,6 +31,20 @@ def _sig(pi: PodInfo) -> tuple:
     return (pi.namespace, tuple(sorted(pi.labels.items())))
 
 
+def came_and_gone(seen: list[PodInfo], now: list[PodInfo]):
+    """The pods that came to one of a node's pod lists and the ones that
+    went since it read `seen` — THE rule of every table advanced by the
+    changed-node log. A pod is its PodInfo object: an update replaces it
+    and counts as one of each. The cache replaces a changed node's clone,
+    so a list read once is never written again."""
+    k = len(seen)
+    if len(now) >= k and now[:k] == seen:
+        return now[k:], ()              # nothing left: the new tail
+    had, have = set(seen), set(now)
+    return ([pi for pi in now if pi not in had],
+            [pi for pi in seen if pi not in have])
+
+
 class LabelSigTable:
     """Unique (namespace, labels) signatures of resident pods and how many
     pods of each sit on each node: `node_sig_count` (n_pad, U).
@@ -67,22 +81,14 @@ class LabelSigTable:
     def recount(self, nodes: Sequence[NodeInfo], rows) -> None:
         """Bring the rows of node indices `rows` up to `nodes[i].pods`: add
         the pods that came since the row was last counted, take off the
-        ones that went (a pod is its PodInfo object: an update replaces
-        it, and counts as one of each)."""
+        ones that went (`came_and_gone`)."""
         at_n: list[int] = []
         at_u: list[int] = []
         by: list[float] = []
         for n in rows:
             pods = nodes[n].pods
-            seen = self._counted[n]
+            came, gone = came_and_gone(self._counted[n], pods)
             self._counted[n] = pods
-            k = len(seen)
-            if len(pods) >= k and pods[:k] == seen:
-                came, gone = pods[k:], ()   # nothing left: the new tail
-            else:
-                had, have = set(seen), set(pods)
-                came = [pi for pi in pods if pi not in had]
-                gone = [pi for pi in seen if pi not in have]
             for moved, step in ((came, 1.0), (gone, -1.0)):
                 for pi in moved:
                     at_n.append(n)

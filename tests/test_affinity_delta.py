@@ -57,25 +57,64 @@ def _term(app: str, key: str) -> dict:
 
 
 def _resident(rng: random.Random, name: str, node: str,
-              labels: dict | None = None) -> PodInfo:
-    """A bound pod; one in five carries terms of its own (the carriers)."""
+              labels: dict | None = None, key: str | None = None,
+              every: bool = False) -> PodInfo:
+    """A bound pod; one in five carries terms of its own (the carriers),
+    or `every` one a required anti-affinity term, as in the benchmark's
+    anti-affinity cell. `key` = the topology key of those terms (None:
+    drawn, hostname or zone)."""
     aff = None
     r = rng.random()
+    if every:
+        r *= 0.1
     if r < 0.1:
         aff = {"podAntiAffinity": {
             "requiredDuringSchedulingIgnoredDuringExecution": [
                 _term(rng.choice(["web", "db"]),
-                      rng.choice([HOSTNAME, ZONE]))]}}
+                      key or rng.choice([HOSTNAME, ZONE]))]}}
     elif r < 0.2:
         aff = {"podAffinity": {
             "preferredDuringSchedulingIgnoredDuringExecution": [
                 {"weight": rng.randrange(1, 50), "podAffinityTerm":
-                 _term(rng.choice(["web", "cache"]), ZONE)}]}}
+                 _term(rng.choice(["web", "cache"]), key or ZONE)}]}}
     return PodInfo(make_pod(
         name, uid=name, node_name=node, affinity=aff,
         labels=dict(labels if labels is not None else rng.choice(SIGS)),
         namespace=rng.choice(["default", "other"]),
         requests={"cpu": "10m"}))
+
+
+def _group_member(name: str, node: str, group: str, key: str,
+                  namespace: str) -> PodInfo:
+    """The cell's pod: labelled with its group, and carrying the one
+    required anti-affinity term that selects the group."""
+    return PodInfo(make_pod(
+        name, uid=name, node_name=node, labels={"app": group},
+        namespace=namespace, requests={"cpu": "10m"},
+        affinity={"podAntiAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [
+                _term(group, key)]}}))
+
+
+def _rich(name: str, node: str, key: str, namespace: str) -> PodInfo:
+    """A pod with a term of every kind that weighs back in the score:
+    preferred, preferred anti and required affinity (and a required
+    anti-affinity term beside them)."""
+    return PodInfo(make_pod(
+        name, uid=name, node_name=node, labels={"app": "cache"},
+        namespace=namespace, requests={"cpu": "10m"},
+        affinity={
+            "podAffinity": {
+                "requiredDuringSchedulingIgnoredDuringExecution": [
+                    _term("db", key)],
+                "preferredDuringSchedulingIgnoredDuringExecution": [
+                    {"weight": 9, "podAffinityTerm": _term("web", key)}]},
+            "podAntiAffinity": {
+                "requiredDuringSchedulingIgnoredDuringExecution": [
+                    _term("cache", key)],
+                "preferredDuringSchedulingIgnoredDuringExecution": [
+                    {"weight": 4, "podAffinityTerm": _term("web", key)},
+                    {"weight": 3, "podAffinityTerm": _term("db", ZONE)}]}}))
 
 
 def _spread_constraint(app: str, skew: int = 2, **extra) -> dict:
@@ -114,13 +153,19 @@ def _pending() -> list[PodInfo]:
 class _Churn:
     """A seeded sequence of cache mutations of every kind the changed-node
     log reports: binds, deletes, a pod of a NEW signature, the last pod of a
-    signature leaving, an assume with its forget, an update in place."""
+    signature leaving, an assume with its forget, an update in place — and,
+    for pods that carry terms: a group bound in one batch, the binding's
+    update of its members, a pod with terms of every kind, and a group
+    deleted down to the last carrier of its term."""
 
-    def __init__(self, cache: SchedulerCache, rng: random.Random):
+    def __init__(self, cache: SchedulerCache, rng: random.Random,
+                 key: str | None = None, every: bool = False):
         self.cache, self.rng = cache, rng
+        self.key, self.every = key, every
         self.live: list[str] = []       # keys of resident pods
         self.seq = 0
         self.assumed: str | None = None
+        self.groups: dict[str, list[str]] = {}   # group -> its pods' keys
 
     def _name(self) -> str:
         self.seq += 1
@@ -130,7 +175,7 @@ class _Churn:
         names = sorted(self.cache.nodes)
         for _ in range(count):
             pi = _resident(self.rng, self._name(), self.rng.choice(names),
-                           labels)
+                           labels, self.key, self.every)
             self.cache.add_pod(pi)
             self.live.append(pi.key)
 
@@ -139,9 +184,46 @@ class _Churn:
             key = self.live.pop(self.rng.randrange(len(self.live)))
             self.cache.remove_pod(key)
 
+    def bind_group(self, group: str, count: int,
+                   namespace: str = "default") -> None:
+        """A batch of the cell's pods: one group, a node each."""
+        nodes = self.rng.sample(sorted(self.cache.nodes), count)
+        for node in nodes:
+            pi = _group_member(self._name(), node, group,
+                               self.key or HOSTNAME, namespace)
+            self.cache.add_pod(pi)
+            self.groups.setdefault(group, []).append(pi.key)
+
+    def confirm(self, keys: list[str]) -> None:
+        """The binding's own event: a NEW PodInfo of the same pod on the
+        same node replaces the one the cache holds."""
+        for key in keys:
+            old = self.cache._pod_states[key]["pod"]
+            self.cache.update_pod(PodInfo(
+                {**old.pod, "metadata": dict(old.pod["metadata"])}))
+
+    def unbind(self, group: str, count: int) -> None:
+        for _ in range(count):
+            self.cache.remove_pod(self.groups[group].pop())
+
     def step(self, k: int) -> None:
         rng = self.rng
-        if k == 3:
+        if k == 2:
+            self.bind_group("solo", 3)
+            self.bind_group("solo", 2, "other")   # the same term, its own key
+        elif k == 4:
+            self.confirm(self.groups["solo"])
+            pi = _rich(self._name(), "n4", self.key or ZONE, "other")
+            self.cache.add_pod(pi)
+            self.groups["rich"] = [pi.key]
+        elif k == 5:
+            self.unbind("solo", 4)      # one carrier of one key is left
+        elif k == 7:
+            self.unbind("solo", 1)      # ... and its key goes with it
+            self.confirm(self.groups["rich"])
+        elif k == 10:
+            self.unbind("rich", 1)
+        elif k == 3:
             self.bind(2, {"app": "extra"})      # a signature nobody had
         elif k == 6:
             # the last pods of that signature leave: a zero column stays
@@ -209,20 +291,34 @@ def _same_answers(advanced: AffinityCompiler, fresh: AffinityCompiler,
                                   fresh.spread_filter_row(pod, cs))
             assert np.array_equal(advanced.spread_raw_scores(pod, cs),
                                   fresh.spread_raw_scores(pod, cs))
-    assert set(advanced.resident_anti) == set(fresh.resident_anti)
-    for key, (vec, _, _) in fresh.resident_anti.items():
-        assert np.array_equal(advanced.resident_anti[key][0], vec)
-    assert set(advanced.resident_score) == set(fresh.resident_score)
-    for key, (vec, _, _, _) in fresh.resident_score.items():
-        assert np.array_equal(advanced.resident_score[key][0], vec)
+    # the carriers of residents' own terms: the keys of a compiler built
+    # anew (none whose last carrier went), each with its vector and what
+    # it holds of the term
+    for name in ("resident_anti", "resident_score"):
+        got, want = getattr(advanced, name), getattr(fresh, name)
+        assert set(got) == set(want)
+        assert len(got) == len(want) and bool(got) == bool(want)
+        for key, (vec, *held) in want.items():
+            assert np.array_equal(got[key][0], vec)
+            assert list(got[key][1:]) == held     # term, owner_ns[, is_hard]
+    # whether a resident weighs back in the score, read from what is kept
+    # and walked from the pods
+    weighs_back = any(
+        pi.preferred_affinity_terms or pi.preferred_anti_affinity_terms
+        or pi.required_affinity_terms
+        for ni in snapshot.nodes for pi in ni.pods)
+    plain = _pending()[-2]          # p-spread: no preferred term of its own
+    assert TPUBackend._ipa_score_relevant(plain, advanced) == weighs_back
+    assert TPUBackend._ipa_score_relevant(plain, fresh) == weighs_back
+    assert TPUBackend._ipa_score_relevant(_pending()[3], advanced)  # p-pref
 
 
-def _standing(seed: int):
+def _standing(seed: int, key: str | None = None, every: bool = False):
     """A cluster with thirty residents, the churn that will move it, a
     framework, and the batch of spread pods every table is built for."""
     rng = random.Random(seed)
     cache = _cluster(rng)
-    churn = _Churn(cache, rng)
+    churn = _Churn(cache, rng, key, every)
     churn.bind(30)
     fwk = Framework(build_plugins(), DEFAULT_SCORE_WEIGHTS)
     batch = [p for p in _pending() if p.topology_spread_constraints]
@@ -307,6 +403,95 @@ def test_an_advanced_compiler_answers_as_one_built_anew(seed):
     planes = kept.metrics.spread_table_builds
     assert (planes.value(planes="built"), planes.value(planes="kept")) \
         == (1, 14)
+
+
+def _anti_key(group: str, key: str, namespace: str) -> str:
+    return repr((_term(group, key), namespace))
+
+
+@pytest.mark.parametrize("every", [False, True],
+                         ids=["one-in-five", "every-resident"])
+@pytest.mark.parametrize("key", [HOSTNAME, ZONE], ids=["hostname", "zone"])
+@pytest.mark.parametrize("seed", [21, 22])
+def test_carriers_come_and_go_and_the_last_takes_its_key(seed, key, every):
+    """The history of the churn with the carriers watched: a group's term
+    is one key per owner namespace while a member is resident and none
+    after the last left; the binding's update moves as many as it takes
+    off; a pod with terms of every kind brings the score's keys and takes
+    them away. At every step the compiler is the one built anew."""
+    cache, churn, _, _ = _standing(seed, key, every)
+    snapshot = cache.update_snapshot()
+    advanced = AffinityCompiler(snapshot, N_PAD)
+    assert (advanced.came, advanced.gone) == (advanced.walked, 0)
+    solo = [_anti_key("solo", key, ns) for ns in ("default", "other")]
+    hard = repr((_term("db", key), "other", True))
+    held, moved = {}, {}
+    for k in range(14):
+        churn.step(k)
+        snapshot = cache.update_snapshot()
+        changed = snapshot.changed_since(advanced.generation)
+        assert advanced.advance(snapshot, N_PAD) == len(changed)
+        assert advanced.reached == "delta"
+        # what a delta looks at: the two lists of the nodes the log named
+        assert advanced.walked == sum(
+            len(snapshot.nodes[n].pods_with_required_anti_affinity)
+            + len(snapshot.nodes[n].pods_with_affinity) for n in changed)
+        _same_answers(advanced, AffinityCompiler(snapshot, N_PAD), snapshot)
+        held[k] = [key_ in advanced.resident_anti for key_ in solo]
+        moved[k] = (advanced.came, advanced.gone)
+        if k in (4, 9):
+            assert advanced.resident_score[hard][3] is True
+            assert TPUBackend._ipa_score_relevant(
+                _pending()[-2], advanced)
+    assert held[1] == [False, False] and held[2] == [True, True]
+    assert held[5] == [True, False]          # four left: one key emptied
+    assert held[7] == [False, False]         # the last carrier went
+    # step 2 binds five members, each on both of its node's lists
+    assert moved[2] == (10, 0)
+    # step 4: their update is one of each, and the rich pod came
+    assert moved[4] == (12, 10)
+    assert moved[5] == (0, 8)
+    assert hard not in advanced.resident_score     # the rich pod left at 10
+    if every:
+        assert not advanced.resident_score
+
+
+def test_a_delta_looks_at_the_changed_nodes_carriers_not_at_every_resident():
+    """200 nodes, 2,000 residents that all carry a term: binding ten more
+    is an advance over ten nodes' lists — tens of carriers, not 4,000."""
+    rng = random.Random(5)
+    cache = SchedulerCache()
+    for i in range(200):
+        cache.add_node(_node(i, rng.choice(ZONES)))
+    churn = _Churn(cache, rng, HOSTNAME)
+    for g in range(20):
+        churn.bind_group(f"g{g}", 100)
+    snapshot = cache.update_snapshot()
+    n_pad = 256
+    advanced = AffinityCompiler(snapshot, n_pad)
+    assert (advanced.walked, advanced.came, advanced.gone) == (4000, 4000, 0)
+    assert len(advanced.resident_anti) == 20
+    churn.bind_group("g20", 10)
+    snapshot = cache.update_snapshot()
+    changed = snapshot.changed_since(advanced.generation)
+    assert advanced.advance(snapshot, n_pad) == len(changed) == 10
+    assert advanced.walked == sum(
+        len(snapshot.nodes[n].pods_with_required_anti_affinity)
+        + len(snapshot.nodes[n].pods_with_affinity) for n in changed)
+    assert 20 <= advanced.walked < 400
+    assert (advanced.came, advanced.gone) == (20, 0)
+    assert len(advanced.resident_anti) == 21
+    # the binding's update of those ten: as many came as went
+    churn.confirm(churn.groups["g20"])
+    snapshot = cache.update_snapshot()
+    assert advanced.advance(snapshot, n_pad) == 10
+    assert advanced.walked < 400
+    assert (advanced.came, advanced.gone) == (20, 20)
+    fresh = AffinityCompiler(snapshot, n_pad)
+    assert set(advanced.resident_anti) == set(fresh.resident_anti)
+    for key, (vec, _, _) in fresh.resident_anti.items():
+        assert np.array_equal(advanced.resident_anti[key][0], vec)
+    assert not advanced.resident_score
 
 
 def test_an_empty_cluster_builds_and_advances():

@@ -297,9 +297,11 @@ def _affinity_spans(backend):
 
 def test_the_span_says_how_the_compiler_reached_the_snapshot():
     """`solver.affinity_rows` (layer `attempt`), one per chunk with a
-    gated pod: the first of an assign() builds or advances and walks
-    the resident carriers, the rest are `kept`; the histogram is
-    observed once per span and the counter grows by what was walked."""
+    gated pod: the first of an assign() builds (looking at every resident
+    carrier) or advances (looking at the carriers of the nodes that
+    changed, and moving the ones that came and went), the rest are
+    `kept`; the histogram is observed once per span and the counters grow
+    by what was looked at and what moved."""
     from kubernetes_tpu.utils.tracing import layer_of
     cluster = _Cluster(_deployment(), RESIDENTS)
     backend = _backend(max_batch=16, tracer=True)
@@ -307,22 +309,37 @@ def test_the_span_says_how_the_compiler_reached_the_snapshot():
         pods = _mixed_batch(cluster, 5)
         left, _ = cluster.assign(backend, pods[:24])      # two chunks
         first, second = _affinity_spans(backend)
-        # each resident is on both of its node's lists: walked twice
+        # each resident is on both of its node's lists: looked at twice,
+        # and to a compiler built anew every one of them came
         assert first.attrs == {"build": "full", "terms": 2, "rows": 3,
-                               "carriers": 2 * len(RESIDENTS)}
+                               "carriers": 2 * len(RESIDENTS),
+                               "came": 2 * len(RESIDENTS), "gone": 0}
         assert second.attrs["build"] == "kept"
-        assert second.attrs["carriers"] == 0
+        assert (second.attrs["carriers"], second.attrs["came"],
+                second.attrs["gone"]) == (0, 0, 0)
         assert layer_of("solver.affinity_rows") == "attempt"
         metrics = backend.metrics
         assert metrics.affinity_carriers_walked.value() == 2 * len(RESIDENTS)
+        moved = metrics.affinity_carriers_moved
+        assert (moved.value(dir="came"), moved.value(dir="gone")) \
+            == (2 * len(RESIDENTS), 0)
         resident = len(cluster.at)
+        bound = resident - len(RESIDENTS)
+        # the nodes the first assign() bound a pod to, and all they hold
+        changed = set(list(cluster.at.values())[len(RESIDENTS):])
+        reread = sum(node in changed for node in cluster.at.values())
         cluster.assign(backend, left + pods[24:])
         third = _affinity_spans(backend)[2]
         assert third.attrs["build"] == "delta"
-        assert third.attrs["carriers"] == 2 * resident
+        # a delta reads the two lists of the changed nodes, not every
+        # resident, and moves the pods that were bound since
+        assert third.attrs["carriers"] == 2 * reread
+        assert (third.attrs["came"], third.attrs["gone"]) == (2 * bound, 0)
         assert third.attrs["terms"] == 3                  # gc is resident now
         assert metrics.affinity_carriers_walked.value() == \
-            2 * len(RESIDENTS) + 2 * resident
+            2 * len(RESIDENTS) + 2 * reread
+        assert (moved.value(dir="came"), moved.value(dir="gone")) \
+            == (2 * resident, 0)
         spans = _affinity_spans(backend)
         _, total = metrics.affinity_rows_duration.snapshot()
         assert total == len(spans)
