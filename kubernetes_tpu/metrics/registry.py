@@ -300,6 +300,15 @@ class WatchMetrics:
         self.index_hits = r.counter(
             "watch_index_hits_total",
             "Events routed through the tracked-field exact-value index")
+        #: one family for both of the store's event windows (a second
+        #: Counter of the same name could not render beside it): the
+        #: mvcc replay log counts window="log", each watch-cache ring
+        #: (store/cacher.py, whose store owns this object) window="cache".
+        self.window_evictions = r.counter(
+            "store_window_evictions_total",
+            "Entries dropped from a full event window: the store's "
+            "replay log (window=log) or a watch-cache ring (window=cache)",
+            labels=("window", "resource"))
 
     def register_into(self, registry: Registry) -> None:
         """Expose these counters through another registry's render: the
@@ -307,7 +316,7 @@ class WatchMetrics:
         surfaces them at /metrics — same Counter objects, one source of
         truth."""
         for c in (self.events_dispatched, self.predicate_checks,
-                  self.index_hits):
+                  self.index_hits, self.window_evictions):
             registry._metrics.setdefault(c.name, c)
 
 

@@ -15,11 +15,11 @@ of the cacher's one etcd watch), which maintains, per resource:
   scan-and-copy per agent — the cold-start relist storm of N agents
   becomes N reads of one shared snapshot;
 - an **event ring**: the last `ring_capacity` events with their
-  pre-update objects, so watch backfill ("start at RV") is a bisect +
-  slice instead of a scan over the store's global history, and LIST *at
-  any cached RV* is a roll-back of the current snapshot — which is what
-  pins paginated `continue` tokens to one snapshot RV across pages on
-  every wire.
+  pre-update objects, so watch backfill ("start at RV") is a walk back
+  from the ring's newest end instead of a scan over the store's global
+  history, and LIST *at any cached RV* is a roll-back of the current
+  snapshot — which is what pins paginated `continue` tokens to one
+  snapshot RV across pages on every wire.
 
 RV-semantics contract (served identically on HTTP, KTPU and gRPC —
 documented in the README architecture section):
@@ -53,12 +53,13 @@ from __future__ import annotations
 
 import logging
 from bisect import bisect_right, insort
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import Any, Mapping
 
 from kubernetes_tpu.api.labels import Selector
 from kubernetes_tpu.api.meta import deep_copy, namespace_of
 from kubernetes_tpu.metrics.registry import WatchCacheMetrics
+from kubernetes_tpu.store.mvcc import push_window, rebound_window
 
 logger = logging.getLogger(__name__)
 
@@ -96,17 +97,19 @@ class _ResourceCache:
     """One resource's snapshot + ring (watch_cache.go watchCache)."""
 
     __slots__ = ("resource", "snapshot", "keys", "ring", "ring_floor",
-                 "tracked", "field_index", "_ring_key")
+                 "tracked", "field_index", "_ring_key", "_evict_key")
 
-    def __init__(self, resource: str, store):
+    def __init__(self, resource: str, store, capacity: int):
         self.resource = resource
         table = store._table(resource)
         # Shared refs with the store: the one cold table read per
         # resource (the "≤1 mvcc LIST per resource" seed).
         self.snapshot: dict[str, dict] = dict(table)
         self.keys: list[str] = sorted(table.keys())
-        #: ring entries (rv, key, Event, prev_obj|None), rv-monotonic.
-        self.ring: list[tuple[int, str, Any, dict | None]] = []
+        #: ring entries (rv, key, Event, prev_obj|None), rv-monotonic;
+        #: the last `capacity` (store/mvcc.py `push_window`).
+        self.ring: deque[tuple[int, str, Any, dict | None]] = \
+            deque(maxlen=capacity)
         #: every event with rv > ring_floor is retained in the ring;
         #: requests below it fall back to the mvcc core.
         self.ring_floor = store.resource_version
@@ -121,6 +124,7 @@ class _ResourceCache:
                     self.field_index[f].setdefault(
                         _field_value(obj, f), set()).add(key)
         self._ring_key = (resource,)  # cached gauge label tuple
+        self._evict_key = ("cache", resource)  # window_evictions labels
 
 
 class Cacher:
@@ -139,6 +143,28 @@ class Cacher:
 
     # -- cache maintenance -------------------------------------------------
 
+    def _ring_cap(self) -> int:
+        # Capped at the store's own event window too: a per-resource ring
+        # must never serve an RV the store has contractually compacted
+        # (the 410 window is API surface clients relist on).
+        return min(self._ring_capacity, self._store._event_window)
+
+    def rebound(self) -> None:
+        """Re-size every ring to `_ring_cap()` (the store's window was
+        resized): the oldest entries past it leave, floors advance."""
+        cap = self._ring_cap()
+        for c in self._caches.values():
+            if c.ring.maxlen == cap:
+                continue
+            c.ring, dropped = rebound_window(c.ring, cap)
+            for entry in dropped:
+                self._evicted(c, entry)
+            self.metrics.ring_len.set_key(c._ring_key, len(c.ring))
+
+    def _evicted(self, c: _ResourceCache, entry: tuple) -> None:
+        c.ring_floor = entry[0]
+        self._store.watch_metrics.window_evictions.inc_key(c._evict_key)
+
     def _cache(self, resource: str) -> _ResourceCache:
         c = self._caches.get(resource)
         if c is None:
@@ -147,7 +173,7 @@ class Cacher:
             # state, one read, and the request is served from the tier
             # — not a miss; misses count requests handed to the core.
             c = self._caches[resource] = _ResourceCache(
-                resource, self._store)
+                resource, self._store, self._ring_cap())
         return c
 
     def ingest(self, resource: str, ev) -> None:
@@ -159,7 +185,8 @@ class Cacher:
         seed absorbs it and coverage begins at `ev.rv`."""
         c = self._caches.get(resource)
         if c is None:
-            self._caches[resource] = _ResourceCache(resource, self._store)
+            self._caches[resource] = _ResourceCache(
+                resource, self._store, self._ring_cap())
             return
         key = self._store._key(ev.object)
         prev = c.snapshot.get(key)
@@ -175,17 +202,10 @@ class Cacher:
             if prev is None:
                 insort(c.keys, key)
             self._index_move(c, key, prev, ev.object)
-        ring = c.ring
-        ring.append((ev.rv, key, ev, prev))
-        # Capped at the store's own event window too: a per-resource ring
-        # must never serve an RV the store has contractually compacted
-        # (the 410 window is API surface clients relist on).
-        cap = min(self._ring_capacity, self._store._event_window)
-        if len(ring) > cap:
-            drop = len(ring) - cap
-            c.ring_floor = ring[drop - 1][0]
-            del ring[:drop]
-        self.metrics.ring_len.set_key(c._ring_key, len(ring))
+        oldest = push_window(c.ring, (ev.rv, key, ev, prev))
+        if oldest is not None:
+            self._evicted(c, oldest)
+        self.metrics.ring_len.set_key(c._ring_key, len(c.ring))
 
     @staticmethod
     def _index_move(c: _ResourceCache, key: str,
@@ -328,10 +348,11 @@ class Cacher:
         bookmarks: bool = True,
     ):
         """Watch with ring-served backfill: events after `resource_version`
-        come from this resource's ring (bisect + slice) instead of a scan
-        over the store's global history. RVs older than the ring fall back
-        to the mvcc core's replay path, which owns the 410 contract. Live
-        dispatch (the interned selector index) is shared with the core."""
+        come from this resource's ring (a walk back from its newest end,
+        as long as the replay) instead of a scan over the store's global
+        history. RVs older than the ring fall back to the mvcc core's
+        replay path, which owns the 410 contract. Live dispatch (the
+        interned selector index) is shared with the core."""
         from kubernetes_tpu.store.mvcc import Expired
         c = self._cache(resource)
         if resource_version and resource_version > self._store.resource_version:
@@ -350,10 +371,11 @@ class Cacher:
         self.metrics.hits.inc()
         replay = []
         if resource_version:
-            ring = c.ring
-            i = bisect_right(ring, resource_version,
-                             key=lambda e: e[0])
-            replay = [e[2] for e in ring[i:]]
+            for erv, _key, ev, _prev in reversed(c.ring):
+                if erv <= resource_version:
+                    break
+                replay.append(ev)
+            replay.reverse()
         return self._store._open_watch(
             resource, resource_version, namespace, selector,
             fields=fields, bookmarks=bookmarks, replay=replay)

@@ -367,6 +367,11 @@ def recover_store(directory: str,
             store = MVCCStore(rv_source=rv_source)
     # Core subresources survive recovery (new_cluster_store parity).
     store.register_subresource("pods", "binding", binding_subresource)
+    # Watch-resume window: everything since the snapshot is replayable;
+    # anything older is compacted (410 Expired → relist). Replay enters
+    # the window through the commit's own append, so a tail longer than
+    # the window advances the floor past what it drops.
+    store._first_retained_rv = snap_rv + 1
     # Replay WAL segments based at or after the snapshot (older segments
     # were compacted; a crash between snapshot and _gc leaves both).
     for base_rv, path in _latest(directory, _WAL_RE):
@@ -385,10 +390,6 @@ def recover_store(directory: str,
             store._rv = max(store.resource_version, rv)
             if metrics is not None:
                 metrics.replayed.inc()
-            store._events.append(
-                (resource, Event(ev_type, obj, rv, prev_labels,
-                                 prev_fields)))
-    # Watch-resume window: everything since the snapshot is replayable;
-    # anything older is compacted (410 Expired → relist).
-    store._first_retained_rv = snap_rv + 1
+            store._retain(resource, Event(ev_type, obj, rv, prev_labels,
+                                          prev_fields))
     return store

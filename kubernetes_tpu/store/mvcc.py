@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+from collections import deque
 from dataclasses import dataclass
 from typing import Any, AsyncIterator, Awaitable, Callable, Mapping
 
@@ -192,6 +193,28 @@ class ListResult:
 DEFAULT_EVENT_WINDOW = 200_000
 BOOKMARK_INTERVAL_S = 5.0
 
+
+def push_window(window: deque, entry):
+    """Append `entry` to a bounded event window (`deque(maxlen=)`: the
+    store's replay log, each watch-cache ring) and return the entry that
+    left to make room — the oldest, or `entry` itself at capacity 0 —
+    or None while the window has room. A full window drops one entry in
+    O(1); the retained set is exactly the last `maxlen` appended."""
+    if len(window) == window.maxlen:
+        oldest = window[0] if window else entry
+        window.append(entry)
+        return oldest
+    window.append(entry)
+    return None
+
+
+def rebound_window(window: deque, capacity: int) -> tuple[deque, list]:
+    """(`window` with capacity `capacity`, the oldest entries dropped
+    to fit it, oldest first) — a run-time change of a window's size."""
+    dropped = [window.popleft() for _ in range(len(window) - capacity)]
+    return deque(window, maxlen=capacity), dropped
+
+
 # Debug guard (KTPU_DEBUG_FREEZE=1, enabled in tests): stored objects — which
 # watch events share — are recursively frozen, so a handler that mutates a
 # delivered object fails loudly instead of silently corrupting the source of
@@ -256,9 +279,9 @@ class MVCCStore:
         # resource -> key -> object (key = "ns/name" or "name")
         self._tables: dict[str, dict[str, dict]] = {}
         self._rv_counter = rv_source or RVCounter()
-        # Ring of (resource, Event) for watch replay.
-        self._events: list[tuple[str, Event]] = []
-        self._event_window = event_window
+        # Ring of (resource, Event) for watch replay: the last
+        # `event_window` events; every rv >= _first_retained_rv is in it.
+        self._events: deque[tuple[str, Event]] = deque(maxlen=event_window)
         self._first_retained_rv = 1
         self._watchers: list[_WatchChannel] = []
         #: resource -> interned watcher index; `_watchers` stays the flat
@@ -331,6 +354,33 @@ class MVCCStore:
     def resource_version(self) -> int:
         return self._rv_counter.value
 
+    @property
+    def _event_window(self) -> int:
+        return self._events.maxlen
+
+    @_event_window.setter
+    def _event_window(self, capacity: int) -> None:
+        """Resize the replay window on a live store: the oldest events
+        past the new size leave now (floor advanced, evictions counted),
+        and the cacher's rings follow (they never outlive the log)."""
+        self._events, dropped = rebound_window(self._events, capacity)
+        for entry in dropped:
+            self._evicted(entry)
+        if self.cacher is not None:
+            self.cacher.rebound()
+
+    def _retain(self, resource: str, ev: Event) -> None:
+        """Append one event to the replay window; the commit's and the
+        WAL replay's one way in, so the floor always names the oldest
+        retained event."""
+        oldest = push_window(self._events, (resource, ev))
+        if oldest is not None:
+            self._evicted(oldest)
+
+    def _evicted(self, entry: tuple[str, Event]) -> None:
+        self._first_retained_rv = entry[1].rv + 1
+        self.watch_metrics.window_evictions.inc_key(("log", entry[0]))
+
     def _record(self, resource: str, ev: Event) -> None:
         t = self.tracer
         if t.enabled:
@@ -343,11 +393,7 @@ class MVCCStore:
         self._dispatch(resource, ev)
 
     def _commit(self, resource: str, ev: Event) -> None:
-        self._events.append((resource, ev))
-        if len(self._events) > self._event_window:
-            drop = len(self._events) - self._event_window
-            self._first_retained_rv = self._events[drop - 1][1].rv + 1
-            del self._events[:drop]
+        self._retain(resource, ev)
         # Durability sinks (store/durable.py WAL) observe every committed
         # event BEFORE watch dispatch — the etcd raft-log position. A sink
         # failure must not fail the (already committed) write nor starve

@@ -18,6 +18,7 @@ from kubernetes_tpu.scheduler import Scheduler
 from kubernetes_tpu.store import (
     DurabilityManager,
     Expired,
+    MVCCStore,
     install_core_validation,
     new_cluster_store,
     recover_store,
@@ -102,6 +103,41 @@ class TestWALRecovery(unittest.TestCase):
             self.assertEqual([p["metadata"]["name"] for p in pods],
                              ["keep"])
             self.assertEqual(pods[0]["metadata"]["labels"]["x"], "1")
+            re_store.stop()
+        run(body())
+
+    def test_replay_longer_than_the_window_keeps_its_tail(self):
+        """A WAL tail longer than the recovered store's event window
+        enters it through the commit's append: the window holds the last
+        `event_window` events and the floor names the first of them —
+        never a floor that claims events the window dropped."""
+        async def body():
+            d = tempfile.mkdtemp()
+            store = new_cluster_store()
+            DurabilityManager(store, d, fsync="always",
+                              snapshot_interval_s=3600)
+            for i in range(12):
+                await store.create("pods", make_pod(f"p{i}"))
+            written = [int(p["metadata"]["resourceVersion"])
+                       for p in (await store.list("pods")).items]
+            del store
+
+            re_store = recover_store(d, factory=lambda: MVCCStore(
+                event_window=5))
+            kept = [ev.rv for _res, ev in re_store._events]
+            self.assertEqual(kept, sorted(written)[-5:])
+            self.assertEqual(re_store._first_retained_rv, kept[0])
+            with self.assertRaises(Expired):
+                await re_store.watch("pods", resource_version=kept[0] - 2)
+            watch = await re_store.watch("pods",
+                                         resource_version=kept[0] - 1)
+            got = []
+            async for ev in watch:
+                if ev.type != "BOOKMARK":
+                    got.append(ev.rv)
+                if len(got) == 5:
+                    break
+            self.assertEqual(got, kept)
             re_store.stop()
         run(body())
 
