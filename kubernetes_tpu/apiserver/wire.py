@@ -37,7 +37,9 @@ debugging and hand-rolled clients.
     [id, "kinds"]                               (discovery: kind map)
     [id, "multi", [[op, ...args], ...]]         (same-tick op batch)
   server→client: [id, "ok", result] | [id, "err", reason, message]
-    [watch_id, "ev", TYPE, object]              (watch push)
+    [watch_id, "ev", TYPE, object, committed?]  (watch push; committed:
+                                                the write's commit time on
+                                                the wall clock, optional)
     [watch_id, "exp", message]                  (watch 410/terminated)
 
 Reference pointers (SURVEY §5.8 comms backend, §3.2 watch fan-out):
@@ -137,11 +139,11 @@ def _encode_memo(ev: Event, attr: str, encode) -> bytes:
     """Per-codec encode-once across an event AND its synthesized
     enter/leave twins: the store delivers the same Event instance to all
     channels of a selector group, and a twin links its source via
-    `_wire_src` (store/mvcc.py `_synth`) — they share one object, so
-    they share one encoding. The memo is read from/written to both ends
-    of the link, so whichever watcher encodes first pays for everyone
-    (SURVEY §3.2 — the reference cacher serializes once per event, not
-    per watcher)."""
+    `_wire_src` (store/mvcc.py `_synth`) — they share one object and one
+    commit stamp, so they share one encoding. The memo is read from/
+    written to both ends of the link, so whichever watcher encodes first
+    pays for everyone (SURVEY §3.2 — the reference cacher serializes
+    once per event, not per watcher). `encode` takes the event."""
     b = getattr(ev, attr, None)
     if b is not None:
         return b
@@ -149,7 +151,7 @@ def _encode_memo(ev: Event, attr: str, encode) -> bytes:
     if src is not None:
         b = getattr(src, attr, None)
     if b is None:
-        b = encode(ev.object)
+        b = encode(ev)
         if src is not None:
             try:
                 setattr(src, attr, b)
@@ -167,13 +169,41 @@ def encode_event_object(ev: Event) -> bytes:
     shared across every watcher on both wires."""
     return _encode_memo(
         ev, "_wire_obj",
-        lambda obj: _dumps(obj, separators=(",", ":")).encode())
+        lambda e: _dumps(e.object, separators=(",", ":")).encode())
 
 
 def encode_event_object_mp(ev: Event) -> bytes:
     """msgpack twin of encode_event_object — one packing per event
     shared across every msgpack watcher."""
-    return _encode_memo(ev, "_wire_obj_mp", _packb)
+    return _encode_memo(ev, "_wire_obj_mp", lambda e: _packb(e.object))
+
+
+def _wall_stamp(ev: Event) -> float:
+    """`ev.committed` (the store's monotonic clock) on the wall clock,
+    the one clock two hosts share."""
+    return ev.committed + (time.time() - time.monotonic())
+
+
+def _event_tail(ev: Event, mp: bool) -> bytes:
+    """The `ev` frame's elements after TYPE — the object, then the
+    commit stamp when the event has one — packed once per event (+
+    twins) across every watcher of the codec."""
+    if ev.committed is None:
+        return encode_event_object_mp(ev) if mp else encode_event_object(ev)
+    if mp:
+        return _encode_memo(ev, "_wire_tail_mp", lambda e: (
+            _packb(e.object) + _packb(_wall_stamp(e))))
+    return _encode_memo(ev, "_wire_tail", lambda e: (
+        encode_event_object(e) + b"," + repr(_wall_stamp(e)).encode()))
+
+
+def _received_commit(frame: list) -> float | None:
+    """The commit stamp an `ev` frame carries, as a time on this
+    process's monotonic clock: its age at receipt, taken back from now.
+    A frame without one (an older server) gives None."""
+    if len(frame) < 5:
+        return None
+    return time.monotonic() - (time.time() - frame[4])
 
 
 class _Conn(asyncio.Protocol):
@@ -773,17 +803,19 @@ class _Conn(asyncio.Protocol):
                         b'{"metadata":{"resourceVersion":"'
                         + str(ev.rv).encode() + b'"}}]')
                 elif mp:
-                    # Spliced msgpack frame [wid,"ev",TYPE,obj]: fixarray(4)
-                    # header + concatenated elements — msgpack concatenates
-                    # like JSON splices, and the object bytes are packed
-                    # once per event across ALL watchers (the _mp memo).
-                    body = (b"\x94" + wid_b + b"\xa2ev"
-                            + _packb(ev.type) + encode_event_object_mp(ev))
+                    # Spliced msgpack frame [wid,"ev",TYPE,obj,committed]:
+                    # fixarray(5) header (4 without a stamp) + concatenated
+                    # elements — msgpack concatenates like JSON splices,
+                    # and the object and stamp are packed once per event
+                    # across ALL watchers (_event_tail's memo).
+                    body = ((b"\x94" if ev.committed is None else b"\x95")
+                            + wid_b + b"\xa2ev" + _packb(ev.type)
+                            + _event_tail(ev, True))
                 else:
-                    # Spliced frame: the object bytes are encoded once per
-                    # event across ALL watchers (encode_event_object memo).
+                    # Spliced frame: object and stamp are encoded once per
+                    # event across ALL watchers (_event_tail's memo).
                     body = (b'[' + wid_b + b',"ev","' + ev.type.encode()
-                            + b'",' + encode_event_object(ev) + b']')
+                            + b'",' + _event_tail(ev, False) + b']')
                 self.send(body)
                 if self._closed:
                     return
@@ -1206,7 +1238,8 @@ class WireStore:
                                 "(consumer too slow)"))
                     self._send([rid, "stopwatch"])
                 else:
-                    w.queue.put_nowait(("ev", frame[2], frame[3]))
+                    w.queue.put_nowait(("ev", frame[2], frame[3],
+                                        _received_commit(frame)))
             return
         if kind == "exp":
             w = self._watches.pop(rid, None)
@@ -1342,10 +1375,10 @@ class WireStore:
                         if "too old" in msg or "expired" in msg.lower():
                             raise Expired(msg)
                         raise StoreError(msg)
-                    ev_type, obj = rest
+                    ev_type, obj, committed = rest
                     rv = int(obj.get("metadata", {})
                              .get("resourceVersion", 0) or 0)
-                    yield Event(ev_type, obj, rv)
+                    yield Event(ev_type, obj, rv, None, None, committed)
             finally:
                 w.closed = True
                 if self._watches.pop(wid, None) is not None \

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from typing import Any, Callable, Iterable, Mapping
 
 from kubernetes_tpu.api.labels import Selector
@@ -140,6 +141,14 @@ class SharedInformer:
         self._task: asyncio.Task | None = None
         self._synced = asyncio.Event()
         self.last_rv = 0
+        #: commit time (time.monotonic()) of the watch event whose
+        #: handlers are running; None outside one, or when the event
+        #: carries none (a relist, an unstamped peer)
+        self.event_committed: float | None = None
+        #: informer_watch_delay_seconds, once a registry is handed to
+        #: the factory (InformerFactory.observe_into); None observes
+        #: nothing
+        self.watch_delay = None
 
     def add_event_handler(self, handler: ResourceEventHandler) -> None:
         self.handlers.append(handler)
@@ -206,7 +215,7 @@ class SharedInformer:
                     if ev.type == "BOOKMARK":
                         self.last_rv = max(self.last_rv, ev.rv)
                         continue
-                    self._apply(ev.type, ev.object)
+                    self._apply_event(ev)
                     self.last_rv = ev.rv
             except Expired:
                 logger.info("informer %s: watch expired, relisting", self.resource)
@@ -234,6 +243,23 @@ class SharedInformer:
             gone = self.indexer.get(key)
             if gone is not None:
                 self._apply("DELETED", gone)
+
+    def _apply_event(self, ev) -> None:
+        """_apply for a watch event: its handlers can read its commit
+        time, and once they return its age is observed. (A client whose
+        events carry no stamp field, the gRPC one, observes nothing.)"""
+        committed = getattr(ev, "committed", None)
+        if committed is None:
+            self._apply(ev.type, ev.object)
+            return
+        self.event_committed = committed
+        try:
+            self._apply(ev.type, ev.object)
+        finally:
+            self.event_committed = None
+        if self.watch_delay is not None:
+            self.watch_delay.observe_key(
+                (self.resource, ev.type), time.monotonic() - committed)
 
     def _apply(self, ev_type: str, obj: dict) -> None:
         if ev_type == "DELETED":
@@ -327,7 +353,7 @@ class ShardedInformer(SharedInformer):
                     if ev.type == "BOOKMARK":
                         rv = max(rv, ev.rv)
                         continue
-                    self._apply(ev.type, ev.object)
+                    self._apply_event(ev)
                     rv = max(rv, ev.rv)
                     self.last_rv = max(self.last_rv, ev.rv)
             except Expired:
@@ -368,14 +394,26 @@ class InformerFactory:
     def __init__(self, store: MVCCStore):
         self.store = store
         self._informers: dict[str, SharedInformer] = {}
+        self._watch_delay = None
 
     def informer(self, resource: str, **kwargs: Any) -> SharedInformer:
         if resource not in self._informers:
             from kubernetes_tpu.store.sharded import PARTITIONED_RESOURCES
             cls = ShardedInformer if resource in PARTITIONED_RESOURCES \
                 else SharedInformer
-            self._informers[resource] = cls(self.store, resource, **kwargs)
+            inf = cls(self.store, resource, **kwargs)
+            inf.watch_delay = self._watch_delay
+            self._informers[resource] = inf
         return self._informers[resource]
+
+    def observe_into(self, registry) -> None:
+        """Every informer of this factory, now and later, observes
+        `informer_watch_delay_seconds` on `registry`. A factory never
+        handed one observes nothing."""
+        from kubernetes_tpu.metrics.registry import watch_delay_histogram
+        self._watch_delay = watch_delay_histogram(registry)
+        for inf in self._informers.values():
+            inf.watch_delay = self._watch_delay
 
     def start(self) -> None:
         for inf in self._informers.values():

@@ -3257,7 +3257,7 @@ class TPUBackend:
                     wsnap = ctx.wsnap = Snapshot(
                         [working.get(n.name, n) for n in snapshot.nodes],
                         snapshot.generation)
-                state = CycleState()
+                state = fwk.new_cycle_state()
                 st = fwk.run_pre_filter(state, pi, wsnap)
                 if st.is_success():
                     st = fwk.run_filters(state, pi, working.get(ni.name, ni))

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import random
 import time
 from typing import Any, Callable, Iterable, Mapping
 
@@ -34,6 +35,11 @@ from kubernetes_tpu.scheduler.types import NodeInfo, PodInfo, Snapshot
 #: shared no-op context manager (stateless, safe to re-enter): the
 #: disabled-tracer fast path of ep_span costs one attribute check + this.
 _NULL_CM = contextlib.nullcontext()
+
+#: share of scheduling cycles whose plugin calls are timed into
+#: scheduler_plugin_execution_duration_seconds (upstream's
+#: pluginMetricsSamplePercent); the others read no clock
+PLUGIN_METRICS_SAMPLE_PERCENT = 10
 
 # --- Status codes (framework.Code) -----------------------------------------
 
@@ -103,12 +109,17 @@ class Status:
 
 class CycleState:
     """Per-attempt scratch space (framework/cycle_state.go): plugins stash
-    PreFilter/PreScore precomputation under their own keys."""
+    PreFilter/PreScore precomputation under their own keys.
 
-    def __init__(self):
+    Only a cycle whose state has `record_plugin_metrics` times its
+    plugin calls: `Framework.new_cycle_state` decides it once a cycle,
+    as upstream's schedulingCycle does."""
+
+    def __init__(self, record_plugin_metrics: bool = False):
         self._data: dict[str, Any] = {}
         self.skip_filter_plugins: set[str] = set()
         self.skip_score_plugins: set[str] = set()
+        self.record_plugin_metrics = record_plugin_metrics
 
     def write(self, key: str, value: Any) -> None:
         self._data[key] = value
@@ -117,7 +128,7 @@ class CycleState:
         return self._data.get(key)
 
     def clone(self) -> "CycleState":
-        cs = CycleState()
+        cs = CycleState(self.record_plugin_metrics)
         cs._data = dict(self._data)
         cs.skip_filter_plugins = set(self.skip_filter_plugins)
         cs.skip_score_plugins = set(self.skip_score_plugins)
@@ -220,7 +231,7 @@ class EnqueueExtensions:
 
 class Framework:
     """frameworkImpl: a configured set of plugins per profile, with
-    per-plugin/per-extension-point timing recorded for metrics parity."""
+    per-plugin timing on sampled cycles recorded for metrics parity."""
 
     def __init__(
         self,
@@ -238,6 +249,8 @@ class Framework:
         #: each extension-point run_* becomes a child span of the attempt
         #: when tracing is on; a None/disabled tracer costs one check.
         self.tracer = None
+        #: the draws behind PLUGIN_METRICS_SAMPLE_PERCENT
+        self.plugin_metrics_sampler = random.Random(0)
         disabled = {k: set(v) for k, v in (disabled or {}).items()}
 
         def enabled(point: str) -> list[Plugin]:
@@ -267,20 +280,35 @@ class Framework:
             return t.span(f"framework.{point}", profile=self.profile_name)
         return _NULL_CM
 
-    def _timed(self, plugin: Plugin, point: str, fn: Callable, *args):
+    def _sample(self) -> bool:
+        return self.plugin_metrics_sampler.random() * 100 \
+            < PLUGIN_METRICS_SAMPLE_PERCENT
+
+    def new_cycle_state(self) -> CycleState:
+        """A scheduling cycle's state, its plugin-metrics sampling drawn:
+        the plugin calls of an unsampled cycle read no clock."""
+        return CycleState(self._sample())
+
+    def _timed(self, record: bool, plugin: Plugin, point: str,
+               fn: Callable, *args):
+        """fn(*args), timed into the plugin histogram when `record` (the
+        cycle was sampled)."""
+        if not record or self.metrics is None:
+            return fn(*args)
         t0 = time.perf_counter()
         try:
             return fn(*args)
         finally:
-            if self.metrics is not None:
-                self.metrics.observe_plugin(plugin.NAME, point,
-                                            time.perf_counter() - t0)
+            self.metrics.observe_plugin(plugin.NAME, point,
+                                        time.perf_counter() - t0)
 
     # -- queue hooks --
 
     def run_pre_enqueue(self, pod: PodInfo) -> Status:
+        # no cycle yet: one draw per add, as upstream's queue does
+        record = bool(self.pre_enqueue_plugins) and self._sample()
         for p in self.pre_enqueue_plugins:
-            st = self._timed(p, "PreEnqueue", p.pre_enqueue, pod)
+            st = self._timed(record, p, "PreEnqueue", p.pre_enqueue, pod)
             if not st.is_success():
                 return st.with_plugin(p.NAME)
         return Status.success()
@@ -296,7 +324,8 @@ class Framework:
                        snapshot: Snapshot) -> Status:
         with self.ep_span("PreFilter"):
             for p in self.pre_filter_plugins:
-                st = self._timed(p, "PreFilter", p.pre_filter, state, pod,
+                st = self._timed(state.record_plugin_metrics, p,
+                                 "PreFilter", p.pre_filter, state, pod,
                                  snapshot)
                 if st.is_skip():
                     state.skip_filter_plugins.add(p.NAME)
@@ -310,7 +339,8 @@ class Framework:
         for p in self.filter_plugins:
             if p.NAME in state.skip_filter_plugins:
                 continue
-            st = self._timed(p, "Filter", p.filter, state, pod, node)
+            st = self._timed(state.record_plugin_metrics, p, "Filter",
+                             p.filter, state, pod, node)
             if not st.is_success():
                 return st.with_plugin(p.NAME)
         return Status.success()
@@ -321,8 +351,8 @@ class Framework:
         with self.ep_span("PostFilter"):
             for p in self.post_filter_plugins:
                 nominated, st = self._timed(
-                    p, "PostFilter", p.post_filter, state, pod, snapshot,
-                    statuses)
+                    state.record_plugin_metrics, p, "PostFilter",
+                    p.post_filter, state, pod, snapshot, statuses)
                 if st.is_success() or not st.is_unschedulable():
                     return nominated, st.with_plugin(p.NAME)
             return "", Status.unschedulable()
@@ -331,7 +361,8 @@ class Framework:
                       nodes: list[NodeInfo]) -> Status:
         with self.ep_span("PreScore"):
             for p in self.pre_score_plugins:
-                st = self._timed(p, "PreScore", p.pre_score, state, pod, nodes)
+                st = self._timed(state.record_plugin_metrics, p,
+                                 "PreScore", p.pre_score, state, pod, nodes)
                 if st.is_skip():
                     state.skip_score_plugins.add(p.NAME)
                     continue
@@ -343,6 +374,7 @@ class Framework:
                    nodes: list[NodeInfo]) -> dict[str, float]:
         """Weighted sum over score plugins (RunScorePlugins + NormalizeScore +
         plugin weight application)."""
+        record = state.record_plugin_metrics
         with self.ep_span("Score"):
             totals = {n.name: 0.0 for n in nodes}
             for p in self.score_plugins:
@@ -350,10 +382,10 @@ class Framework:
                     continue
                 raw = {}
                 for n in nodes:
-                    raw[n.name] = self._timed(p, "Score", p.score, state,
-                                              pod, n)
-                self._timed(p, "NormalizeScore", p.normalize_scores, state,
-                            pod, raw)
+                    raw[n.name] = self._timed(record, p, "Score", p.score,
+                                              state, pod, n)
+                self._timed(record, p, "NormalizeScore", p.normalize_scores,
+                            state, pod, raw)
                 w = self.score_weights.get(p.NAME, 1)
                 for name, s in raw.items():
                     totals[name] += w * s
@@ -365,8 +397,8 @@ class Framework:
         with self.ep_span("Reserve"):
             done: list[Plugin] = []
             for p in self.reserve_plugins:
-                st = self._timed(p, "Reserve", p.reserve, state, pod,
-                                 node_name)
+                st = self._timed(state.record_plugin_metrics, p,
+                                 "Reserve", p.reserve, state, pod, node_name)
                 if not st.is_success():
                     for q in done:
                         q.unreserve(state, pod, node_name)
@@ -376,7 +408,8 @@ class Framework:
 
     def run_unreserve(self, state: CycleState, pod: PodInfo, node_name: str) -> None:
         for p in reversed(self.reserve_plugins):
-            self._timed(p, "Unreserve", p.unreserve, state, pod, node_name)
+            self._timed(state.record_plugin_metrics, p, "Unreserve",
+                        p.unreserve, state, pod, node_name)
 
     def run_permit(self, state: CycleState, pod: PodInfo,
                    node_name: str) -> tuple[Status, float]:
@@ -384,8 +417,9 @@ class Framework:
             max_timeout = 0.0
             waiting = False
             for p in self.permit_plugins:
-                st, timeout = self._timed(p, "Permit", p.permit, state, pod,
-                                          node_name)
+                st, timeout = self._timed(
+                    state.record_plugin_metrics, p, "Permit", p.permit,
+                    state, pod, node_name)
                 if st.is_wait():
                     waiting = True
                     max_timeout = max(max_timeout, timeout)
@@ -396,11 +430,12 @@ class Framework:
 
     async def run_pre_bind(self, state: CycleState, pod: PodInfo,
                            node_name: str) -> Status:
+        record = state.record_plugin_metrics and self.metrics is not None
         with self.ep_span("PreBind"):
             for p in self.pre_bind_plugins:
-                t0 = time.perf_counter()
+                t0 = time.perf_counter() if record else 0.0
                 st = await p.pre_bind(state, pod, node_name)
-                if self.metrics is not None:
+                if record:
                     self.metrics.observe_plugin(p.NAME, "PreBind",
                                                 time.perf_counter() - t0)
                 if not st.is_success():
@@ -409,11 +444,12 @@ class Framework:
 
     async def run_bind(self, state: CycleState, pod: PodInfo,
                        node_name: str) -> Status:
+        record = state.record_plugin_metrics and self.metrics is not None
         with self.ep_span("Bind"):
             for p in self.bind_plugins:
-                t0 = time.perf_counter()
+                t0 = time.perf_counter() if record else 0.0
                 st = await p.bind(state, pod, node_name)
-                if self.metrics is not None:
+                if record:
                     self.metrics.observe_plugin(p.NAME, "Bind",
                                                 time.perf_counter() - t0)
                 if st.is_skip():
@@ -424,4 +460,5 @@ class Framework:
     def run_post_bind(self, state: CycleState, pod: PodInfo, node_name: str) -> None:
         with self.ep_span("PostBind"):
             for p in self.post_bind_plugins:
-                self._timed(p, "PostBind", p.post_bind, state, pod, node_name)
+                self._timed(state.record_plugin_metrics, p, "PostBind",
+                            p.post_bind, state, pod, node_name)

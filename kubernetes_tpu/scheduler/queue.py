@@ -45,6 +45,10 @@ QUEUE_SKIP = "QueueSkip"
 #: hint fn: (pod, event) -> QUEUE | QUEUE_SKIP
 HintFn = Callable[[PodInfo, ClusterEvent], str]
 
+#: scheduler_pod_stage_duration_seconds label tuples the queue observes
+_DELIVERY = ("delivery",)
+_QUEUE = ("queue",)
+
 
 class SchedulingQueue:
     def __init__(
@@ -54,8 +58,12 @@ class SchedulingQueue:
         max_backoff: float = 10.0,
         unschedulable_flush_interval: float = 60.0,
         clock: Callable[[], float] = time.monotonic,
+        metrics=None,
     ):
         self.framework = framework
+        #: SchedulerMetrics: the delivery and queue stages and
+        #: scheduler_queue_incoming_pods_total (None: observe nothing)
+        self.metrics = metrics
         self.initial_backoff = initial_backoff
         self.max_backoff = max_backoff
         self.unschedulable_flush_interval = unschedulable_flush_interval
@@ -102,12 +110,17 @@ class SchedulingQueue:
                 return key_fn(pi)
         return (-pi.priority, pi.queued_at)
 
-    def _push_active(self, pi: PodInfo) -> None:
+    def _incoming(self, event: str, queue: str) -> None:
+        if self.metrics is not None:
+            self.metrics.queue_incoming.inc_key((event, queue))
+
+    def _push_active(self, pi: PodInfo, now: float | None = None) -> None:
         if pi.key in self._active_keys:
             return
         # Every activeQ entry (first add, backoff flush, move_all) stamps
-        # the queue-wait start for this attempt's retroactive span.
-        pi.enqueued_at = self.clock()
+        # the queue-wait start for this attempt's retroactive span and
+        # its queue stage.
+        pi.enqueued_at = self.clock() if now is None else now
         tracer = self.framework.tracer
         if tracer is not None and tracer.enabled:
             tracer.cut()    # a retroactive scheduler.queue.wait starts here
@@ -121,25 +134,33 @@ class SchedulingQueue:
 
     # -- public API --------------------------------------------------------
 
-    async def add(self, pi: PodInfo) -> None:
-        """New pending pod enters activeQ (unless gated by PreEnqueue)."""
+    async def add(self, pi: PodInfo, event: str = "PodAdd") -> None:
+        """New pending pod enters activeQ (unless gated by PreEnqueue).
+        Its first add is its first activeQ entry, to the clock read:
+        the delivery stage ends and the queue stage starts there."""
         async with self._cond:
+            now = self.clock()
             if pi.queued_at == 0.0:
-                pi.queued_at = self.clock()
+                pi.queued_at = now
+                if pi.committed_at and self.metrics is not None:
+                    self.metrics.pod_stage_duration.observe_key(
+                        _DELIVERY, now - pi.committed_at)
             st = self.framework.run_pre_enqueue(pi)
             if not st.is_success():
                 pi.unschedulable_plugins = {st.plugin} if st.plugin else set()
                 self._gated[pi.key] = pi
+                self._incoming(event, "gated")
                 return
             self._remove_everywhere(pi.key)
-            self._push_active(pi)
+            self._push_active(pi, now)
+            self._incoming(event, "active")
             self._cond.notify_all()
 
     async def update(self, pi: PodInfo) -> None:
         """Pod object changed while queued: refresh it wherever it sits; a
         gated pod gets re-evaluated (SchedulingGates removal path). add()
         handles removal from every tier via _remove_everywhere."""
-        await self.add(pi)
+        await self.add(pi, "PodUpdate")
 
     def _remove_everywhere(self, key: str) -> None:
         if key in self._active_keys:
@@ -186,6 +207,8 @@ class SchedulingQueue:
     def _drain_locked(self, max_pods: int) -> list[PodInfo]:
         out: list[PodInfo] = []
         now = self.clock()
+        stages = None if self.metrics is None \
+            else self.metrics.pod_stage_duration
         while self._active and len(out) < max_pods:
             _, _, pi = heapq.heappop(self._active)
             self._active_keys.discard(pi.key)
@@ -193,6 +216,8 @@ class SchedulingQueue:
             # Queue-wait endpoint for the attempt's retroactive
             # scheduler.queue.wait span (queued_at → dequeued_at).
             pi.dequeued_at = now
+            if stages is not None:
+                stages.observe_key(_QUEUE, now - pi.enqueued_at)
             self._in_flight.add(pi.key)
             out.append(pi)
         return out
@@ -215,6 +240,7 @@ class SchedulingQueue:
             _, _, pi = heapq.heappop(self._backoff)
             self._backoff_keys.discard(pi.key)
             self._push_active(pi)
+            self._incoming("BackoffComplete", "active")
 
     async def add_unschedulable(self, pi: PodInfo) -> None:
         """Failed cycle: park the pod (AddUnschedulableIfNotPresent). If a
@@ -229,11 +255,13 @@ class SchedulingQueue:
                     ready = self.clock() + self._backoff_duration(pi)
                     heapq.heappush(self._backoff, (ready, next(self._seq), pi))
                     self._backoff_keys.add(pi.key)
+                    self._incoming("ScheduleAttemptFailure", "backoff")
                     self._cond.notify_all()
                 return
             if pi.key in self._active_keys or pi.key in self._backoff_keys:
                 return
             self._unschedulable[pi.key] = (pi, self.clock())
+            self._incoming("ScheduleAttemptFailure", "unschedulable")
 
     async def done(self, pod_key: str) -> None:
         """Cycle finished without requeue (scheduled or error-dropped)."""
@@ -250,6 +278,7 @@ class SchedulingQueue:
             ready = self.clock() + self._backoff_duration(pi)
             heapq.heappush(self._backoff, (ready, next(self._seq), pi))
             self._backoff_keys.add(pi.key)
+            self._incoming("ScheduleAttemptFailure", "backoff")
             self._cond.notify_all()
 
     async def move_all(self, event: ClusterEvent) -> int:
@@ -277,19 +306,23 @@ class SchedulingQueue:
                 if self.framework.run_pre_enqueue(pi).is_success():
                     del self._gated[key]
                     self._push_active(pi)
+                    self._incoming(events[0].label, "active")
                     moved += 1
             for key in list(self._unschedulable):
                 pi, _ = self._unschedulable[key]
-                if not any(self._hint_says_queue(pi, event)
-                           for event in events):
+                event = next((e for e in events
+                              if self._hint_says_queue(pi, e)), None)
+                if event is None:
                     continue
                 del self._unschedulable[key]
                 if pi.attempts > 0 and self._backoff_duration(pi) > 0:
                     ready = self.clock() + self._backoff_duration(pi)
                     heapq.heappush(self._backoff, (ready, next(self._seq), pi))
                     self._backoff_keys.add(pi.key)
+                    self._incoming(event.label, "backoff")
                 else:
                     self._push_active(pi)
+                    self._incoming(event.label, "active")
                 moved += 1
             if moved:
                 self._cond.notify_all()
@@ -321,6 +354,7 @@ class SchedulingQueue:
                 ready = now + self._backoff_duration(pi)
                 heapq.heappush(self._backoff, (ready, next(self._seq), pi))
                 self._backoff_keys.add(pi.key)
+                self._incoming("UnschedulableTimeout", "backoff")
                 moved += 1
             if moved:
                 self._cond.notify_all()

@@ -50,6 +50,10 @@ from kubernetes_tpu.utils.tracing import ambient, traceparent_of
 
 logger = logging.getLogger(__name__)
 
+#: scheduler_pod_stage_duration_seconds label tuples the cycle observes
+_ATTEMPT = ("attempt",)
+_BINDING = ("binding",)
+
 
 class FitError(Exception):
     def __init__(self, pod: PodInfo, num_nodes: int, statuses: Mapping[str, Status]):
@@ -126,7 +130,7 @@ class Scheduler:
         default_fwk = next(iter(self.profiles.values()))
         self.queue = SchedulingQueue(
             default_fwk, initial_backoff=pod_initial_backoff,
-            max_backoff=pod_max_backoff)
+            max_backoff=pod_max_backoff, metrics=self.metrics)
         self.percentage_of_nodes_to_score = percentage_of_nodes_to_score
         #: utiltrace threshold: scheduling attempts slower than this log a
         #: step-by-step latency trace (SURVEY §5.1). None defaults from
@@ -195,6 +199,9 @@ class Scheduler:
 
     async def setup_informers(self, factory: InformerFactory) -> None:
         self._informer_factory = factory
+        # the factory's informers observe informer_watch_delay_seconds
+        # where this scheduler's series are read
+        factory.observe_into(self.metrics.registry)
         if self.backend is not None \
                 and getattr(self.backend, "control_shards", 0) is None:
             # Remote store: ask the server for the control-plane shape
@@ -225,6 +232,8 @@ class Scheduler:
                 self.cache.add_pod(pi)
                 self._move_all_soon(ClusterEvent("Pod", "Add"))
             elif self._responsible(pi):
+                # the delivery stage starts at the create's commit
+                pi.committed_at = pods.event_committed or 0.0
                 asyncio.ensure_future(self.queue.add(pi))
                 # A new PENDING pod can lift gates of other pods (e.g.
                 # Coscheduling's minMember gate counts siblings). Only poke
@@ -581,7 +590,7 @@ class Scheduler:
         ni = snapshot.get(pi.nominated_node)
         if ni is None:
             return False
-        state = CycleState()
+        state = fwk.new_cycle_state()
         t0 = time.perf_counter()
         if not fwk.run_pre_filter(state, pi, snapshot).is_success():
             return False
@@ -687,7 +696,8 @@ class Scheduler:
             node = assignments.get(pi.key)
             if node:
                 self.metrics.observe_attempt("scheduled", fwk.profile_name, elapsed / len(pods))
-                await self._assume_and_bind(fwk, CycleState(), pi, node)
+                await self._assume_and_bind(
+                    fwk, fwk.new_cycle_state(), pi, node)
             else:
                 failed.append(pi)
         live = self.cache.update_snapshot() if failed else None
@@ -703,7 +713,7 @@ class Scheduler:
             # pod's affinity/spread/volume prefilter state (an empty
             # CycleState would make those filters vacuously pass and
             # evict victims on nodes the pod can never land on).
-            state = CycleState()
+            state = fwk.new_cycle_state()
             fwk.run_pre_filter(state, pi, live)
             await self._handle_failure(
                 fwk, pi, FitError(pi, len(snapshot), statuses),
@@ -765,7 +775,7 @@ class Scheduler:
                     self.metrics.observe_attempt(
                         "scheduled", fwk.profile_name, elapsed / n)
                     await self._assume_and_bind(
-                        fwk, CycleState(), pi, node)
+                        fwk, fwk.new_cycle_state(), pi, node)
                 else:
                     failed.append(pi)
             live = self.cache.update_snapshot() if failed else None
@@ -775,7 +785,7 @@ class Scheduler:
                 self.metrics.observe_attempt(
                     "unschedulable", fwk.profile_name, elapsed / n)
                 statuses = ctx.diagnostics.get(pi.key, {})
-                state = CycleState()
+                state = fwk.new_cycle_state()
                 fwk.run_pre_filter(state, pi, live)
                 try:
                     await self._handle_failure(
@@ -828,7 +838,7 @@ class Scheduler:
 
     async def _schedule_host_path_traced(self, pi: PodInfo, snapshot,
                                          fwk) -> None:
-        state = CycleState()
+        state = fwk.new_cycle_state()
         t0 = time.perf_counter()
         try:
             result = await self.schedule_pod(fwk, state, pi, snapshot)
@@ -861,6 +871,10 @@ class Scheduler:
             logger.error("assume failed for %s: %s", pi.key, e)
             await self.queue.move_to_backoff(pi)
             return
+        pi.assumed_at = self.queue.clock()
+        if pi.dequeued_at:
+            self.metrics.pod_stage_duration.observe_key(
+                _ATTEMPT, pi.assumed_at - pi.dequeued_at)
         st = fwk.run_reserve(state, pi, node_name)
         if not st.is_success():
             self.cache.forget_pod(pi.key)
@@ -921,6 +935,11 @@ class Scheduler:
                 self.cache.forget_pod(pi.key)
                 await self._requeue_unschedulable(pi, st)
                 return
+            if pi.queued_at:
+                acked = self.queue.clock()
+                self.metrics.pod_stage_duration.observe_key(
+                    _BINDING, acked - pi.assumed_at)
+                self.metrics.observe_bound(pi.attempts, acked - pi.queued_at)
             # The pod is durably bound in the API from here on: failures
             # below must NOT forget/requeue it (it is genuinely scheduled).
             bound = True

@@ -87,7 +87,8 @@ class PodInfo:
         "required_affinity_terms", "required_anti_affinity_terms",
         "preferred_affinity_terms", "preferred_anti_affinity_terms",
         "attempts", "last_failure", "unschedulable_plugins", "queued_at",
-        "enqueued_at", "dequeued_at", "nominated_node",
+        "enqueued_at", "dequeued_at", "committed_at", "assumed_at",
+        "nominated_node",
     )
 
     def __init__(self, pod: Mapping):
@@ -137,6 +138,11 @@ class PodInfo:
         #: cycles/backoff), dequeued_at when pop_batch hands it out.
         self.enqueued_at = 0.0
         self.dequeued_at = 0.0
+        #: the same clock: when the create this PodInfo was built from
+        #: was committed (0.0: not known — a relist, an update, an
+        #: unstamped watch), and when the pod was last assumed
+        self.committed_at = 0.0
+        self.assumed_at = 0.0
         self.nominated_node = ""
 
     @property

@@ -44,7 +44,6 @@ import time
 from collections import deque
 
 from kubernetes_tpu.ops.backend import AdaptiveTuner
-from kubernetes_tpu.scheduler.framework import CycleState
 from kubernetes_tpu.serving.admission import AdmissionWindow
 from kubernetes_tpu.serving.fastpath import SinglePodFastPath
 from kubernetes_tpu.serving.resident import ResidentPlanes
@@ -259,7 +258,7 @@ class ServingTier:
             self._fast_walls.append(wall)
             self._last_fast_t = time.monotonic()
         sched.metrics.observe_attempt("scheduled", fwk.profile_name, wall)
-        await sched._assume_and_bind(fwk, CycleState(), pi, node)
+        await sched._assume_and_bind(fwk, fwk.new_cycle_state(), pi, node)
         return True
 
     # -- batch side ---------------------------------------------------------

@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import time
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, AsyncIterator, Awaitable, Callable, Mapping
@@ -91,6 +92,11 @@ class Event:
     #: spec.nodeName) so field watchers see enter/leave transitions the
     #: same way label watchers do.
     prev_fields: dict | None = None
+    #: when the write was committed, on this process's time.monotonic()
+    #: (not on the wire as part of the object; the KTPU wire carries it
+    #: as a trailing stamp). None: not stamped (replayed, synthesized
+    #: bookmarks, a peer that sends no stamp).
+    committed: float | None = None
 
     def to_wire(self) -> dict:
         return {"type": self.type, "object": self.object}
@@ -102,7 +108,8 @@ def _synth(ev: Event, ev_type: str) -> Event:
     per-codec encoding of the shared object (encode-once fan-out): a
     MODIFIED event synthesized into ADDED for a whole selector group
     costs zero extra serializations."""
-    twin = Event(ev_type, ev.object, ev.rv, ev.prev_labels, ev.prev_fields)
+    twin = Event(ev_type, ev.object, ev.rv, ev.prev_labels, ev.prev_fields,
+                 ev.committed)
     twin._wire_src = ev
     return twin
 
@@ -382,6 +389,7 @@ class MVCCStore:
         self.watch_metrics.window_evictions.inc_key(("log", entry[0]))
 
     def _record(self, resource: str, ev: Event) -> None:
+        ev.committed = time.monotonic()
         t = self.tracer
         if t.enabled:
             with t.section(f"store.commit.{resource}"):
