@@ -704,6 +704,21 @@ class SchedulerMetrics:
             "topology_plane_rebuilds_total",
             "Rebuilds of the tensorized interconnect coordinate planes "
             "(mesh flags or node set moved; reuse does not count)")
+        #: Which path each ClusterTensors build took (ops/tensorize):
+        #: kind="delta" shared the static pieces with the last build by
+        #: the snapshot's epoch handles and re-quantized the changed rows
+        #: alone, kind="full" walked every node. The histogram is the
+        #: build's wall, observed with tracing on or off.
+        self.cluster_tensor_builds = r.counter(
+            "scheduler_tpu_cluster_tensor_builds_total",
+            "ClusterTensors builds by kind (delta: O(changed) / full: "
+            "every node)", labels=("kind",))
+        for kind in ("delta", "full"):
+            self.cluster_tensor_builds.inc(0, kind=kind)
+        self.tensors_duration = r.histogram(
+            "scheduler_tpu_tensors_seconds",
+            "Host wall of one ClusterTensors build from a new snapshot "
+            "generation", buckets=STAGE_BUCKETS)
         #: How the backend's affinity compiler (label-signature counts
         #: behind InterPodAffinity rows and the spread table) reached a
         #: new snapshot: kind="delta" recounted the changed nodes' rows,

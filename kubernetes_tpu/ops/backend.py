@@ -1117,9 +1117,11 @@ class TPUBackend:
 
     def _tensors(self, snapshot: Snapshot) -> ClusterTensors:
         if self._ct is None or self._ct.generation != snapshot.generation:
+            t0 = time.perf_counter()
             self._ct = ClusterTensors(
                 snapshot, resources=self._pinned_resources, prev=self._ct,
                 shards=self.control_shards)
+            built_s = time.perf_counter() - t0
             # The affinity compiler is NOT dropped with the tensors: it
             # is brought to the snapshot where a pod first needs it
             # (_affinity_compiler), by the same delta handles. What goes
@@ -1130,6 +1132,9 @@ class TPUBackend:
             # whose rows this build rewrote count a rebuild — the
             # incremental delta path's observable witness.
             if self.metrics is not None:
+                self.metrics.tensors_duration.observe(built_s)
+                self.metrics.cluster_tensor_builds.inc(
+                    kind=self._ct.build_kind)
                 for s in self._ct.shard_rebuilds:
                     self.metrics.shard_tensor_rebuilds.inc(shard=str(s))
                 topo = getattr(self._ct, "topology", None)

@@ -47,7 +47,11 @@ from kubernetes_tpu.api.types import (
     toleration_tolerates_taint,
 )
 from kubernetes_tpu.scheduler.types import NodeInfo, PodInfo, Snapshot
-from kubernetes_tpu.topology.planes import TopologyPlanes, build_topology_planes
+from kubernetes_tpu.topology.planes import (
+    TopologyPlanes,
+    build_topology_planes,
+    keep_topology_planes,
+)
 from kubernetes_tpu.utils import flags
 
 #: Node axis is padded to a multiple of this so node add/remove churn doesn't
@@ -151,7 +155,11 @@ class ClusterTensors:
         self.prep_shards = 1
         self.shard_ids: np.ndarray | None = None
         self.shard_rebuilds: list[int] = []
+        #: which path built these tensors: "delta" (O(changed), shared
+        #: with prev by the epoch handles) or "full" (a walk of every node)
+        self.build_kind = "full"
         if self._init_delta(snapshot, resources, prev):
+            self.build_kind = "delta"
             return
         self.node_names = [ni.name for ni in nodes]
         self.name_to_idx = {n: i for i, n in enumerate(self.node_names)}
@@ -240,11 +248,12 @@ class ClusterTensors:
                 self.taints.node_rows(nodes, N)
         self._static_fp = fp
         # Topology coordinate planes (topology/planes): static per
-        # node-set like the taint interning, absent entirely when the
-        # kill switch is off (flat-capacity call graph, no new arrays).
+        # node-set like the taint interning, keyed on the same per-node
+        # tuple, absent entirely when the kill switch is off
+        # (flat-capacity call graph, no new arrays).
         self.topology: TopologyPlanes | None = (
             build_topology_planes(
-                nodes, N, getattr(prev, "topology", None))
+                nodes, N, getattr(prev, "topology", None), fingerprint=fp)
             if flags.get("KTPU_TOPOLOGY") else None)
         self._shard_accounting(
             prev=prev if incremental else None,
@@ -261,12 +270,15 @@ class ClusterTensors:
         `prev` (set_epoch / spec_seq match) and the cache's changed-log
         still covers prev.generation, every O(N) walk of the full build
         is skipped: the static pieces (names, resource columns, scales,
-        allocatable, taints) are SHARED with prev — spec_seq pins them
-        identical, and the caller discards prev — while the used-state
+        allocatable, taints, topology planes) are SHARED with prev —
+        spec_seq pins them identical, and the caller discards prev —
+        while the used-state
         arrays are copied and only the rows of nodes whose generation
         advanced are re-quantized, grouped by control-plane shard for
         the rebuild accounting. O(changed) per generation instead of
         O(N): the host-prep half of ROADMAP #5's sharded scale-out.
+        Nothing here iterates the node list: `len(nodes)`, the changed
+        rows by index, and C-level copies of the used-state arrays.
         Node order is untouched, so assignments (and the index tie
         rule) stay bit-identical to the full build."""
         if prev is None or self.set_epoch < 0 \
@@ -309,12 +321,13 @@ class ClusterTensors:
                 self.used_nz_q[i, j] = _quant_ceil(
                     ni.nonzero_requested.get(r), sc[j])
             self.used_pods[i] = ni.requested.pods
-        # spec_seq pins node specs identical, so the planes fingerprint
-        # matches and this is a pure reuse (rebuilt=False) — unless the
-        # mesh flags moved live, which forces the honest rebuild.
+        # set_epoch / spec_seq pin every node's name and spec_epoch, so
+        # the planes are prev's (rebuilt=False) without a look at the
+        # node list — unless the mesh flag moved live, or the switch
+        # came on live, which force the honest rebuild.
         self.topology = (
-            build_topology_planes(
-                nodes, self.n_pad, getattr(prev, "topology", None))
+            keep_topology_planes(
+                nodes, self.n_pad, prev.topology, self._static_fp)
             if flags.get("KTPU_TOPOLOGY") else None)
         self._shard_accounting(prev=prev, changed=changed)
         return True

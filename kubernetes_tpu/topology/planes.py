@@ -8,6 +8,9 @@ walks. Like the taint interning, the planes are STATIC per node-set:
 they are rebuilt only when the mesh flags or the (name, spec_epoch)
 node fingerprint move, and reused (shared arrays, `rebuilt=False`)
 otherwise; `topology_plane_rebuilds_total` counts the real rebuilds.
+A delta build of ClusterTensors keeps them by the snapshot's epoch
+handles and the mesh flag alone (`keep_topology_planes`); only a full
+build compares the per-node fingerprint.
 
 Cell collisions (two nodes claiming one coordinate — a mislabeled
 agent) resolve deterministically: the LOWEST node index keeps the
@@ -62,17 +65,38 @@ class TopologyPlanes:
 
 
 def build_topology_planes(nodes: "Sequence[NodeInfo]", n_pad: int,
-                          prev: TopologyPlanes | None) -> TopologyPlanes:
+                          prev: TopologyPlanes | None,
+                          fingerprint: tuple) -> TopologyPlanes:
     """Build (or reuse) the planes for the current mesh flags + node
-    set. Reuse keys on (raw flag values, (name, spec_epoch) per node):
-    label moves bump spec_epoch, so a re-stamped coordinate rebuilds."""
+    set. Reuse keys on (raw flag values, `fingerprint`): the caller's
+    (name, spec_epoch) per node, ClusterTensors' taint key, so it is
+    built once; label moves bump spec_epoch, so a re-stamped coordinate
+    rebuilds."""
     from kubernetes_tpu.utils import flags
 
     raw_shape = flags.get("KTPU_MESH_SHAPE")
-    fingerprint = (raw_shape, n_pad,
-                   tuple((ni.name, ni.spec_epoch) for ni in nodes))
-    if prev is not None and prev.fingerprint == fingerprint:
+    key = (raw_shape, n_pad, fingerprint)
+    if prev is not None and prev.fingerprint == key:
         prev.rebuilt = False
         return prev
-    spec = parse_mesh_shape(raw_shape, len(nodes))
-    return TopologyPlanes(spec, nodes, n_pad, fingerprint)
+    return TopologyPlanes(parse_mesh_shape(raw_shape, len(nodes)), nodes,
+                          n_pad, key)
+
+
+def keep_topology_planes(nodes: "Sequence[NodeInfo]", n_pad: int,
+                         prev: TopologyPlanes | None,
+                         fingerprint: tuple) -> TopologyPlanes:
+    """The planes for a node set the caller vouches is the one `prev`
+    was built for, name for name and spec_epoch for spec_epoch (the
+    snapshot's set_epoch / spec_seq handles; `fingerprint` is that
+    set's per-node tuple). O(1): `prev` is kept unless the mesh flag
+    moved or the node axis was padded otherwise; only then, or with
+    no `prev` (the switch turned on live), are `nodes` read."""
+    from kubernetes_tpu.utils import flags
+
+    raw_shape = flags.get("KTPU_MESH_SHAPE")
+    if prev is not None and prev.fingerprint[:2] == (raw_shape, n_pad):
+        prev.rebuilt = False
+        return prev
+    return TopologyPlanes(parse_mesh_shape(raw_shape, len(nodes)), nodes,
+                          n_pad, (raw_shape, n_pad, fingerprint))

@@ -66,8 +66,9 @@ class TestActiveByDefault:
         for i in range(4):
             cache.add_node(make_node(f"node-{i}"))
         nodes = cache.update_snapshot().nodes
-        first = build_topology_planes(nodes, 8, None)
-        again = build_topology_planes(nodes, 8, first)
+        fp = tuple((ni.name, ni.spec_epoch) for ni in nodes)
+        first = build_topology_planes(nodes, 8, None, fp)
+        again = build_topology_planes(nodes, 8, first, fp)
         assert again is first and not again.rebuilt
 
 
