@@ -797,6 +797,24 @@ class SchedulerMetrics:
             "scheduler_tpu_affinity_rows_seconds",
             "Host time per chunk in the affinity compiler: reaching the "
             "snapshot and the chunk's InterPodAffinity filter rows")
+        #: Once per assign() whose pods carry a preferred or required
+        #: affinity term (span solver.affinity_score): which of its pod
+        #: groups with an InterPodAffinity score the assign's own
+        #: placements move — kind="carried", scored inside the scan from
+        #: counts chained on the device — and which keep a chunk-start
+        #: row, kind="static"; and the host wall of that decision and of
+        #: the carry's inputs, with tracing on or off.
+        self.affinity_score_duration = r.histogram(
+            "scheduler_tpu_affinity_score_seconds",
+            "Host time per assign() deciding which InterPodAffinity "
+            "scores move inside it and building the scan's carry")
+        self.affinity_score_classes = r.counter(
+            "scheduler_tpu_affinity_score_classes_total",
+            "Pod groups with an InterPodAffinity score, by whether the "
+            "assign's own placements move it (carried) or not (static)",
+            labels=("kind",))
+        for kind in ("carried", "static"):
+            self.affinity_score_classes.inc(0, kind=kind)
         self.plane_classes = r.gauge(
             "scheduler_tpu_plane_classes_per_chunk",
             "Pod equivalence classes behind the latest chunk's planes")

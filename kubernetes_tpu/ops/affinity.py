@@ -79,6 +79,10 @@ class _Carriers(dict):
         if not self._carrying[key]:
             del self[key], self._carrying[key]
 
+    def terms(self) -> int:
+        """How many resident terms the carriers hold in all."""
+        return sum(self._carrying.values())
+
 
 class AffinityCompiler:
     """Compiled state for batched affinity filtering, kept ACROSS snapshots.
@@ -232,6 +236,7 @@ class AffinityCompiler:
         #: plane class (ops/backend._prep_chunk).
         self._filter_row_cache: dict[tuple, np.ndarray] = {}
         self._score_row_cache: dict[tuple, np.ndarray] = {}
+        self._score_parts_cache: dict[tuple, dict[str, np.ndarray]] = {}
 
     def advance(self, snapshot: Snapshot, n_pad: int) -> int | None:
         """Move to a later `snapshot` of the same node set by reading
@@ -399,16 +404,97 @@ class AffinityCompiler:
         d[0] = 0.0
         return d[dom_ids], has_key
 
+    def _selects(self, term: dict, owner_ns: str, pod: PodInfo) -> bool:
+        """Does `term`, owned by a pod in `owner_ns`, select `pod`?
+        Cached by term and the pod's namespace and labels."""
+        mk = ("sel", repr(term), owner_ns, pod.namespace,
+              tuple(sorted(pod.labels.items())))
+        hit = self._sym_match_cache.get(mk)
+        if hit is None:
+            hit = self._sym_match_cache[mk] = ns_contains(
+                _term_ns(term, owner_ns, self.ns_resolver),
+                pod.namespace) and from_label_selector(
+                term.get("labelSelector")).matches(pod.labels)
+        return hit
+
+    def score_parts(self, pod: PodInfo,
+                    hard_weight: float) -> dict[str, np.ndarray]:
+        """The pod's raw InterPodAffinity weight per node, before any
+        node is left out, by topology key: {key: (n_pad,) float32}. A
+        node's entry sums what the residents ON it add — the pod's
+        preferred (anti-)terms weigh the residents they select, and the
+        residents' preferred terms and required affinity terms
+        (× `hard_weight`) that select the pod weigh back (scoring.go's
+        two loops). `score_row` folds these into domains over the
+        feasible nodes; a chunk whose own placements move the pod's
+        weight carries them on the device as they are (ops/solver.py,
+        `ipa=`). Cached by pod content signature. Shared arrays — do not
+        mutate."""
+        ck = (pod.namespace, tuple(sorted(pod.labels.items())),
+              repr(pod.preferred_affinity_terms),
+              repr(pod.preferred_anti_affinity_terms), hard_weight)
+        parts = self._score_parts_cache.get(ck)
+        if parts is not None:
+            return parts
+        parts = {}
+
+        def add(key: str, vec: np.ndarray, w: float) -> None:
+            got = parts.get(key)
+            if got is None:
+                got = parts[key] = np.zeros((self.n_pad,), dtype=np.float32)
+            got += w * vec
+
+        for sign, preferred in ((1.0, pod.preferred_affinity_terms),
+                                (-1.0, pod.preferred_anti_affinity_terms)):
+            for term in preferred:
+                t = term.get("podAffinityTerm") or {}
+                add(t.get("topologyKey", ""), self.counts_for(
+                    t.get("labelSelector"),
+                    _term_ns(t, pod.namespace, self.ns_resolver)),
+                    sign * float(term.get("weight", 1)))
+        for carriers, term, owner_ns, is_hard in \
+                self.resident_score.values():
+            if self._selects(term, owner_ns, pod):
+                add(term.get("topologyKey", ""), carriers,
+                    hard_weight if is_hard else 1.0)
+        for vec in parts.values():
+            vec[self.n_real:] = 0.0
+        self._score_parts_cache[ck] = parts
+        return parts
+
+    def score_delta(self, pod: PodInfo, other: PodInfo,
+                    hard_weight: float) -> dict[str, float]:
+        """What one more pod like `other` adds to `pod`'s raw weight on
+        the node it lands on, by topology key (`score_parts`' terms: the
+        pod's terms that select it, and its terms that select the pod).
+        Keys whose weights cancel are left out."""
+        out: dict[str, float] = {}
+        for owner, target, hard in ((pod, other, False),
+                                    (other, pod, True)):
+            for sign, preferred in (
+                    (1.0, owner.preferred_affinity_terms),
+                    (-1.0, owner.preferred_anti_affinity_terms)):
+                for term in preferred:
+                    t = term.get("podAffinityTerm") or {}
+                    if self._selects(t, owner.namespace, target):
+                        key = t.get("topologyKey", "")
+                        out[key] = out.get(key, 0.0) \
+                            + sign * float(term.get("weight", 1))
+            if hard:
+                for t in owner.required_affinity_terms:
+                    if self._selects(t, owner.namespace, target):
+                        key = t.get("topologyKey", "")
+                        out[key] = out.get(key, 0.0) + hard_weight
+        return {k: w for k, w in out.items() if w}
+
     def score_row(self, pod: PodInfo, hard_weight: float,
                   feasible: np.ndarray) -> np.ndarray:
         """(n_pad,) raw InterPodAffinity score — exactly pre_score's
         domain-weight accumulation (scoring.go) over the pod's FEASIBLE
-        nodes, vectorized: the pod's preferred (anti-)terms weigh matching
-        residents by domain; residents' preferred terms + required terms
-        (× hardPodAffinityWeight) weigh back symmetrically. Cached by
-        (pod content signature, feasible-mask bytes): template batches
-        share one row per distinct feasibility class. Shared array — do
-        not mutate."""
+        nodes: `score_parts` summed by domain over the feasible nodes of
+        each key. Cached by (pod content signature, feasible-mask
+        bytes): template batches share one row per distinct feasibility
+        class. Shared array — do not mutate."""
         ck = (pod.namespace, tuple(sorted(pod.labels.items())),
               repr(pod.preferred_affinity_terms),
               repr(pod.preferred_anti_affinity_terms),
@@ -417,40 +503,9 @@ class AffinityCompiler:
         if cached is not None:
             return cached
         row = np.zeros((self.n_pad,), dtype=np.float32)
-        for term in pod.preferred_affinity_terms:
-            t = term.get("podAffinityTerm") or {}
-            counts = self.counts_for(t.get("labelSelector"),
-                                     _term_ns(t, pod.namespace, self.ns_resolver))
-            per_node, has_key = self._masked_presence(
-                counts, t.get("topologyKey", ""), feasible)
-            row += float(term.get("weight", 1)) * np.where(
-                has_key, per_node, 0.0)
-        for term in pod.preferred_anti_affinity_terms:
-            t = term.get("podAffinityTerm") or {}
-            counts = self.counts_for(t.get("labelSelector"),
-                                     _term_ns(t, pod.namespace, self.ns_resolver))
-            per_node, has_key = self._masked_presence(
-                counts, t.get("topologyKey", ""), feasible)
-            row -= float(term.get("weight", 1)) * np.where(
-                has_key, per_node, 0.0)
-        from kubernetes_tpu.api.labels import from_label_selector
-        pod_sig = (pod.namespace, tuple(sorted(pod.labels.items())))
-        for key, (carriers, term, owner_ns, is_hard) in \
-                self.resident_score.items():
-            mk = ("score", key, pod_sig)
-            hit = self._sym_match_cache.get(mk)
-            if hit is None:
-                nses = _term_ns(term, owner_ns, self.ns_resolver)
-                hit = ns_contains(nses, pod.namespace) and \
-                    from_label_selector(
-                        term.get("labelSelector")).matches(pod.labels)
-                self._sym_match_cache[mk] = hit
-            if not hit:
-                continue
-            per_node, has_key = self._masked_presence(
-                carriers, term.get("topologyKey", ""), feasible)
-            w = hard_weight if is_hard else 1.0
-            row += w * np.where(has_key, per_node, 0.0)
+        for key, vec in self.score_parts(pod, hard_weight).items():
+            per_node, has_key = self._masked_presence(vec, key, feasible)
+            row += np.where(has_key, per_node, 0.0)
         row[self.n_real:] = 0.0
         self._score_row_cache[ck] = row
         return row

@@ -71,6 +71,7 @@ from kubernetes_tpu.utils.jax_platform import cpu_requested
 from kubernetes_tpu.ops import kernels, solver
 from kubernetes_tpu.ops.tensorize import ClusterTensors, PodBatch
 from kubernetes_tpu.scheduler.framework import (
+    MAX_NODE_SCORE,
     CycleState,
     Framework,
     Status,
@@ -139,6 +140,15 @@ def class_pad() -> int:
     """Effective class cap: 0 = class planes off (per-pod fallback).
     Read per assign() so tests/bench can flip the env knobs live."""
     return max(0, flags.get("KTPU_CLASS_PAD"))
+
+
+def _pow2(n: int) -> int:
+    """The least power of two ≥ n (≥ 1): carry shapes repeat across
+    chunks, so their programs do."""
+    out = 1
+    while out < n:
+        out <<= 1
+    return out
 
 
 def _class_rows_bucket(n_classes: int) -> int:
@@ -333,20 +343,24 @@ class AdaptiveTuner:
         self.wave_replays += replays
 
     def solve_mode(self, p_real: int, has_gang: bool, spread: bool,
-                   class_mode: bool, exclusive: bool = False
-                   ) -> tuple[str, bool]:
+                   class_mode: bool, exclusive: bool = False,
+                   carried: bool = False) -> tuple[str, bool]:
         """('greedy' | 'optimal', structural_fallback) for one chunk —
         the KTPU_SOLVE_MODE policy row. 'greedy' pins the r18 scan call
         graph (the kill switch). Optimal requires class planes (the
         (C,N) cost matrix IS the class dictionary), a non-spread chunk
         (the spread scan's non-monotone domain gating has no transport
-        relaxation) and a chunk that is not `exclusive` (a pod of it
+        relaxation), a chunk that is not `exclusive` (a pod of it
         carries a required anti-affinity term, so pods of the chunk may
         exclude each other, which no column capacity states: a plan
         sends a whole hostname group to a handful of nodes, the host
         verify keeps one pod on each and requeues the rest; the greedy
-        scan debits a node as it takes it and spreads the group); an
-        ineligible chunk degrades structurally to greedy with the
+        scan debits a node as it takes it and spreads the group) and a
+        chunk that is not `carried` (its own placements move a pod's
+        InterPodAffinity score — "co-locate my replicas" — which a plan
+        over chunk-start scores cannot state: the scan recomputes and
+        renormalises that score at every step from counts it carries);
+        an ineligible chunk degrades structurally to greedy with the
         fallback bit set so solver_optimal_fallbacks_total records it.
         Under 'auto' the
         optimal mode engages for gang chunks and for chunks of at least
@@ -366,7 +380,8 @@ class AdaptiveTuner:
         raw = flags.get("KTPU_SOLVE_MODE")
         if raw == "greedy":
             return "greedy", False
-        eligible = class_mode and not spread and not exclusive
+        eligible = class_mode and not spread and not exclusive \
+            and not carried
         if raw == "optimal":
             return ("optimal", False) if eligible else ("greedy", True)
         if not (has_gang or p_real >= self.OPTIMAL_MIN_PODS):
@@ -667,7 +682,7 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                        sp_min_ok, sp_haskey,
                        sp_applies, sp_contrib, perms, gang_onehot,
                        gang_required, sink_iters, sink_temp, n_real, p_real,
-                       strategy: str, use_spread: bool, shortlist_k: int,
+                       ipa, strategy: str, use_spread: bool, shortlist_k: int,
                        wave_w: int, solve_mode: str = "greedy",
                        block_w: int = 0):
     """One fused device pass: plugin masks → scores → assignment → state.
@@ -768,11 +783,21 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     trickled chunk of two pods and a full one run the same executable,
     and every output keeps its padded shape and contents.
 
+    `ipa` is None, or the InterPodAffinity carry of a chunk whose own
+    placements move some pod's InterPodAffinity score (ops/solver.py
+    `_ipa_score`: the raw weights by node and topology key at the
+    snapshot, what each placed pod adds, the per-pod rows, and `placed`
+    (T, N), this assign()'s placements so far). Such a chunk takes the
+    identity-order W = 1 scan (the spread scan where it has spread pods)
+    and never a plan or a shortlist: a score that moves everywhere when
+    its maximum moves has no chunk-start bound. Its shapes are the
+    program key; None traces the call graph without it.
+
     Returns (assign (P+5,) — the tail is [shortlist fallbacks, wave
     commits, wave replays, blocks scanned, blocks pruned] riding the one
-    fetch — used_pack', fit0 (C,N), taint_ok (C,N), dom_counts'). The
-    diagnostic planes are CLASS-level; consumers gather through cls_idx
-    host-side.
+    fetch — used_pack', fit0 (C,N), taint_ok (C,N), dom_counts',
+    placed' or None). The diagnostic planes are CLASS-level; consumers
+    gather through cls_idx host-side.
     """
     # Wire decompression (see _prep_chunk): masks arrive bit-packed
     # uint8 (C, N/8) big-endian, scores float16 — unpack/cast on device
@@ -820,7 +845,8 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     blk_scanned = jnp.int32(0)
     blk_pruned = jnp.int32(0)
     n_pad = alloc_q.shape[0]
-    if solve_mode == "optimal" and not use_spread:
+    placed2 = None
+    if solve_mode == "optimal" and not use_spread and ipa is None:
         # Batch-optimal mode (see docstring): transport plan over the
         # class planes, then the SAME scans round it against live
         # capacity with the re-scoring weights zeroed. Runs BEFORE the
@@ -897,13 +923,16 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
                     sp_min_ok, sp_haskey, sp_applies, sp_contrib,
                     rows=cls_idx, exc=exc_col, p_real=p_real)
         else:
-            a0, dom_counts2 = solver.greedy_assign_rescoring_spread(
+            out = solver.greedy_assign_rescoring_spread(
                 req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
                 static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
                 w_fit, w_bal, strategy,
                 dom_onehot, cid_onehot, dom_counts, max_skew,
                 sp_min_ok, sp_haskey, sp_applies, sp_contrib,
-                rows=cls_idx, exc=exc_col, p_real=p_real)
+                rows=cls_idx, exc=exc_col, p_real=p_real, ipa=ipa)
+            a0, dom_counts2 = out[:2]
+            if ipa is not None:
+                placed2 = out[2]
         assign = solver.gang_filter(a0, gang_onehot, gang_required)
         # Gang-dropped pods bumped the chained counts in-scan (for the
         # constraints they CONTRIBUTE to) — fold them back out so later
@@ -914,6 +943,13 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
         dom_counts2 = dom_counts2 - jnp.sum(
             jnp.where(dropped[:, None],
                       dom_onehot[safe] * contrib_d, 0.0), axis=0)
+    elif ipa is not None:
+        a0, placed2 = solver.greedy_assign_rescoring(
+            req_q, req_nz_q, free_q, free_pods, used_nz_q, alloc_q, mask,
+            static_scores, fit_col_w, bal_col_mask, shape_u, shape_s,
+            w_fit, w_bal, strategy, rows=cls_idx, exc=exc_col,
+            p_real=p_real, ipa=ipa)
+        assign = solver.gang_filter(a0, gang_onehot, gang_required)
     else:
         if shortlist_k and wave_w > 1:
             # The wave scan takes the shortlist as CLASS tables and never
@@ -973,7 +1009,13 @@ def _mask_solve_update(alloc_q, used_pack, alloc_pods, class_pack,
     assign_out = jnp.concatenate(
         [assign, nfall[None], wave_com[None], wave_rep[None],
          blk_scanned[None], blk_pruned[None]])
-    return assign_out, used_pack2, fit0, taint_ok, dom_counts2
+    if placed2 is not None:
+        # Gang-dropped pods were counted in-scan: take them out again.
+        gone = (a0 >= 0) & (assign < 0)
+        placed2 = placed2.at[jnp.maximum(ipa[-1], 0),
+                             jnp.clip(a0, 0, n - 1)].add(
+            jnp.where(gone & (ipa[-1] >= 0), -1.0, 0.0))
+    return assign_out, used_pack2, fit0, taint_ok, dom_counts2, placed2
 
 
 class TPUBackend:
@@ -1333,6 +1375,157 @@ class TPUBackend:
         return bool(pi.preferred_affinity_terms
                     or pi.preferred_anti_affinity_terms
                     or compiler.resident_score)
+
+    def _ipa_table(self, ctx: "_AssignCtx", pods: list[PodInfo]) -> None:
+        """The InterPodAffinity carry of one assign() (`ctx.ipa`; None
+        where no pod's score moves with the assign's own placements).
+
+        Pods are grouped by what the score reads of them (namespace,
+        labels, affinity terms). A group is
+        CARRIED when placing a pod of the assign — of its own chunk or of
+        an earlier one in flight — changes its raw score
+        (`AffinityCompiler.score_delta` non-zero: a pod its preferred
+        terms select, or a pod whose preferred or required affinity terms
+        select it). A chunk-start row would be stale after the chunk's
+        first such placement, so a carried group's score is left out of
+        the static rows and the scan recomputes it at every step
+        (ops/solver.py `_ipa_score`) from the raw weights at the snapshot
+        (`score_parts`) plus `placed`, the assign's placements so far,
+        chained from chunk to chunk on the device. The other groups keep
+        their static rows. A topology key that names each keyed node
+        alone (hostname) is a per-node vector; any other key goes through
+        a node -> domain one-hot.
+
+        Timed as the span `solver.affinity_score` and the histogram
+        `scheduler_tpu_affinity_score_seconds`; the groups with a score
+        are counted by kind (carried / static)."""
+        ctx.ipa = None
+        fwk, snapshot, ct = ctx.fwk, ctx.snapshot, ctx.ct
+        plugin = next((p for p in fwk.score_plugins
+                       if p.NAME == "InterPodAffinity"), None)
+        if plugin is None or not fwk.score_weights.get(
+                "InterPodAffinity", 1):
+            return
+        # No pod of the assign carries a term: nothing it places moves a
+        # score (every score then stands as the snapshot's rows say).
+        if not any(pi.preferred_affinity_terms
+                   or pi.preferred_anti_affinity_terms
+                   or pi.required_affinity_terms for pi in pods):
+            return
+        with self._span("solver.affinity_score") as sp:
+            t0 = time.monotonic() if sp is None else 0.0
+            compiler = self._affinity_compiler(snapshot, ct)
+            hard = float(getattr(plugin, "hard_pod_affinity_weight", 1))
+            group_of: dict[tuple, int] = {}
+            reps: list[PodInfo] = []
+            pod_group = np.empty((len(pods),), dtype=np.int64)
+            for j, pi in enumerate(pods):
+                key = (pi.namespace, tuple(sorted(pi.labels.items())),
+                       repr(pi.affinity))
+                g = group_of.get(key)
+                if g is None:
+                    g = group_of[key] = len(reps)
+                    reps.append(pi)
+                pod_group[j] = g
+            movers = [g for g, pi in enumerate(reps)
+                      if pi.preferred_affinity_terms
+                      or pi.preferred_anti_affinity_terms
+                      or pi.required_affinity_terms]
+            deltas: dict[tuple[int, int], dict[str, float]] = {}
+            for a, pa in enumerate(reps):
+                own = pa.preferred_affinity_terms \
+                    or pa.preferred_anti_affinity_terms
+                for b in (range(len(reps)) if own else movers):
+                    d = compiler.score_delta(pa, reps[b], hard)
+                    if d:
+                        deltas[(a, b)] = d
+            carried = sorted({a for a, _ in deltas})
+            static = sum(1 for g, pi in enumerate(reps)
+                         if g not in carried
+                         and self._ipa_score_relevant(pi, compiler))
+            if carried:
+                ctx.ipa = self._ipa_carry(
+                    ctx, compiler, reps, pod_group, carried, deltas, hard,
+                    float(fwk.score_weights.get("InterPodAffinity", 1)))
+            if sp is not None:
+                sp.attrs.update(classes=len(carried) + static,
+                                carried=len(carried),
+                                carriers=compiler.resident_score.terms())
+        if self.metrics is not None:
+            self.metrics.affinity_score_duration.observe(
+                sp.end - sp.start if sp is not None
+                else time.monotonic() - t0)
+            self.metrics.affinity_score_classes.inc(
+                len(carried), kind="carried")
+            self.metrics.affinity_score_classes.inc(static, kind="static")
+
+    def _ipa_carry(self, ctx, compiler, reps, pod_group, carried, deltas,
+                   hard: float, weight: float) -> dict:
+        """The device inputs of `_ipa_table`'s carried groups (see
+        ops/solver.py `_ipa_score` for the layout), uploaded once per
+        assign(), and per chunk the (P,) carried row and touching row of
+        each pod (-1: none)."""
+        touching = sorted({b for (a, b) in deltas})
+        a_of = {a: i for i, a in enumerate(carried)}
+        t_of = {b: i for i, b in enumerate(touching)}
+        parts = {a: compiler.score_parts(reps[a], hard) for a in carried}
+        keys = sorted({k for p in parts.values() for k in p}
+                      | {k for d in deltas.values() for k in d})
+        k_of = {k: i for i, k in enumerate(keys)}
+        n = compiler.n_pad
+        kp = _pow2(len(keys))
+        keyed = np.zeros((kp, n), dtype=np.float32)
+        by_domain = []
+        for k, key in enumerate(keys):
+            ids, num = compiler.topo.domains(key)
+            has = ids > 0
+            if np.unique(ids[has]).size == int(has.sum()):
+                keyed[k] = has              # every keyed node its own domain
+            else:
+                by_domain.append((k, ids, num))
+        n_dom = sum(num - 1 for _, _, num in by_domain)
+        dp = _pow2(max(n_dom, 8)) if n_dom else 0
+        dom = np.zeros((n, dp), dtype=np.float32)
+        dom_key = np.zeros((dp, kp), dtype=np.float32)
+        col = 0
+        for k, ids, num in by_domain:
+            at = np.nonzero(ids > 0)[0]
+            dom[at, col + ids[at] - 1] = 1.0
+            dom_key[col:col + num - 1, k] = 1.0
+            col += num - 1
+        base = np.zeros((_pow2(len(carried)), kp, n), dtype=np.float32)
+        for a in carried:
+            for key, vec in parts[a].items():
+                base[a_of[a], k_of[key]] = vec
+        delta = np.zeros((base.shape[0], _pow2(len(touching)), kp),
+                         dtype=np.float32)
+        for (a, b), d in deltas.items():
+            for key, w in d.items():
+                delta[a_of[a], t_of[b], k_of[key]] = w
+        row_of = np.array([a_of.get(g, -1) for g in range(len(reps))],
+                          dtype=np.int32)
+        tpl_of = np.array([t_of.get(g, -1) for g in range(len(reps))],
+                          dtype=np.int32)
+        P = self.max_batch
+        chunks, lo, last = [], 0, -1
+        for k, chunk in enumerate(ctx.chunks):
+            rows = np.full((P,), -1, dtype=np.int32)
+            tpls = np.full((P,), -1, dtype=np.int32)
+            g = pod_group[lo:lo + len(chunk)]
+            rows[:len(chunk)] = row_of[g]
+            tpls[:len(chunk)] = tpl_of[g]
+            lo += len(chunk)
+            if (rows >= 0).any():
+                last = k
+            chunks.append((rows, tpls))
+        return {
+            "chunks": chunks, "last": last,
+            "dev": tuple(self._put(x) for x in (
+                base, delta, keyed, dom, dom_key)) + (
+                jnp.float32(weight * MAX_NODE_SCORE),),
+            "placed": self._put(np.zeros((delta.shape[1], n),
+                                         dtype=np.float32)),
+        }
 
     # -- host rows -----------------------------------------------------------
 
@@ -2012,6 +2205,7 @@ class TPUBackend:
                                     cs, pj) in cols:
                                 ctx.spread_last_gated = k
                                 break
+        self._ipa_table(ctx, pods)
         ctx.params = self._fwk_params(fwk, ct)
         # Used-state seed for the on-device chunk chain: the serving
         # tier's resident planes refresh it O(changed) from the cache's
@@ -2090,6 +2284,9 @@ class TPUBackend:
         ct, snapshot, fwk = ctx.ct, ctx.snapshot, ctx.fwk
         ctx.chunk_seq += 1
         chunk_idx = ctx.chunk_seq
+        #: per pod, its carried InterPodAffinity row (-1: none)
+        ipa_rows = ctx.ipa["chunks"][chunk_idx][0] \
+            if ctx.ipa is not None else None
         P = self.max_batch
         batch = PodBatch(pods, ct, P)
         N = ct.n_pad
@@ -2471,6 +2668,8 @@ class TPUBackend:
                         got[2].append(i)
                         continue
                     if name == "InterPodAffinity":
+                        if ipa_rows is not None and ipa_rows[i] >= 0:
+                            continue    # carried: the scan scores it
                         if compiler is None:
                             compiler = self._affinity_compiler(snapshot, ct)
                         if not self._ipa_score_relevant(pi, compiler):
@@ -2920,6 +3119,22 @@ class TPUBackend:
         # variants that would all route to the same W=1 body.
         if use_spread and prep["shortlist_k"]:
             prep["wave_w"] = 0
+        # A chunk whose placements move an InterPodAffinity score (or feed
+        # a later chunk's) carries the counts: the W = 1 full-width scan,
+        # no plan (the policy row below), no shortlist.
+        ipa = ctx.ipa
+        carried = False
+        if ipa is not None:
+            rows_np, tpls_np = ipa["chunks"][prep["chunk_idx"]]
+            carried = bool((rows_np >= 0).any() or (
+                (tpls_np >= 0).any() and prep["chunk_idx"] < ipa["last"]))
+        if carried:
+            prep["shortlist_k"] = 0
+            prep["wave_w"] = 0
+            ipa_args = ipa["dev"] + (ipa["placed"], self._put(rows_np),
+                                     self._put(tpls_np))
+        else:
+            ipa_args = None
         # Solve-mode policy row (r20): greedy pins the r18 call graph;
         # optimal routes the Sinkhorn plan + rounding. Transport plans
         # tie across equally-attractive columns, so under optimal mode
@@ -2934,7 +3149,8 @@ class TPUBackend:
             spread=use_spread,
             class_mode=prep.get("class_mode", False),
             exclusive=any(pi.has_required_anti_affinity
-                          for pi in prep["pods"]))
+                          for pi in prep["pods"]),
+            carried=carried)
         if solve_mode == "optimal":
             prep["shortlist_k"] = 0
             prep["wave_w"] = 0
@@ -2951,7 +3167,7 @@ class TPUBackend:
                        self._put(prep["sp_contrib"]))
         else:
             sp_args = self._spread_dummies(ct.n_pad, batch.req_q.shape[0])
-        assign_d, used_pack2, fit0_d, taint_ok_d, dom_counts2 = \
+        assign_d, used_pack2, fit0_d, taint_ok_d, dom_counts2, placed2 = \
             _solve_program()(
                 self._dev_static["alloc_q"], self._dev_used,
                 self._dev_static["alloc_pods"], prep["dev_pack"],
@@ -2964,13 +3180,15 @@ class TPUBackend:
                 prep["dev_perms"], *self._gang_args(prep, batch),
                 np.int32(max(1, flags.get("KTPU_SINKHORN_ITERS"))),
                 np.float32(flags.get("KTPU_SINKHORN_TEMP")),
-                np.int32(ct.n_real), np.int32(batch.p_real),
+                np.int32(ct.n_real), np.int32(batch.p_real), ipa_args,
                 p["strategy"], use_spread, prep["shortlist_k"],
                 prep["wave_w"], solve_mode, prep["block_w"],
             )
         self._dev_used = used_pack2
         if use_spread:
             sp["dev_counts"] = dom_counts2
+        if carried:
+            ipa["placed"] = placed2
         if self.metrics is not None:
             # The scan's trip count as the program was just handed it.
             w = max(prep["wave_w"], 1)
@@ -3448,7 +3666,7 @@ class _AssignCtx:
                  "assignments", "diagnostics",
                  "working", "delta", "delta_has_terms", "sel_cache",
                  "delta_idx", "wsnap", "spread", "spread_poisoned",
-                 "spread_last_gated", "chunk_seq", "class_pad")
+                 "spread_last_gated", "chunk_seq", "class_pad", "ipa")
 
 
 def _cached_matcher(term: dict, owner_ns: str, sel_cache: dict,

@@ -149,11 +149,54 @@ def greedy_assign(req_q, free_q, free_pods, mask, scores):
     return assign
 
 
+def _ipa_score(ipa, placed, a, fits):
+    """InterPodAffinity's weighted score of pod row `a` (-1: none) on every
+    node, normalised over this step's feasible nodes `fits`, from counts
+    that move with the chunk's own placements — what
+    `InterPodAffinity.pre_score` + `normalize_scores` give the host
+    scheduler one pod at a time.
+
+    `ipa` = (base, delta, keyed, dom, dom_key, w100, ...):
+    base (A, K, N) the raw weight each carried pod row gets per node and
+    topology key from the residents at the snapshot (before any node is
+    left out: AffinityCompiler.score_parts); delta (A, T, K) what one
+    placed pod of touching row t adds to it on its node; keyed (K, N)
+    1.0 where key k names every keyed node alone (hostname: a node is
+    its own domain); dom (N, D) node -> domain one-hot over the other
+    keys' domains, owned by key through dom_key (D, K); w100 the
+    profile's weight x 100. `placed` (T, N) counts this assign()'s
+    placements of each touching row per node.
+
+    A domain sums what its FEASIBLE nodes hold (pre_score walks the
+    filtered nodes); max == min adds nothing."""
+    base, delta, keyed, dom, dom_key, w100 = ipa[:6]
+    sa = jnp.maximum(a, 0)
+    u = base[sa] + delta[sa].T @ placed                         # (K, N)
+    raw = jnp.sum(keyed * u, axis=0)
+    if dom.shape[1]:
+        held = dom_key @ (u * fits.astype(jnp.float32))         # (D, N)
+        raw = raw + dom @ jnp.sum(dom.T * held, axis=1)
+    hi = jnp.max(jnp.where(fits, raw, NEG_INF))
+    lo = jnp.min(jnp.where(fits, raw, jnp.inf))
+    span = hi - lo
+    live = (a >= 0) & (span > 0)
+    return jnp.where(live, w100 * (raw - lo) / jnp.where(live, span, 1.0),
+                     0.0)
+
+
+def _ipa_place(placed, t, idx):
+    """One more pod of touching row `t` (-1: none) on node `idx`."""
+    ok = (t >= 0) & (idx >= 0)
+    return placed.at[jnp.maximum(t, 0), jnp.maximum(idx, 0)].add(
+        jnp.where(ok, 1.0, 0.0))
+
+
 @partial(jax.jit, static_argnames=("strategy",))
 def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
                             alloc_q, mask, static_scores, fit_col_w,
                             bal_col_mask, shape_u, shape_s, w_fit, w_bal,
-                            strategy: str, rows=None, exc=None, p_real=None):
+                            strategy: str, rows=None, exc=None, p_real=None,
+                            ipa=None):
     """Sequential-equivalent greedy with **live re-scoring**.
 
     The capacity-dependent score plugins (NodeResourcesFit strategies,
@@ -170,6 +213,12 @@ def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
     optional (P,) single-allowed-column restriction (-1 = none).
     `p_real` (traced int32, None = P) is the chunk's real pod count: the
     scan stops there (`_scan_real`), as in every chunk scan below.
+
+    `ipa` (see `_ipa_score`; its last three entries are `placed` (T, N)
+    and the per-pod (P,) carried row and touching row, -1 = none) adds
+    the InterPodAffinity score of pods whose score the chunk's own
+    placements move, and returns (assign, placed') so that the counts
+    chain across chunks like the used-state.
     """
     from kubernetes_tpu.ops import kernels  # local to avoid import cycle
 
@@ -180,6 +229,9 @@ def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
         rows = jnp.arange(p, dtype=jnp.int32)
 
     def step(carry, inp):
+        if ipa is not None:
+            carry, placed = carry[:3], carry[3]
+            inp, (ia, it) = inp[:-2], inp[-2:]
         free_q, free_pods, used_nz = carry
         if exc is None:
             req, req_nz, row = inp
@@ -196,6 +248,8 @@ def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
             shape_u, shape_s)[0]
         sc = sc + w_bal * kernels.balanced_allocation_score(
             alloc_q, used_nz, req_nz[None, :], bal_col_mask)[0]
+        if ipa is not None:
+            sc = sc + _ipa_score(ipa, placed, ia, fits)
         masked = jnp.where(fits, sc, NEG_INF)
         idx = jnp.argmax(masked).astype(jnp.int32)
         idx = jnp.where(any_fit, idx, jnp.int32(-1))
@@ -203,12 +257,20 @@ def greedy_assign_rescoring(req_q, req_nz_q, free_q, free_pods, used_nz_q,
         free_q = free_q - jnp.where(hit[:, None], req[None, :], 0)
         free_pods = free_pods - hit.astype(jnp.int32)
         used_nz = used_nz + jnp.where(hit[:, None], req_nz[None, :], 0)
+        if ipa is not None:
+            return (free_q, free_pods, used_nz,
+                    _ipa_place(placed, it, idx)), idx
         return (free_q, free_pods, used_nz), idx
 
     xs = (req_q, req_nz_q, rows) if exc is None \
         else (req_q, req_nz_q, rows, exc)
-    (_, _, _), assign = _scan_real(
-        step, (free_q, free_pods, used_nz_q), xs, p_real)
+    carry0 = (free_q, free_pods, used_nz_q)
+    if ipa is not None:
+        xs = xs + tuple(ipa[-2:])
+        carry0 = carry0 + (ipa[-3],)
+    carry, assign = _scan_real(step, carry0, xs, p_real)
+    if ipa is not None:
+        return assign, carry[3]
     return assign
 
 
@@ -220,7 +282,7 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
                                    dom_onehot, cid_onehot, dom_counts,
                                    max_skew, min_ok, has_key_nc,
                                    applies, contributes, rows=None,
-                                   exc=None, p_real=None):
+                                   exc=None, p_real=None, ipa=None):
     """greedy_assign_rescoring + PodTopologySpread hard constraints INSIDE
     the scan (sequential-equivalent, like capacity).
 
@@ -264,7 +326,8 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
     (rows=None ⇒ per-pod planes). applies/contributes stay per-pod.
 
     Returns (assign, dom_counts') so the caller can chain counts across
-    chunks on device, exactly like the packed used-state.
+    chunks on device, exactly like the packed used-state; with `ipa`
+    (greedy_assign_rescoring's) (assign, dom_counts', placed').
     """
     from kubernetes_tpu.ops import kernels  # local to avoid import cycle
 
@@ -280,6 +343,9 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
     gate_nc = has_key_nc > 0
 
     def step(carry, inp):
+        if ipa is not None:
+            carry, placed = carry[:4], carry[4]
+            inp, (ia, it) = inp[:-2], inp[-2:]
         free_q, free_pods, used_nz, dcounts = carry
         if exc is None:
             req, req_nz, row, app, contrib = inp
@@ -314,6 +380,8 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
             shape_u, shape_s)[0]
         sc = sc + w_bal * kernels.balanced_allocation_score(
             alloc_q, used_nz, req_nz[None, :], bal_col_mask)[0]
+        if ipa is not None:
+            sc = sc + _ipa_score(ipa, placed, ia, fits)
         masked = jnp.where(fits, sc, NEG_INF)
         idx = jnp.argmax(masked).astype(jnp.int32)
         idx = jnp.where(any_fit, idx, jnp.int32(-1))
@@ -328,13 +396,21 @@ def greedy_assign_rescoring_spread(req_q, req_nz_q, free_q, free_pods,
             any_fit,
             (hit.astype(jnp.float32) @ dom_onehot) * (cid_onehot @ contrib),
             0.0)
+        if ipa is not None:
+            return (free_q, free_pods, used_nz, dcounts,
+                    _ipa_place(placed, it, idx)), idx
         return (free_q, free_pods, used_nz, dcounts), idx
 
     xs = (req_q, req_nz_q, rows, applies, contributes) if exc is None \
         else (req_q, req_nz_q, rows, applies, contributes, exc)
-    (_, _, _, dom_counts2), assign = _scan_real(
-        step, (free_q, free_pods, used_nz_q, dom_counts), xs, p_real)
-    return assign, dom_counts2
+    carry0 = (free_q, free_pods, used_nz_q, dom_counts)
+    if ipa is not None:
+        xs = xs + tuple(ipa[-2:])
+        carry0 = carry0 + (ipa[-3],)
+    carry, assign = _scan_real(step, carry0, xs, p_real)
+    if ipa is not None:
+        return assign, carry[3], carry[4]
+    return assign, carry[3]
 
 
 @partial(jax.jit, static_argnames=("strategy",))

@@ -55,6 +55,13 @@ DEFAULT_CONSTRAINTS = [
 ]
 
 
+def _controlled(pod: PodInfo) -> bool:
+    """Does a controller own the pod (an ownerReference with
+    `controller: true`)?"""
+    refs = (pod.pod.get("metadata") or {}).get("ownerReferences") or ()
+    return any(ref.get("controller") for ref in refs)
+
+
 def _node_eligible(pod: PodInfo, node: NodeInfo) -> bool:
     """Honor nodeAffinity + taints when counting domains (filtering.go
     `pl.filterNodesWithTaintsAndAffinity` equivalent)."""
@@ -121,13 +128,15 @@ class PodTopologySpread(Plugin):
     def _constraints_for(self, pod: PodInfo, action: str) -> list[dict]:
         cons = pod.topology_spread_constraints
         if not cons and self.default_constraints:
-            # Default constraints adopt the pod's own labels as selector (the
-            # reference builds the selector from the pod's owning service/RS;
-            # we use pod labels — same effect for replicated workloads).
+            # Default constraints hold a pod that a controller owns, and
+            # adopt the pod's own labels as selector (the reference builds
+            # the selector from the owning service/RS/RC/StatefulSet — the
+            # same pods for a replicated workload). A bare pod has no such
+            # selector and the reference gives it no default constraint.
             cons = [
                 {**c, "labelSelector": {"matchLabels": pod.labels}}
                 for c in self.default_constraints
-            ] if pod.labels else []
+            ] if pod.labels and _controlled(pod) else []
         return [c for c in cons if c.get("whenUnsatisfiable", "DoNotSchedule") == action]
 
     def _build_state(self, pod: PodInfo, nodes, action: str) -> _SpreadState:
