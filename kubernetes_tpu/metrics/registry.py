@@ -861,6 +861,14 @@ class SchedulerMetrics:
             "serving_fast_path_failures_total",
             "Fast-path solves or warm-ups that raised (the pod took "
             "the batch path instead)")
+        #: the fast path's device round trip, with the loop yielded:
+        #: from the index's copy_to_host_async to the index on the host,
+        #: less the loop's last pass of other callbacks, one observation
+        #: per fast solve, whether tracing is on or off.
+        self.fast_fetch_duration = r.histogram(
+            "scheduler_tpu_fast_fetch_seconds",
+            "Wait for a single-pod fast solve's index to reach the host, "
+            "to within one pass of the event loop", buckets=STAGE_BUCKETS)
         self.resident_plane_refreshes = r.counter(
             "resident_plane_refreshes_total",
             "Refreshes of the device-resident used-state planes "

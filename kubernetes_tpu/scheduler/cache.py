@@ -55,6 +55,11 @@ class SchedulerCache:
         self._log_floor = 0          # gens ≤ floor are out of the log
         self._last_snap: Snapshot | None = None
 
+    @property
+    def spec_seq(self) -> int:
+        """Bumps on every node object update or removal."""
+        return self._spec_seq
+
     def _bump(self, node: NodeInfo) -> None:
         self._generation += 1
         node.generation = self._generation
@@ -243,12 +248,14 @@ class SchedulerCache:
         O(N) copies were most of the fast path's host wall at 5k nodes,
         paid per lone-pod placement for a one-row change.
 
-        Contract: consume SYNCHRONOUSLY and drop before the next cache
-        mutation — any assume/informer event replaces entries beneath
-        it (update_snapshot's copies exist precisely for callers that
-        hold snapshots across mutations, like the batch pipeline's
-        chunked verify). Never cached as _last_snap for the same
-        reason."""
+        Contract: consume SYNCHRONOUSLY, before the next await — any
+        assume/informer event replaces entries beneath it
+        (update_snapshot's copies exist precisely for callers that hold
+        snapshots across mutations, like the batch pipeline's chunked
+        verify). The fast path reads it up to the solve's dispatch only;
+        after yielding the loop for the index, its verify reads the
+        cache's live entry (`nodes`) and `spec_seq`, not this wrapper.
+        Never cached as _last_snap for the same reason."""
         if self._full or self._dirty:
             # This maintenance clears the dirty set, but _last_snap's
             # COPIED lists still hold the pre-mutation clones — without

@@ -38,11 +38,20 @@ of one; the host path only where the backend cannot take the pod), which
 also owns diagnostics/preemption for no-fit pods — the fast path only
 takes the happy path, and a host verify (exact integer re-check)
 backstops the quantized device fit exactly like the batch verify.
+
+An attempt comes in three steps. `dispatch` runs up to the solve's
+dispatch and starts the index's copy to the host. The serving tier then
+yields the event loop until the index is ready, so binding tasks, watch
+deliveries and creates run during the device round trip, and `fetch`
+reads it. `finish` verifies against the cache as it stands after the
+wait. `try_schedule` is the blocking composition of the three.
 """
 
 from __future__ import annotations
 
 import logging
+import time
+from typing import NamedTuple
 
 import numpy as np
 
@@ -73,6 +82,18 @@ logger = logging.getLogger(__name__)
 #: split, every novel bucket recompiled the whole solve mid-serve and
 #: the compile walls poisoned the tier's fast-wall estimate.
 FUSE_MAX_ROWS = 2
+
+
+class PendingSolve(NamedTuple):
+    """A dispatched fast solve: the device index on its way to the host,
+    the node names it indexes (the tensors'), the snapshot's node-spec
+    sequence number, and the `perf_counter` reading when its copy
+    started."""
+    idx_d: object
+    node_names: list
+    n_real: int
+    spec_seq: int
+    copy_t: float
 
 
 class SinglePodFastPath:
@@ -214,12 +235,12 @@ class SinglePodFastPath:
 
     # -- the solve ----------------------------------------------------------
 
-    def try_schedule(self, pi, snapshot, fwk, record: bool = True) -> str | None:
-        """One placement attempt. Returns the node name, or None when the
-        pod is ineligible / nothing fits (the caller routes it through
-        the normal path, which owns diagnostics and preemption).
-        record=False is the warmup form: full solve, nothing counted
-        (the caller discards the result without assuming)."""
+    def dispatch(self, pi, snapshot, fwk) -> PendingSolve | None:
+        """The synchronous half of a placement attempt: tensors,
+        eligibility, the resident refresh and the solve's dispatch, then
+        the index's copy to the host started. Returns None when the pod
+        is ineligible. The snapshot is consumed here: nothing of it
+        outlives the call but the tensors' node names."""
         backend = self.backend
         ct = backend._tensors(snapshot)
         if pi.nominated_node or ct.has_unknown_resource(pi.requests):
@@ -254,26 +275,69 @@ class SinglePodFastPath:
                 delta[0], delta[1], static["alloc_pods"],
                 static["taint_f"], static["taint_p"], *tail)
             self.resident.adopt(pack)
+        idx_d.copy_to_host_async()
+        return PendingSolve(idx_d, ct.node_names, ct.n_real, snapshot.spec_seq,
+                            time.perf_counter())
+
+    def fetch(self, pending: PendingSolve,
+              last_pass: float = 0.0) -> tuple[int, float]:
+        """The index on the host, blocking until it is there. The serving
+        tier calls this once `idx_d.is_ready()`, so the read is only the
+        copy's tail. Returns the index and the device's wait: from the
+        copy's start to the index on the host, less `last_pass`, the
+        caller's last yield of the loop, during which the index became
+        ready while other callbacks ran. That is the round trip to within
+        one pass of the loop, from below; scheduler_tpu_fast_fetch_seconds
+        observes it whether tracing is on or off."""
         check_dispatch_seam("serving.fastpath.fetch")
-        idx = int(np.asarray(idx_d))
-        if idx < 0 or idx >= ct.n_real:
+        with self.backend._span("solver.fast_fetch"):
+            idx = int(np.asarray(pending.idx_d))
+        wall = time.perf_counter() - pending.copy_t - last_pass
+        if self.metrics is not None:
+            self.metrics.fast_fetch_duration.observe(wall)
+        return idx, wall
+
+    def finish(self, pi, pending: PendingSolve, idx: int, node_info,
+               spec_seq: int) -> str | None:
+        """The index as a node name, verified against `node_info(name)`
+        (the cache's entry as it stands now, or None for a node that is
+        gone) and against `spec_seq`, the node specs' sequence number
+        now. None sends the pod through the normal path."""
+        if idx < 0 or idx >= pending.n_real:
             self.no_fit += 1
             return None
-        name = ct.node_names[idx]
-        ni = snapshot.get(name)
-        if ni is None or insufficient_resources(pi, ni):
-            # Quantized fit is conservative, so this is belt-and-braces:
-            # route the pod through the exact batch verify instead.
+        name = pending.node_names[idx]
+        ni = node_info(name)
+        if ni is None or spec_seq != pending.spec_seq \
+                or insufficient_resources(pi, ni):
+            # The quantized fit is conservative, so on a still cache
+            # this is belt-and-braces. A node removed or filled while
+            # the index was on its way lands here too, and so does any
+            # node spec update in that time (a taint, a cordon, a label
+            # the device mask read before it). Either way the pod takes
+            # the exact batch verify instead.
             logger.warning(
                 "fast path verify rejected %s on %s; rerouting", pi.key,
                 name)
             self.no_fit += 1
             return None
-        if record:
-            self.placed += 1
-            if self.metrics is not None:
-                self.metrics.serving_fast_path_pods.inc()
+        self.placed += 1
+        if self.metrics is not None:
+            self.metrics.serving_fast_path_pods.inc()
         return name
+
+    def try_schedule(self, pi, snapshot, fwk) -> str | None:
+        """One placement attempt, blocking: dispatch, fetch without
+        yielding, verify against the same snapshot. Returns the node
+        name, or None when the pod is ineligible / nothing fits (the
+        caller routes it through the normal path, which owns diagnostics
+        and preemption)."""
+        pending = self.dispatch(pi, snapshot, fwk)
+        if pending is None:
+            return None
+        idx, _ = self.fetch(pending)
+        return self.finish(pi, pending, idx, snapshot.get,
+                           snapshot.spec_seq)
 
     def warm(self, pi, snapshot, fwk) -> None:
         """Compile every serve-path program variant OFF the serve path:

@@ -216,9 +216,9 @@ class ServingTier:
         if sched.backend_profiles is not None \
                 and pi.scheduler_name not in sched.backend_profiles:
             return False
-        # Zero-copy snapshot: consumed synchronously inside this cycle
-        # (ct build → eligibility → solve → verify), dropped before the
-        # assume mutates the cache — the light contract.
+        # Zero-copy snapshot: consumed by the fast path's dispatch (ct
+        # build → eligibility → solve) before the loop is yielded — the
+        # light contract; the verify reads the live cache.
         snapshot = sched.cache.light_snapshot()
         if sched.tracer.enabled:
             with sched.tracer.span(
@@ -231,14 +231,37 @@ class ServingTier:
 
     async def _fast_cycle(self, pi, snapshot, fwk) -> bool:
         sched = self.sched
+        fp = self.fastpath
         t0 = time.perf_counter()
         tr = sched.tracer
         try:
             if tr.enabled:
                 with tr.span("solver.fast"):
-                    node = self.fastpath.try_schedule(pi, snapshot, fwk)
+                    pending = fp.dispatch(pi, snapshot, fwk)
             else:
-                node = self.fastpath.try_schedule(pi, snapshot, fwk)
+                pending = fp.dispatch(pi, snapshot, fwk)
+            if pending is None:
+                return False
+            own = time.perf_counter() - t0
+            # The device round trip with the loop free: look, and yield
+            # to whatever else is ready, until the index is there, so
+            # binding tasks, watch deliveries and creates run meanwhile.
+            # No worker thread: it would have to take the GIL back from
+            # the busy loop, then wait for a turn on it. The dispatch
+            # loop awaits this cycle, so no other attempt, assume or
+            # resident refresh starts during it.
+            idx_d, last_pass = pending.idx_d, 0.0
+            while not idx_d.is_ready():
+                t_look = time.perf_counter()
+                await asyncio.sleep(0)
+                last_pass = time.perf_counter() - t_look
+            idx, fetch_wall = fp.fetch(pending, last_pass)
+            t1 = time.perf_counter()
+            # The cache as it stands after the wait: a node removed,
+            # filled or respecced meanwhile sends the pod to the batch
+            # path.
+            node = fp.finish(pi, pending, idx, sched.cache.nodes.get,
+                             sched.cache.spec_seq)
         except Exception:
             # The fast path must never break scheduling: any device/host
             # error just reroutes the pod through the normal path (and
@@ -248,16 +271,20 @@ class ServingTier:
             logger.exception("fast path failed for %s; normal path", pi.key)
             sched.metrics.serving_fast_path_failures.inc()
             return False
-        wall = time.perf_counter() - t0
+        t2 = time.perf_counter()
         if node is None:
             return False
         self._fast_samples += 1
         if self._fast_samples > 1:
             # The first sample carries the jit compile when warmup was
             # skipped — policy seeds cover until a warm sample lands.
-            self._fast_walls.append(wall)
+            # The pod's own cost: dispatch, the device's wait and the
+            # verify, not the loop's last pass of other callbacks, or
+            # the rate gate would read the freed loop as a slower fast
+            # path.
+            self._fast_walls.append(own + fetch_wall + (t2 - t1))
             self._last_fast_t = time.monotonic()
-        sched.metrics.observe_attempt("scheduled", fwk.profile_name, wall)
+        sched.metrics.observe_attempt("scheduled", fwk.profile_name, t2 - t0)
         await sched._assume_and_bind(fwk, fwk.new_cycle_state(), pi, node)
         return True
 

@@ -147,7 +147,7 @@ class TestDeviceLossIsVisible:
 
         def boom(self, *a, **kw):
             raise RuntimeError("device lost (injected)")
-        monkeypatch.setattr(SinglePodFastPath, "try_schedule", boom)
+        monkeypatch.setattr(SinglePodFastPath, "dispatch", boom)
         monkeypatch.setattr(SinglePodFastPath, "warm", boom)
 
         async def body():

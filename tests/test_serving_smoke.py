@@ -28,8 +28,10 @@ from kubernetes_tpu.scheduler.types import PodInfo
 from kubernetes_tpu.serving import serving_enabled
 from kubernetes_tpu.serving.admission import AdmissionWindow
 from kubernetes_tpu.serving.fastpath import SinglePodFastPath
+from kubernetes_tpu.serving.loop import ServingTier
 from kubernetes_tpu.serving.resident import ResidentPlanes
 from kubernetes_tpu.utils import locking
+from kubernetes_tpu.utils.tracing import Tracer
 from test_tpu_backend import default_fwk
 
 ZONE_LABEL = "topology.kubernetes.io/zone"
@@ -71,6 +73,44 @@ def _backend(chunk=16):
 def _fast(backend):
     res = ResidentPlanes(backend)
     return res, SinglePodFastPath(backend, res)
+
+
+class FastCycleScheduler:
+    """What `ServingTier`'s fast cycle reaches of a Scheduler, over a
+    bare cache: assume is the cache's own, and `placed` lists what the
+    cycle assumed, in order."""
+
+    def __init__(self, cache, tracer=None):
+        self.cache = cache
+        self.metrics = SchedulerMetrics()
+        self.tracer = tracer if tracer is not None else Tracer()
+        self.backend = _backend()
+        self.backend.metrics = self.metrics
+        self.backend.tracer = self.tracer
+        self.fwk = default_fwk()
+        self.profiles = {"default-scheduler": self.fwk}
+        self.backend_profiles = None
+        self.placed: list[tuple[str, str]] = []
+
+    def _record_queue_wait(self, pi) -> None:
+        pass
+
+    async def _assume_and_bind(self, fwk, state, pi, node) -> None:
+        self.cache.assume_pod(pi, node)
+        self.placed.append((pi.key, node))
+
+
+def _random_lone_pod(rng, t, n_nodes):
+    kw = {"requests": {
+        "cpu": f"{rng.choice([100, 250, 500, 900, 1700])}m",
+        "memory": f"{rng.choice([128, 512, 1024])}Mi"}}
+    if rng.random() < 0.3:
+        kw["tolerations"] = [{"key": "dedicated", "operator": "Exists"}]
+    if rng.random() < 0.25:
+        # NodeAffinity static row rides the fast-path base mask.
+        kw["node_selector"] = {
+            "kubernetes.io/hostname": f"n{rng.randrange(n_nodes)}"}
+    return PodInfo(make_pod(f"p{t}", uid=f"u{t}", **kw)), kw
 
 
 class TestAdmissionPolicy:
@@ -158,17 +198,7 @@ class TestFastPathDifferential:
         _, fp = _fast(b_fast)
         checked = 0
         for t in range(24):
-            kw = {"requests": {
-                "cpu": f"{rng.choice([100, 250, 500, 900, 1700])}m",
-                "memory": f"{rng.choice([128, 512, 1024])}Mi"}}
-            if rng.random() < 0.3:
-                kw["tolerations"] = [{"key": "dedicated",
-                                      "operator": "Exists"}]
-            if rng.random() < 0.25:
-                # NodeAffinity static row rides the fast-path base mask.
-                kw["node_selector"] = {
-                    "kubernetes.io/hostname": f"n{rng.randrange(150)}"}
-            pi = PodInfo(make_pod(f"p{t}", uid=f"u{t}", **kw))
+            pi, kw = _random_lone_pod(rng, t, 150)
             a, _ = b_batch.assign([pi], snap, fwk)
             fast = fp.try_schedule(pi, snap, fwk)
             assert fast == a[pi.key], (t, kw)
@@ -178,6 +208,43 @@ class TestFastPathDifferential:
                 snap = cache.update_snapshot()
         assert checked >= 12  # the differential actually exercised placements
         assert fp.placed == checked
+
+    def test_async_cycle_matches_the_blocking_form(self):
+        """The serving tier's cycle, which yields the loop until the
+        index is ready and verifies against the live cache, places a
+        random sequence of lone pods exactly where the blocking
+        try_schedule does, each placement assumed before the next pod
+        on both sides."""
+        cache_b, _ = _cluster(150, taint_every=7)
+        cache_a, _ = _cluster(150, taint_every=7)
+        sched = FastCycleScheduler(cache_a)
+        tier = ServingTier(sched)
+        fwk = default_fwk()
+        _, fp = _fast(_backend())
+        rng = random.Random(0xFE7C)
+
+        async def run():
+            checked = 0
+            for t in range(24):
+                pi, kw = _random_lone_pod(rng, t, 150)
+                blocking = fp.try_schedule(pi, cache_b.update_snapshot(),
+                                           fwk)
+                placed = len(sched.placed)
+                took = await tier._try_fast_path(pi)
+                assert took == (blocking is not None), (t, kw)
+                if blocking is not None:
+                    assert sched.placed[placed:] == [(pi.key, blocking)], \
+                        (t, kw)
+                    cache_b.assume_pod(pi, blocking)
+                    checked += 1
+            return checked
+
+        checked = asyncio.run(run())
+        assert checked >= 12
+        assert tier.fastpath.placed == fp.placed == checked
+        # one fetch per solve dispatched (placed or not)
+        assert sched.metrics.fast_fetch_duration.count() \
+            == tier.fastpath.placed + tier.fastpath.no_fit
 
     def test_ineligible_shapes_fall_through(self):
         _, snap = _cluster(20)

@@ -867,7 +867,7 @@ class TestSelfTimeLedger:
     ("framework.Permit", "attempt"), ("solver.tensors", "attempt"),
     ("solver.prep", "attempt"), ("solver.dispatch", "attempt"),
     ("solver.solve", "attempt"), ("solver.verify", "attempt"),
-    ("solver.fast", "attempt"),
+    ("solver.fast", "attempt"), ("solver.fast_fetch", "attempt"),
     ("scheduler.bind", "bind"), ("framework.PreBind", "bind"),
     ("framework.Bind", "bind"), ("framework.PostBind", "bind"),
     ("host.gc", "gc"), ("loop.idle", "idle"), ("loop.other", "other"),
